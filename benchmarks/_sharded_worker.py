@@ -12,6 +12,7 @@ import argparse
 import json
 import time
 
+import jax
 import numpy as np
 
 from repro.core import exact_join_pairs
@@ -50,7 +51,8 @@ def main(argv=None) -> None:
     n_waves = max(-(-args.n_query // args.wave), 1)
     host_bytes = st.bytes_feedback + st.bytes_band + st.bytes_assembly
     print(json.dumps(dict(
-        n_shards=args.shards, n_data=args.n_data, method=args.method,
+        platform=jax.devices()[0].platform, n_shards=args.shards,
+        n_data=args.n_data, method=args.method,
         quant=args.quant, seconds=dt, recall=rec, pairs=len(res.pairs),
         n_dist=int(st.n_dist),
         bytes_feedback=int(st.bytes_feedback),

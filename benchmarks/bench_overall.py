@@ -214,7 +214,10 @@ def run_sharded(scale: str = "ci", *, regime: str = "manifold",
     (max/mean ambiguous-band occupancy across shards).
 
     Each shard count runs in a subprocess with that many forced host
-    devices (jax locks the device count at first init). Two extra ``nlj``
+    devices (jax locks the device count at first init). The children run
+    on the CPU (``JAX_PLATFORMS=cpu``, so none of them reaches for a chip
+    this process may hold), and every row says so in ``platform``. Two
+    extra ``nlj``
     cells run the same shard count and θ at N_y and 4·N_y and *assert*
     host bytes per wave stay sub-linear in N_y (< 2× for 4× rows): the
     on-device pool merge ships only the band-compacted merged pool
@@ -235,7 +238,7 @@ def run_sharded(scale: str = "ci", *, regime: str = "manifold",
 
     def cell(n_shards, *, n_data=n_data, method=method, quant=quant):
         env = dict(os.environ, REPRO_BENCH_DEVICES=str(max(n_shards, 1)),
-                   PYTHONPATH="src")
+                   PYTHONPATH="src", JAX_PLATFORMS="cpu")
         out = subprocess.run(
             [sys.executable, "-m", "benchmarks._sharded_worker",
              "--n-data", str(n_data), "--n-query", str(n_query),

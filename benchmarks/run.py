@@ -18,6 +18,7 @@ from benchmarks import (bench_breakdown, bench_distributed, bench_index_type,
                         bench_join_size, bench_offline, bench_overall,
                         bench_queue_size, bench_scalability)
 from benchmarks.common import emit
+from repro.launch.compile_cache import enable_compile_cache
 
 _quant = SimpleNamespace(run=lambda scale: bench_breakdown.run_quant(
     "full_hd" if scale == "full" else "ci_hd"))
@@ -40,6 +41,7 @@ def main(argv=None) -> int:
     ap.add_argument("--scale", choices=("ci", "full"), default="ci")
     ap.add_argument("--only")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     failed = []
     for name, mod in BENCHES:
         if args.only and args.only not in name:

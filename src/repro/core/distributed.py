@@ -50,13 +50,16 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat, traversal
+from repro.core import traversal
 from repro.core.types import (NO_NODE, GraphIndex, JoinStats,
                               TraversalConfig, early_exit_enabled)
 from repro.kernels import ops
 from repro.obs import trace as obs_trace
 
 Array = jax.Array
+# matmul-form distances contract at full f32: the certified bounds and the
+# exact NLJ assume f32 rounding, which a TPU's default precision is not
+_F32 = jax.lax.Precision.HIGHEST
 
 # MeshPlan decision-rule constants (ARCHITECTURE §8). Hybrid
 # dimension+vector partitioning pays off only when (a) the dim axis is
@@ -104,10 +107,16 @@ class MeshPlan:
         return self.n_shards * self.dim_shards
 
     def make_mesh(self) -> Mesh:
+        """The plan's mesh, with ``Auto`` axes: the sharded joins state
+        every layout in their ``shard_map`` specs and placements, so the
+        per-shard tables placed on it stay plainly indexable."""
+        auto = jax.sharding.AxisType.Auto
         if self.dim_shards == 1:
-            return jax.make_mesh((self.n_shards,), (self.data_axis,))
+            return jax.make_mesh((self.n_shards,), (self.data_axis,),
+                                 axis_types=(auto,))
         return jax.make_mesh((self.n_shards, self.dim_shards),
-                             (self.data_axis, self.model_axis))
+                             (self.data_axis, self.model_axis),
+                             axis_types=(auto, auto))
 
     @classmethod
     def plan(cls, n_y: int, d: int, shards, *, devices: int | None = None,
@@ -186,6 +195,21 @@ class ShardedMergedIndex:
     @property
     def n_shards(self) -> int:
         return self.vecs.shape[0]
+
+
+def place_sharded_index(smi: ShardedMergedIndex, mesh: Mesh, axes
+                        ) -> ShardedMergedIndex:
+    """Lay the stacked per-shard arrays out over the mesh's shard axes,
+    one shard per device, so that a wave reads each shard where it lives
+    instead of streaming the whole stack from the device it was built on
+    (the wave step's ``shard_map`` takes them with this same spec)."""
+    axes = (axes,) if isinstance(axes, str) else tuple(axes)
+    sh = NamedSharding(mesh, P(axes[0] if len(axes) == 1 else axes))
+    return dataclasses.replace(
+        smi, vecs=jax.device_put(smi.vecs, sh),
+        nbrs=jax.device_put(smi.nbrs, sh),
+        start=jax.device_put(smi.start, sh),
+        mean_nbr_dist=jax.device_put(smi.mean_nbr_dist, sh))
 
 
 def build_sharded_merged_index(Y, X, n_shards: int, **build_kw
@@ -661,7 +685,7 @@ def make_distributed_mi_join(mesh: Mesh, shard_axes, smi: ShardedMergedIndex,
         early_exit=early_exit_enabled(cfg) if pdx else False,
         merge_cap=merge_cap, pool_combine=pool_combine)
 
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_idx, spec_idx, spec_idx, spec_idx,
                   spec_idx, spec_idx, spec_idx, spec_idx,
@@ -808,7 +832,7 @@ def distributed_mi_join(X, smi: ShardedMergedIndex, mesh: Mesh | None = None,
         step, qargs = get_step()
         dev = tr.begin("wave/device", lane="traversal", cap=rcap.cap,
                        merge_cap=mcap.cap, shards=S)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             outs = step(
                 smi.vecs, smi.nbrs, smi.mean_nbr_dist, smi.start, *qargs,
                 X[jnp.asarray(padded)], jnp.asarray(padded),
@@ -967,7 +991,7 @@ def slab_partial_sq_dists(X, Y, k: int, *, slab: int | None = None):
         y = jnp.asarray(Yp[:, g * w:(g + 1) * w])
         xn = jnp.sum(x * x, axis=-1, keepdims=True)
         yn = jnp.sum(y * y, axis=-1, keepdims=True)
-        parts.append(xn + yn.T - 2.0 * (x @ y.T))
+        parts.append(xn + yn.T - 2.0 * jnp.matmul(x, y.T, precision=_F32))
     return jnp.stack(parts)
 
 
@@ -980,15 +1004,15 @@ def make_hybrid_sq_dists(mesh: Mesh, plan: MeshPlan):
     def body(x, y):
         xn = jnp.sum(x * x, axis=-1, keepdims=True)
         yn = jnp.sum(y * y, axis=-1, keepdims=True)
-        part = xn + yn.T - 2.0 * (x @ y.T)
+        part = xn + yn.T - 2.0 * jnp.matmul(x, y.T, precision=_F32)
         if plan.dim_shards > 1:
             part = jax.lax.psum(part, plan.model_axis)
         return part
 
     spec = (P(None, plan.model_axis) if plan.dim_shards > 1
             else P(None, None))
-    mapped = compat.shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                              out_specs=P(), check_vma=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                           out_specs=P(), check_vma=False)
     return jax.jit(mapped)
 
 
@@ -1031,7 +1055,7 @@ def _make_nlj_step(mesh: Mesh, plan: MeshPlan, *, rows: int, d: int,
         # x: (B, w) local dim slice;  y: (rows, w) local rows × dims
         xn = jnp.sum(x * x, axis=-1, keepdims=True)
         yn = jnp.sum(y * y, axis=-1, keepdims=True)
-        part = xn + yn.T - 2.0 * (x @ y.T)
+        part = xn + yn.T - 2.0 * jnp.matmul(x, y.T, precision=_F32)
         if k > 1:
             # full norms, certified per-rank retirement, exact combine
             nx = jax.lax.psum(xn, maxis)
@@ -1061,8 +1085,8 @@ def _make_nlj_step(mesh: Mesh, plan: MeshPlan, *, rows: int, d: int,
     else:
         in_specs = (P(None, None), P(daxis, None), P(), P())
         out_specs = (P(), P(daxis))
-    mapped = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     return jax.jit(mapped)
 
 
@@ -1105,8 +1129,12 @@ def distributed_nlj_join(X, Y, plan: MeshPlan, *, theta: float,
                 axis=0)
         Yp, w = _pad_cols(Yp, k, DEFAULT_SLAB)
         cache.clear()
-        cache.update(key=key, mesh=plan.make_mesh(), rows=rows, w=w,
-                     Yp=jnp.asarray(Yp),
+        mesh = plan.make_mesh()
+        # Y lives sharded, rows over the data axis (and dim groups over
+        # the model axis), exactly as the step's shard_map takes it
+        y_spec = P(plan.data_axis, plan.model_axis if k > 1 else None)
+        cache.update(key=key, mesh=mesh, rows=rows, w=w,
+                     Yp=jax.device_put(Yp, NamedSharding(mesh, y_spec)),
                      mcap=W.StickyCap(merge_cap, rows * S), steps={})
     mesh, rows, w = cache["mesh"], cache["rows"], cache["w"]
     mcap: W.StickyCap = cache["mcap"]
@@ -1125,7 +1153,7 @@ def distributed_nlj_join(X, Y, plan: MeshPlan, *, theta: float,
 
     def dispatch(xw, lane_valid):
         step = get_step()
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             outs = step(jnp.asarray(xw), cache["Yp"], th2,
                         jnp.asarray(lane_valid))
         B = int(lane_valid.shape[0])
@@ -1195,13 +1223,13 @@ def make_distributed_nlj_count(mesh: Mesh, data_axes, model_axis: str,
         # partial squared-distance terms over the local dim slice
         xn = jnp.sum(x * x, axis=-1, keepdims=True)
         yn = jnp.sum(y * y, axis=-1, keepdims=True).T
-        xy = x @ y.T
+        xy = jnp.matmul(x, y.T, precision=_F32)
         part = xn + yn - 2.0 * xy                      # (B, N/s)
         d2 = jax.lax.psum(part, model_axis)            # full squared dists
         cnt = jnp.sum(d2 < jnp.float32(theta) ** 2, axis=1).astype(jnp.int32)
         return jax.lax.psum(cnt, data_axes)            # (B,) global counts
 
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, model_axis), P(data_axes, model_axis)),
         out_specs=P(),

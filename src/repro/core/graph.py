@@ -47,6 +47,8 @@ from repro.kernels import ops
 
 Array = jax.Array
 _INF = jnp.float32(jnp.inf)
+# element budget of one blocked distance tile (2^28 f32 = 1 GiB)
+_TILE_ELEMS = 1 << 28
 
 
 @dataclasses.dataclass
@@ -237,7 +239,8 @@ def _cascade_knn(vecs: Array, tier, k: int, *, qblock: int, dblock: int,
         valid[qi, slot] = True
         ysub = vj[jnp.asarray(colmat)]                       # (bq, S, d)
         xy = jnp.matmul(vj[q0:q1][:, None, :],
-                        jnp.transpose(ysub, (0, 2, 1)))[:, 0, :]
+                        jnp.transpose(ysub, (0, 2, 1)),
+                        precision=jax.lax.Precision.HIGHEST)[:, 0, :]
         dmat = jnp.maximum(vn[q0:q1][:, None] + vn[jnp.asarray(colmat)]
                            - 2.0 * xy, 0.0)
         dsur = np.asarray(dmat)[qi, slot]
@@ -290,7 +293,8 @@ def _pair_sq_dists(cvecs: Array) -> Array:
     squared distances (the prune rule's comparison values)."""
     cn = jnp.sum(cvecs.astype(jnp.float32) ** 2, axis=-1)    # (b, k)
     cc = jnp.einsum("bkd,bjd->bkj", cvecs.astype(jnp.float32),
-                    cvecs.astype(jnp.float32))
+                    cvecs.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(cn[:, :, None] + cn[:, None, :] - 2.0 * cc, 0.0)
 
 
@@ -441,11 +445,15 @@ def _repair_connectivity(vecs_np: np.ndarray, nbrs: np.ndarray, start: int,
         if missing.size == 0:
             break
         reach_ids = np.flatnonzero(seen)
-        # nearest reachable node for each missing node (blocked exact)
-        mv = jnp.asarray(vecs_np[missing])
+        # nearest reachable node for each missing node (exact, blocked
+        # over the missing side so the distance tile stays ~1 GB)
         rv = jnp.asarray(vecs_np[reach_ids])
-        d = np.asarray(ops.pairwise_sq_dists(mv, rv, impl=impl))
-        host = reach_ids[np.argmin(d, axis=1)]
+        step = max(1, _TILE_ELEMS // reach_ids.size)
+        host = np.concatenate([
+            reach_ids[np.argmin(np.asarray(ops.pairwise_sq_dists(
+                jnp.asarray(vecs_np[missing[m0:m0 + step]]), rv,
+                impl=impl)), axis=1)]
+            for m0 in range(0, missing.size, step)])
         for m, h in zip(missing, host):
             row = nbrs[h]
             free = np.flatnonzero(row == NO_NODE)
@@ -459,6 +467,16 @@ def _repair_connectivity(vecs_np: np.ndarray, nbrs: np.ndarray, start: int,
 # ---------------------------------------------------------------------------
 # public builders
 # ---------------------------------------------------------------------------
+
+def _mean_nbr_dist(rows: Array, vecs: Array, nbrs: Array, *,
+                   impl: str | None) -> Array:
+    """Mean L2 distance from each row to its valid neighbors."""
+    nd = jnp.sqrt(ops.rowwise_sq_dists(rows, vecs[jnp.clip(nbrs, 0)],
+                                       impl=impl))
+    mask = nbrs != NO_NODE
+    return jnp.sum(jnp.where(mask, nd, 0.0), axis=1) / jnp.maximum(
+        jnp.sum(mask, axis=1), 1)
+
 
 def build_index(vecs, *, k: int = 48, degree: int = 32, n_data: int | None = None,
                 prune_block: int = 1024, seed: int = 0,
@@ -543,13 +561,16 @@ def build_index(vecs, *, k: int = 48, degree: int = 32, n_data: int | None = Non
     nbrs = _add_reverse_edges(nbrs)
     nbrs = _repair_connectivity(vecs_np, nbrs, start, impl)
     nbrs = _add_reverse_edges(nbrs)  # make repair spokes two-way as well
-    # OOD side table (paper §4.5): mean L2 (not squared) neighbor distance.
+    # OOD side table (paper §4.5): mean L2 (not squared) neighbor distance,
+    # in row blocks — the gathered (rows, R, d) neighbor tensor of a whole
+    # million-row table would not fit in device memory (the rowwise
+    # kernel pads R to a 128-lane tile).
     nbrs_j = jnp.asarray(nbrs)
-    nvecs = vecs[jnp.clip(nbrs_j, 0)]
-    nd = jnp.sqrt(ops.rowwise_sq_dists(vecs, nvecs, impl=impl))
-    mask = nbrs_j != NO_NODE
-    mnd = jnp.sum(jnp.where(mask, nd, 0.0), axis=1) / jnp.maximum(
-        jnp.sum(mask, axis=1), 1)
+    rows = max(1, _TILE_ELEMS // (max(degree, 128) * max(d, 1)))
+    mnd = jnp.concatenate([
+        _mean_nbr_dist(vecs[b0:b0 + rows], vecs, nbrs_j[b0:b0 + rows],
+                       impl=impl)
+        for b0 in range(0, n, rows)])
     return GraphIndex(vecs=vecs, nbrs=nbrs_j, start=jnp.int32(start),
                       mean_nbr_dist=mnd,
                       n_data=int(n if n_data is None else n_data))

@@ -177,6 +177,23 @@ def exact_join_pairs(X, Y, theta: float, *, block: int = 1024,
     return pairs
 
 
+def theta_band(X, Y, theta: float) -> set:
+    """Pairs whose float64 squared distance lies within the f32 matmul
+    rounding guard of θ² (host numpy, small inputs only).
+
+    On these pairs two correct f32 evaluations may disagree — the
+    matmul form of the exact NLJ against the difference form of the
+    re-rank, or two partitionings of one reduction — so tests compare
+    pair sets up to this band."""
+    from repro.quant.cascade import MATMUL_GUARD
+    x = np.asarray(X, np.float64)
+    y = np.asarray(Y, np.float64)
+    d2 = np.sum((x[:, None, :] - y[None, :, :]) ** 2, axis=-1)
+    norms = np.sum(x * x, axis=1)[:, None] + np.sum(y * y, axis=1)[None, :]
+    qi, yi = np.nonzero(np.abs(d2 - theta * theta) <= MATMUL_GUARD * norms)
+    return set(zip(qi.tolist(), yi.tolist()))
+
+
 # ---------------------------------------------------------------------------
 # one-shot compatibility wrapper over the engine
 # ---------------------------------------------------------------------------

@@ -276,6 +276,12 @@ class JoinEngine:
             t0 = time.perf_counter()
             hit = distributed.build_sharded_merged_index(
                 self.Y, np.asarray(X), self.n_shards, **self.build_kw)
+            if self._mesh is not None:
+                mesh, axes = self._mesh, self._shard_axes
+            else:
+                plan = self._mesh_plan(traversal=True)
+                mesh, axes = plan.make_mesh(), plan.data_axis
+            hit = distributed.place_sharded_index(hit, mesh, axes)
             self.build_seconds += time.perf_counter() - t0
             self.build_counts["sharded"] += 1
             self._sharded.put(fp, hit)

@@ -29,6 +29,17 @@ from jax.experimental import pallas as pl
 Array = jax.Array
 
 
+def f32_precision(dtype):
+    """Contraction precision for the matmul-form distance kernels: f32
+    operands contract at full f32 (``HIGHEST``), because the certified
+    bounds and the exact NLJ assume f32 rounding and the TPU's default
+    may contract f32 at bf16 precision. Narrower inputs keep the default:
+    their products are exact in the f32 accumulator (and Mosaic rejects
+    ``HIGHEST`` for them)."""
+    return (jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32
+            else None)
+
+
 # ---------------------------------------------------------------------------
 # pairwise: (B, d) x (N, d) -> (B, N) squared L2, matmul form
 # ---------------------------------------------------------------------------
@@ -43,6 +54,7 @@ def _pairwise_kernel(x_ref, y_ref, xn_ref, yn_ref, o_ref, *, nk: int):
     o_ref[...] += jax.lax.dot_general(
         x_ref[...], y_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=f32_precision(x_ref.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)

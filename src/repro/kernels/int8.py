@@ -3,8 +3,9 @@
 Companions of the f32 kernels in ``distance.py``, operating on QuantStore
 codes (per-dimension-group scaled int8; see ``repro.quant.store``). Both
 kernels step the k-grid one *dimension group* at a time, so the per-group
-dequantization scale is a scalar fetch per step and the inner arithmetic
-stays in the integer domain:
+dequantization scale is a scalar read per step (the (G,) scale vector
+lives whole in SMEM — a (1, 1) VMEM block is not a legal TPU tile) and
+the inner arithmetic stays in the integer domain:
 
   * ``pairwise`` — int8×int8 ``dot_general`` accumulating in int32 (the
     MXU's native int8 path), dequantized per group into the f32 output
@@ -32,6 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
@@ -52,7 +54,7 @@ def _pairwise_i8_kernel(x_ref, y_ref, s_ref, xn_ref, yn_ref, o_ref, *,
         x_ref[...], y_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32)          # int8×int8 → int32 (MXU)
-    s = s_ref[0, 0]
+    s = s_ref[k]
     o_ref[...] += (s * s) * acc.astype(jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -87,14 +89,14 @@ def pairwise_sq_dists_int8_pallas(qx: Array, qy: Array, scales: Array,
         in_specs=[
             pl.BlockSpec((bm, group_size), lambda i, j, k: (i, k)),
             pl.BlockSpec((bn, group_size), lambda i, j, k: (j, k)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, k)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
-    )(qx, qy, scales.reshape(1, nk), xn.reshape(B, 1), yn.reshape(1, N))
+    )(qx, qy, scales, xn.reshape(B, 1), yn.reshape(1, N))
 
 
 # ---------------------------------------------------------------------------
@@ -112,7 +114,7 @@ def _rowwise_i8_kernel(x_ref, c_ref, s_ref, o_ref):
     cb = c_ref[...].astype(jnp.int32)              # (bm, bkk, gs)
     diff = cb - xb[:, None, :]
     ssq = jnp.sum(diff * diff, axis=-1)            # int32, no overflow
-    s = s_ref[0, 0]
+    s = s_ref[kd]
     o_ref[...] += (s * s) * ssq.astype(jnp.float32)
 
 
@@ -135,9 +137,9 @@ def rowwise_sq_dists_int8_pallas(qx: Array, qcands: Array, scales: Array, *,
         in_specs=[
             pl.BlockSpec((bm, group_size), lambda i, j, k: (i, k)),
             pl.BlockSpec((bm, bkk, group_size), lambda i, j, k: (i, j, k)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, k)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bkk), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, K), jnp.float32),
         interpret=interpret,
-    )(qx, qcands, scales.reshape(1, nk))
+    )(qx, qcands, scales)

@@ -18,6 +18,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.distance import f32_precision
 
 Array = jax.Array
 
@@ -38,6 +41,7 @@ def _nlj_kernel(x_ref, y_ref, xn_ref, yn_ref, cnt_ref, acc_ref, *,
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], y_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=f32_precision(x_ref.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -71,11 +75,7 @@ def nlj_count_pallas(x: Array, y: Array, theta: float, *, bm: int = 256,
     grid = (B // bm, N // bn, nk)
     kernel = functools.partial(_nlj_kernel, nk=nk,
                                theta_sq=float(theta) ** 2)
-    try:
-        from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
-        scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
-    except ImportError:  # pragma: no cover
-        scratch = [pl.VMEM((bm, bn), jnp.float32)]
+    scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
     return pl.pallas_call(
         kernel,
         grid=grid,

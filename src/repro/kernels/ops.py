@@ -5,6 +5,8 @@ Implementation selection:
   * ``pallas_interpret``  — Pallas interpret mode (CPU validation of the
                             exact kernel bodies; used by tests).
   * ``ref``               — pure-jnp oracle (fast on CPU; default off-TPU).
+  * ``xla``               — ``topk_merge`` only: ``lax.top_k``, its
+                            default on every backend.
 
 Wrappers pad inputs to block-divisible shapes and slice results back, with
 padding arranged so it can never contaminate results (padded data rows get
@@ -34,6 +36,7 @@ import jax.numpy as jnp
 
 from repro.kernels import bits as _bits
 from repro.kernels import distance as _distance
+from repro.kernels import gather_distance as _gd
 from repro.kernels import int8 as _int8
 from repro.kernels import nlj as _nlj
 from repro.kernels import pdx as _pdx
@@ -46,6 +49,18 @@ _BIG = jnp.float32(1e30)
 
 def default_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
+# entry name → every implementation it resolved to, recorded when the
+# entry is traced. ``chip_smoke.py`` reads it to prove that no reference
+# or interpret-mode path ran on the chip.
+IMPLS_USED: dict[str, set[str]] = {}
+
+
+def _resolve(entry: str, impl: str | None) -> str:
+    impl = impl or default_impl()
+    IMPLS_USED.setdefault(entry, set()).add(impl)
+    return impl
 
 
 def _round_up(v: int, m: int) -> int:
@@ -86,7 +101,7 @@ def _pad_axis(a: Array, n: int, axis: int, fill: float = 0.0) -> Array:
 @functools.partial(jax.jit, static_argnames=("impl",))
 def pairwise_sq_dists(x: Array, y: Array, *, impl: str | None = None) -> Array:
     """(B, d) × (N, d) → (B, N) f32 squared L2 distances."""
-    impl = impl or default_impl()
+    impl = _resolve("pairwise_sq_dists", impl)
     B, d = x.shape
     N, _ = y.shape
     if B == 0 or N == 0 or d == 0:
@@ -106,7 +121,7 @@ def pairwise_sq_dists(x: Array, y: Array, *, impl: str | None = None) -> Array:
 @functools.partial(jax.jit, static_argnames=("impl",))
 def rowwise_sq_dists(x: Array, cands: Array, *, impl: str | None = None) -> Array:
     """(B, d) × (B, K, d) → (B, K) f32 per-query candidate distances."""
-    impl = impl or default_impl()
+    impl = _resolve("rowwise_sq_dists", impl)
     B, d = x.shape
     _, K, _ = cands.shape
     if B == 0 or K == 0 or d == 0:
@@ -127,7 +142,7 @@ def rowwise_sq_dists(x: Array, cands: Array, *, impl: str | None = None) -> Arra
 def nlj_count(x: Array, y: Array, *, theta: float,
               impl: str | None = None) -> Array:
     """Exact per-query join counts |{j : dist(x_b, y_j) < theta}| → (B,) i32."""
-    impl = impl or default_impl()
+    impl = _resolve("nlj_count", impl)
     B, d = x.shape
     N, _ = y.shape
     if B == 0:
@@ -160,8 +175,20 @@ def nlj_mask(x: Array, y: Array, *, theta: float,
 def topk_merge(beam_dist: Array, beam_idx: Array, cand_dist: Array,
                cand_idx: Array, *, impl: str | None = None
                ) -> tuple[Array, Array]:
-    """Merge sorted beam with candidates; keep L smallest."""
-    impl = impl or "ref"   # argsort is fine on CPU; kernel is the TPU path
+    """Merge sorted beam with candidates; keep L smallest.
+
+    The default on every backend is ``xla``: ``lax.top_k`` over the
+    concatenated row, which keeps the ``ref`` argsort's stable order
+    (equal distances keep their position order) without sorting the
+    whole row. The Pallas rank-select kernel materializes a (B, K, K)
+    comparison tensor, far too large at the index build's K = 8192."""
+    impl = _resolve("topk_merge", impl or "xla")
+    if impl == "xla":
+        L = beam_dist.shape[-1]
+        alld = jnp.concatenate([beam_dist, cand_dist], axis=-1)
+        alli = jnp.concatenate([beam_idx, cand_idx], axis=-1)
+        neg, pos = jax.lax.top_k(-alld, L)
+        return -neg, jnp.take_along_axis(alli, pos, axis=-1)
     if impl == "ref":
         return _ref.topk_merge(beam_dist, beam_idx, cand_dist, cand_idx)
     from repro.kernels import topk_merge as _tk
@@ -178,7 +205,7 @@ def gather_sq_dists(vecs: Array, x: Array, idx: Array, *,
     NO_NODE (-1) slots come back +inf. The Pallas path fuses the gather
     with the distance (ids scalar-prefetched; see kernels/gather_distance).
     """
-    impl = impl or default_impl()
+    impl = _resolve("gather_sq_dists", impl)
     B, K = idx.shape
     if B == 0 or K == 0:
         return jnp.zeros((B, K), jnp.float32)
@@ -187,10 +214,26 @@ def gather_sq_dists(vecs: Array, x: Array, idx: Array, *,
     if impl == "ref":
         d = _ref.rowwise_sq_dists(x, vecs[safe])
     else:
-        from repro.kernels import gather_distance as _gd
-        d = _gd.gather_sq_dists_pallas(
-            vecs, x, safe, interpret=(impl == "pallas_interpret"))
+        Bp = _round_up(B, _gd.ROWS)
+        xp, ip = _pad_rows(x, Bp), _pad_rows(safe, Bp)
+        d = jnp.concatenate([
+            _gd.gather_sq_dists_pallas(
+                vecs, xp[r0:r1], ip[r0:r1],
+                interpret=(impl == "pallas_interpret"))
+            for r0, r1 in _prefetch_chunks(Bp, K)])[:B]
     return jnp.where(valid, d, jnp.float32(jnp.inf))
+
+
+# ids per scalar-prefetched gather call: 128 KiB of int32, well inside the
+# v5e's 1 MiB SMEM (a (256, 1024) id matrix in one call overflows it)
+_PREFETCH_IDS = 1 << 15
+
+
+def _prefetch_chunks(B: int, K: int) -> list[tuple[int, int]]:
+    """Row ranges of a (B, K) id matrix, B a multiple of 8, such that each
+    range's ids fit one scalar-prefetch buffer."""
+    step = max(_gd.ROWS, _PREFETCH_IDS // max(K, 1) // _gd.ROWS * _gd.ROWS)
+    return [(r0, min(r0 + step, B)) for r0 in range(0, B, step)]
 
 
 # ---------------------------------------------------------------------------
@@ -311,7 +354,7 @@ def pairwise_sq_dists_int8(qx: Array, qy: Array, scales: Array, *,
     ``quant.store.quantize_queries``). ``xn``/``yn`` are the dequantized
     squared norms; pass the QuantStore's stored norms to skip recompute.
     """
-    impl = impl or default_impl()
+    impl = _resolve("pairwise_sq_dists_int8", impl)
     B, d = qx.shape
     N, _ = qy.shape
     if B == 0 or N == 0 or d == 0:
@@ -346,7 +389,7 @@ def rowwise_sq_dists_int8(qx: Array, qcands: Array, scales: Array, *,
     Difference form — exact on a shared scale grid; the kernel moves d×1
     bytes per candidate instead of the f32 path's d×4.
     """
-    impl = impl or default_impl()
+    impl = _resolve("rowwise_sq_dists_int8", impl)
     B, d = qx.shape
     _, K, _ = qcands.shape
     if B == 0 or K == 0 or d == 0:
@@ -395,7 +438,7 @@ def pairwise_sq_dists_pdx(qx: Array, qy: Array, scales: Array,
     kernel is a plain slab-ordered accumulation (``nscan`` = S) whose
     survivor sums are bit-identical to the early-exit run's.
     """
-    impl = impl or default_impl()
+    impl = _resolve("pairwise_sq_dists_pdx", impl)
     B = qx.shape[0]
     N = qy.shape[0]
     if B == 0 or N == 0:
@@ -435,7 +478,7 @@ def pdx_gather_sq_dists(vp: Array, vtail: Array, vnorm: Array, xp: Array,
     is the traced θ² retirement threshold; retired lanes are +inf, and
     survivors carry the slab-ordered f32 sum (bit-identical on/off).
     """
-    impl = impl or default_impl()
+    impl = _resolve("pdx_gather_sq_dists", impl)
     B, K = idx.shape
     if B == 0 or K == 0:
         return (jnp.zeros((B, K), jnp.float32), jnp.zeros((B, K), jnp.int32))
@@ -449,10 +492,16 @@ def pdx_gather_sq_dists(vp: Array, vtail: Array, vnorm: Array, xp: Array,
             slab=slab, dim=dim, early_exit=early_exit)
     else:
         guard, guard_abs = _pdx_guards(dim)
-        d, ns = _pdx.pdx_gather_sq_dists_pallas(
-            vp, vtail, vnorm, xp, xtail, xn, safe, th2, guard=guard,
-            guard_abs=guard_abs, early_exit=early_exit,
-            interpret=(impl == "pallas_interpret"))
+        Bp = _round_up(B, _gd.ROWS)
+        xp, xtail, xn, safe_p = (_pad_rows(a, Bp)
+                                 for a in (xp, xtail, xn, safe))
+        parts = [_pdx.pdx_gather_sq_dists_pallas(
+            vp, vtail, vnorm, xp[r0:r1], xtail[r0:r1], xn[r0:r1],
+            safe_p[r0:r1], th2, guard=guard, guard_abs=guard_abs,
+            early_exit=early_exit, interpret=(impl == "pallas_interpret"))
+            for r0, r1 in _prefetch_chunks(Bp, K)]
+        d = jnp.concatenate([p[0] for p in parts])[:B]
+        ns = jnp.concatenate([p[1] for p in parts])[:B]
     return (jnp.where(valid, d, jnp.float32(jnp.inf)),
             jnp.where(valid, ns, 0))
 
@@ -499,7 +548,7 @@ def pairwise_hamming(cx: Array, cy: Array, *, impl: str | None = None
 
     Counts convert to certified L2 lower bounds via the per-vector slack
     tables (``quant.sketch.sketch_lower_bound_pairwise``)."""
-    impl = impl or default_impl()
+    impl = _resolve("pairwise_hamming", impl)
     B, W = cx.shape
     N, _ = cy.shape
     if B == 0 or N == 0 or W == 0:
@@ -520,7 +569,7 @@ def rowwise_hamming(cx: Array, ccands: Array, *, impl: str | None = None
                     ) -> Array:
     """(B, W) × (B, K, W) uint32 → (B, K) int32 Hamming counts over
     per-query gathered candidate codes (the traversal's shape)."""
-    impl = impl or default_impl()
+    impl = _resolve("rowwise_hamming", impl)
     B, W = cx.shape
     _, K, _ = ccands.shape
     if B == 0 or K == 0 or W == 0:

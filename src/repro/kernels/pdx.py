@@ -38,10 +38,17 @@ retirement certifies the full f32 sum would exceed the threshold.
     per-lane ``@pl.when(live)`` skips the whole DMA'd-row reduction for
     retired lanes.
 
-Tiling note: ``slab`` is the lane dimension of every vector block; the
-default (64) is half a lane tile — fine in interpret mode and on Mosaic
-with lane padding, but on real TPUs a 128-multiple slab maximizes tile
-utilization (pass ``slab=128`` to ``build_pdx``).
+TPU tiling: a block's last two dims must be multiples of (8, 128) or
+span the whole array. A slab that is a multiple of 128 lanes (or the
+only slab) is its own legal block. A narrower slab (the default 64) is
+scanned through a full-width block: the pairwise kernel takes a per-slab
+copy of the queries with every other slab's codes zeroed (an int8 dot
+over exact zeros adds exactly 0), and the gather kernel masks the
+squared differences to slab ``k``'s lanes. Per-slab tables are taken
+whole along ``S`` and the column for slab ``k`` is picked with a one-hot
+mask (exact); scales and the threshold are scalars in SMEM; the gather
+kernel reads 8-row blocks and selects its row, as in
+``gather_distance.py``.
 """
 from __future__ import annotations
 
@@ -50,14 +57,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_TPU_NS = True
-except ImportError:  # pragma: no cover
-    _HAVE_TPU_NS = False
+from repro.kernels.gather_distance import ROWS, put_slot, select_row
 
 Array = jax.Array
+
+
+def lane_aligned(slab: int, n_slab: int) -> bool:
+    """Whether one slab is a legal TPU block on its own (a multiple of
+    the 128-lane tile, or the whole row)."""
+    return slab % 128 == 0 or n_slab == 1
+
+
+def _pick(block: Array, axis: int, k) -> Array:
+    """Entry ``k`` along ``axis`` of a 2-D block (keepdims), by one-hot
+    masking — exact, and no dynamic lane or sublane slicing."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, block.shape, axis)
+    return jnp.sum(jnp.where(pos == k, block, 0), axis=axis, keepdims=True)
+
+
+def _pick2(block: Array, r, c) -> Array:
+    """Entry (r, c) of a 2-D block as a (1, 1) array (exact)."""
+    return _pick(_pick(block, 0, r), 1, c)
 
 
 # ---------------------------------------------------------------------------
@@ -80,8 +102,8 @@ def _pairwise_pdx_kernel(x_ref, y_ref, s_ref, xsl_ref, ysl_ref, xtl_ref,
             x_ref[...], y_ref[...],
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.int32)      # int8×int8 → int32 (MXU)
-        s = s_ref[0, 0]
-        c = (xsl_ref[...] + ysl_ref[...]
+        s = s_ref[k]
+        c = (_pick(xsl_ref[...], 1, k) + _pick(ysl_ref[...], 0, k)
              - 2.0 * (s * s) * dot.astype(jnp.float32))
         return jnp.maximum(c, 0.0)                 # monotone partial sums
 
@@ -94,9 +116,10 @@ def _pairwise_pdx_kernel(x_ref, y_ref, s_ref, xsl_ref, ysl_ref, xtl_ref,
         return
 
     energy = xn_ref[...] + yn_ref[...]                       # (bm, bn)
-    th = ((th_ref[0, 0] + xe_ref[...] + ye_ref[...]) ** 2
+    th = ((th_ref[0] + xe_ref[...] + ye_ref[...]) ** 2
           + jnp.float32(mguard) * energy)
-    rt = (jnp.sqrt(xtl_ref[...]) - jnp.sqrt(ytl_ref[...])) ** 2
+    rt = (jnp.sqrt(_pick(xtl_ref[...], 1, k))
+          - jnp.sqrt(_pick(ytl_ref[...], 0, k))) ** 2
     tl = jnp.maximum(rt - jnp.float32(guard) * energy
                      - jnp.float32(guard_abs), 0.0)
     acc = o_ref[...]
@@ -144,22 +167,33 @@ def pairwise_sq_dists_pdx_pallas(qx: Array, qy: Array, scales: Array,
     kernel = functools.partial(
         _pairwise_pdx_kernel, nk=S, guard=guard, guard_abs=guard_abs,
         mguard=mguard, early_exit=early_exit)
+    if lane_aligned(slab, S):
+        xs = qx
+        x_spec = pl.BlockSpec((bm, slab), lambda i, j, k: (i, k))
+        y_spec = pl.BlockSpec((bn, slab), lambda i, j, k: (j, k))
+    else:
+        # one copy of the query codes per slab, zero outside it
+        own = (jnp.arange(dp)[None, :] // slab) == jnp.arange(S)[:, None]
+        xs = jnp.where(own[:, None, :], qx[None], jnp.int8(0))
+        x_spec = pl.BlockSpec((None, bm, dp), lambda i, j, k: (k, i, 0))
+        y_spec = pl.BlockSpec((bn, dp), lambda i, j, k: (j, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, slab), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, slab), lambda i, j, k: (j, k)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, k)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, k)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, k)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (k, j)),
+            x_spec,
+            y_spec,
+            smem,
+            pl.BlockSpec((bm, S), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((S, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec((bm, S), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((S, bn), lambda i, j, k: (0, j)),
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
+            smem,
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
@@ -170,9 +204,9 @@ def pairwise_sq_dists_pdx_pallas(qx: Array, qy: Array, scales: Array,
             jax.ShapeDtypeStruct((B, N), jnp.int32),
         ],
         interpret=interpret,
-    )(qx, qy, scales.reshape(1, S), xslab, yslab.T, xtail, ytail.T,
+    )(xs, qy, scales, xslab, yslab.T, xtail, ytail.T,
       xn.reshape(B, 1), yn.reshape(1, N), xe.reshape(B, 1),
-      ye.reshape(1, N), jnp.asarray(theta, jnp.float32).reshape(1, 1))
+      ye.reshape(1, N), jnp.asarray(theta, jnp.float32).reshape(1))
 
 
 # ---------------------------------------------------------------------------
@@ -180,43 +214,53 @@ def pairwise_sq_dists_pdx_pallas(qx: Array, qy: Array, scales: Array,
 # ---------------------------------------------------------------------------
 
 def _gather_pdx_kernel(idx_ref, x_ref, xtl_ref, xn_ref, v_ref, vtl_ref,
-                       vn_ref, th_ref, o_ref, ns_ref, *, nk: int,
-                       guard: float, guard_abs: float, early_exit: bool):
-    k = pl.program_id(2)
+                       vn_ref, th_ref, o_ref, ns_ref, *, nk: int, slab: int,
+                       masked: bool, guard: float, guard_abs: float,
+                       early_exit: bool):
+    i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    r = i % ROWS                     # query row within its 8-row block
+    rv = idx_ref[i, j] % ROWS        # candidate row within its block
 
     @pl.when(k == 0)
     def _zero():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        ns_ref[...] = jnp.zeros_like(ns_ref)
+        put_slot(o_ref, r, j, jnp.zeros((1, 1), jnp.float32))
+        put_slot(ns_ref, r, j, jnp.zeros((1, 1), jnp.int32))
 
     def _contrib():
-        diff = x_ref[...] - v_ref[...]
-        return jnp.sum(diff * diff, axis=-1, keepdims=True)
+        diff = select_row(x_ref[...], r) - select_row(v_ref[...], rv)
+        sq = diff * diff
+        if masked:                   # full-width block: slab k's lanes
+            lane = jax.lax.broadcasted_iota(jnp.int32, sq.shape, 1)
+            sq = jnp.where(lane // slab == k, sq, 0.0)
+        return jnp.sum(sq, axis=-1, keepdims=True)            # (1, 1)
 
+    acc = _pick2(o_ref[...], r, j)
+    scanned = _pick2(ns_ref[...], r, j)
     if not early_exit:
-        o_ref[...] += _contrib()
+        put_slot(o_ref, r, j, acc + _contrib())
 
         @pl.when(k == nk - 1)
         def _done():
-            ns_ref[...] = jnp.full_like(ns_ref, nk)
+            put_slot(ns_ref, r, j, jnp.full((1, 1), nk, jnp.int32))
         return
 
-    energy = xn_ref[0, 0] + vn_ref[0, 0]
-    rt = (jnp.sqrt(xtl_ref[0, 0]) - jnp.sqrt(vtl_ref[0, 0])) ** 2
+    energy = _pick(xn_ref[...], 0, r) + _pick(vn_ref[...], 0, rv)
+    rt = (jnp.sqrt(_pick2(xtl_ref[...], r, k))
+          - jnp.sqrt(_pick2(vtl_ref[...], rv, k))) ** 2
     tl = jnp.maximum(rt - jnp.float32(guard) * energy
                      - jnp.float32(guard_abs), 0.0)
-    acc = o_ref[0, 0]
-    scanned = ns_ref[0, 0]
-    live = (scanned == k) & (acc + tl <= th_ref[0, 0])
+    live = (scanned == k) & (acc + tl <= th_ref[0])
 
-    @pl.when(live)                   # retired lane: skip the reduction
+    @pl.when(jnp.any(live))          # retired lane: skip the reduction
     def _scan():
-        o_ref[...] = acc + _contrib()
-        ns_ref[...] = jnp.full_like(ns_ref, k + 1)
+        put_slot(o_ref, r, j, acc + _contrib())
+        put_slot(ns_ref, r, j, jnp.full((1, 1), k + 1, jnp.int32))
 
     @pl.when(k == nk - 1)
     def _epilogue():
-        o_ref[...] = jnp.where(ns_ref[...] == nk, o_ref[...], jnp.inf)
+        done = _pick2(ns_ref[...], r, j) == nk
+        put_slot(o_ref, r, j,
+                 jnp.where(done, _pick2(o_ref[...], r, j), jnp.inf))
 
 
 def pdx_gather_sq_dists_pallas(vp: Array, vtail: Array, vnorm: Array,
@@ -231,6 +275,7 @@ def pdx_gather_sq_dists_pallas(vp: Array, vtail: Array, vnorm: Array,
       xp: (B, S·slab) f32 PDX queries; xtail: (B, S); xn: (B,).
       idx: (B, K) int32 ids, pre-clamped to [0, N) by the wrapper.
       th2: traced f32 θ² retirement threshold.
+    B must be a multiple of 8 (the ops.py wrapper pads).
     Returns:
       (dist, nscan): (B, K) f32 (+inf where retired), (B, K) int32.
     """
@@ -238,28 +283,39 @@ def pdx_gather_sq_dists_pallas(vp: Array, vtail: Array, vnorm: Array,
     _, K = idx.shape
     N, S = vtail.shape
     slab = dp // S
-    grid = (B, K, S)
+    assert B % ROWS == 0, (B, ROWS)
+    masked = not lane_aligned(slab, S)
+    w = dp if masked else slab
     kernel = functools.partial(
-        _gather_pdx_kernel, nk=S, guard=guard, guard_abs=guard_abs,
-        early_exit=early_exit)
+        _gather_pdx_kernel, nk=S, slab=slab, masked=masked, guard=guard,
+        guard_abs=guard_abs, early_exit=early_exit)
+
+    def lanes(k):
+        return 0 if masked else k
+
+    def own(i, j, k, idx_ref):
+        return (i // ROWS, 0)
+
+    def cand(i, j, k, idx_ref):
+        return (idx_ref[i, j] // ROWS, 0)
+
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
+        grid=(B, K, S),
         in_specs=[
-            pl.BlockSpec((1, slab), lambda i, j, k, idx_ref: (i, k)),
-            pl.BlockSpec((1, 1), lambda i, j, k, idx_ref: (i, k)),
-            pl.BlockSpec((1, 1), lambda i, j, k, idx_ref: (i, 0)),
-            pl.BlockSpec((1, slab),
-                         lambda i, j, k, idx_ref: (idx_ref[i, j], k)),
-            pl.BlockSpec((1, 1),
-                         lambda i, j, k, idx_ref: (idx_ref[i, j], k)),
-            pl.BlockSpec((1, 1),
-                         lambda i, j, k, idx_ref: (idx_ref[i, j], 0)),
-            pl.BlockSpec((1, 1), lambda i, j, k, idx_ref: (0, 0)),
+            pl.BlockSpec((ROWS, w),
+                         lambda i, j, k, idx_ref: (i // ROWS, lanes(k))),
+            pl.BlockSpec((ROWS, S), own),
+            pl.BlockSpec((ROWS, 1), own),
+            pl.BlockSpec((ROWS, w), lambda i, j, k, idx_ref: (
+                idx_ref[i, j] // ROWS, lanes(k))),
+            pl.BlockSpec((ROWS, S), cand),
+            pl.BlockSpec((ROWS, 1), cand),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, k, idx_ref: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k, idx_ref: (i, j)),
+            pl.BlockSpec((ROWS, K), own),
+            pl.BlockSpec((ROWS, K), own),
         ],
     )
     return pl.pallas_call(
@@ -271,4 +327,4 @@ def pdx_gather_sq_dists_pallas(vp: Array, vtail: Array, vnorm: Array,
         ],
         interpret=interpret,
     )(idx, xp, xtail, xn.reshape(B, 1), vp, vtail, vnorm.reshape(N, 1),
-      jnp.asarray(th2, jnp.float32).reshape(1, 1))
+      jnp.asarray(th2, jnp.float32).reshape(1))

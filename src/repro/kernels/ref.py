@@ -26,7 +26,8 @@ def pairwise_sq_dists(x: Array, y: Array) -> Array:
     y = y.astype(jnp.float32)
     xn = jnp.sum(x * x, axis=-1, keepdims=True)          # (B, 1)
     yn = jnp.sum(y * y, axis=-1, keepdims=True).T        # (1, N)
-    xy = x @ y.T                                         # (B, N)
+    xy = jnp.matmul(x, y.T,                              # (B, N)
+                    precision=jax.lax.Precision.HIGHEST)
     d = xn + yn - 2.0 * xy
     return jnp.maximum(d, 0.0)
 

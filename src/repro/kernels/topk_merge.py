@@ -55,10 +55,11 @@ def _kernel(bd_ref, bi_ref, cd_ref, ci_ref, od_ref, oi_ref, *, L: int,
     slot = jax.lax.broadcasted_iota(jnp.float32, (1, 1, L), 2)
     oh_b = (rank_b[:, :, None] == slot).astype(jnp.float32)        # (bm,L,L)
     oh_c = (rank_c[:, :, None] == slot).astype(jnp.float32)        # (bm,K,L)
-    od = (jnp.einsum("blk,bl->bk", oh_b, bd)
-          + jnp.einsum("blk,bl->bk", oh_c, cd))
-    oi = (jnp.einsum("blk,bl->bk", oh_b, bi)
-          + jnp.einsum("blk,bl->bk", oh_c, ci))
+    hi = jax.lax.Precision.HIGHEST      # ids ride through f32: keep them exact
+    od = (jnp.einsum("blk,bl->bk", oh_b, bd, precision=hi)
+          + jnp.einsum("blk,bl->bk", oh_c, cd, precision=hi))
+    oi = (jnp.einsum("blk,bl->bk", oh_b, bi, precision=hi)
+          + jnp.einsum("blk,bl->bk", oh_c, ci, precision=hi))
     # empty slots (total valid < L never happens here: beam is L-long) —
     # but +inf beam entries carry through as +inf naturally
     filled = ((jnp.sum(oh_b, axis=1) + jnp.sum(oh_c, axis=1)) > 0) \

@@ -22,6 +22,7 @@ from repro.configs.vectorjoin import ENGINE_PRESETS, make_engine, preset
 from repro.core import exact_join_pairs
 from repro.core.types import METHODS, QUANT_MODES
 from repro.data.vectors import make_dataset, thresholds
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import trace as obs_trace
 
 
@@ -129,6 +130,7 @@ def main(argv=None) -> int:
                          "(cache hit/miss/eviction/tombstone counters, "
                          "per-shard band gauges, wave histograms)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ds = make_dataset(args.regime, n_data=args.n_data, n_query=args.n_query,
                       dim=args.dim, seed=args.seed)

@@ -22,6 +22,7 @@ from repro.configs.vectorjoin import preset
 from repro.core import exact_join_pairs
 from repro.core.types import QUANT_MODES
 from repro.data.vectors import make_dataset, thresholds
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.join import check_shards, shards_arg
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -85,6 +86,7 @@ def main(argv=None) -> int:
                     help="TraceKit span capture of the serving rounds "
                          "(load at ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     quants = tuple(q.strip() for q in args.quants.split(",") if q.strip())
     for q in quants:

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 
 Array = jax.Array
 PyTree = Any
@@ -63,7 +62,7 @@ def ef_quantized_psum(flat_grad: Array, err: Array, axes) -> tuple[Array,
     n = flat_grad.shape[0]
     dp = 1
     for a in (axes if isinstance(axes, tuple) else (axes,)):
-        dp *= compat.axis_size(a)
+        dp *= jax.lax.axis_size(a)
     target = flat_grad / dp + err
     q, scale = _quantize(target)
     sent = _dequantize(q, scale, n)
@@ -83,7 +82,7 @@ def make_compressed_allreduce(mesh: Mesh, axes, n: int):
     """jit'd (flat_grad, err) -> (reduced, new_err) over ``axes``."""
     spec = P()  # grads replicated within reduction group entry-wise
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(ef_quantized_psum, axes=axes),
         mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec),
         check_vma=False)

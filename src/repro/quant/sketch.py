@@ -136,7 +136,7 @@ def sketch_encode(x: Array, mu: Array, rot: Array, hs: Array
     ``store.quantize_on_grid``).
     """
     x = jnp.asarray(x, jnp.float32)
-    z = (x - mu) @ rot.T
+    z = jnp.matmul(x - mu, rot.T, precision=jax.lax.Precision.HIGHEST)
     codes = _pack_bits(z > 0)
     s = jnp.sort(z * z, axis=1)
     cumfull = jnp.concatenate(

@@ -80,12 +80,11 @@ def test_heartbeat(tmp_path):
 def test_elastic_restore_with_shardings(tmp_path):
     """Restore onto explicit (single-device here; any mesh in prod)
     shardings — the elastic-scaling path."""
-    from repro.core.compat import P
     t = _tree()
     save_tree(t, str(tmp_path / "ck"))
     mesh = jax.make_mesh((1,), ("data",))
     sh = jax.tree.map(
-        lambda _: jax.sharding.NamedSharding(mesh, P()), t)
+        lambda _: jax.sharding.NamedSharding(mesh, jax.P()), t)
     back = restore_tree(str(tmp_path / "ck"), t, shardings=sh)
-    assert all(l.sharding == jax.sharding.NamedSharding(mesh, P())
+    assert all(l.sharding == jax.sharding.NamedSharding(mesh, jax.P())
                for l in jax.tree.leaves(back))

@@ -14,7 +14,6 @@ _SCRIPT = textwrap.dedent("""
     import numpy as np, jax, jax.numpy as jnp
     from repro.data.vectors import make_dataset, thresholds
     from repro.core import exact_join_pairs, TraversalConfig
-    from repro.core import compat
     from repro.core.distributed import (build_sharded_merged_index,
                                         distributed_mi_join,
                                         make_distributed_nlj_count)
@@ -24,10 +23,8 @@ _SCRIPT = textwrap.dedent("""
     truth = set(map(tuple, exact_join_pairs(ds.X, ds.Y, theta).tolist()))
     assert len(truth) > 0
 
-    mesh_kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        mesh_kw["axis_types"] = (jax.sharding.AxisType.Auto,) * 3
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), **mesh_kw)
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     smi = build_sharded_merged_index(ds.Y, ds.X, 4, k=32, degree=16)
     tc = TraversalConfig(beam_width=64, expand_per_iter=4, pool_cap=512,
                          hybrid_beam=64, seeds_max=8, max_iters=1024)
@@ -43,7 +40,7 @@ _SCRIPT = textwrap.dedent("""
     # 2-D sharded exact NLJ == brute force
     nlj = make_distributed_nlj_count(mesh, ("pod", "data"), "model",
                                      theta=theta)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         cnt = np.asarray(nlj(jnp.asarray(ds.X[:32]), jnp.asarray(ds.Y)))
     ref = np.array([(np.linalg.norm(ds.X[i] - ds.Y, axis=1) < theta).sum()
                     for i in range(32)])

@@ -35,6 +35,27 @@ def test_topk_merge_property(L, K, B, seed):
     assert (np.diff(md, axis=1) >= 0).all()
 
 
+@settings(deadline=None, max_examples=30)
+@given(st.integers(2, 24), st.integers(1, 40), st.integers(1, 6),
+       st.integers(0, 2**31 - 1))
+def test_topk_merge_xla_equals_argsort_oracle(L, K, B, seed):
+    """The default ``lax.top_k`` merge returns exactly the stable-argsort
+    oracle's beam — distances and ids — with ties (coarse values) and
+    +inf slots kept in position order."""
+    rng = np.random.default_rng(seed)
+    bd = np.sort(rng.integers(0, 6, (B, L)).astype(np.float32), axis=1)
+    bd[:, L - int(rng.integers(0, L)):] = np.inf
+    cd = rng.integers(0, 6, (B, K)).astype(np.float32)
+    cd[rng.random((B, K)) < 0.2] = np.inf
+    bi = rng.integers(0, 1000, (B, L)).astype(np.int32)
+    ci = rng.integers(0, 1000, (B, K)).astype(np.int32)
+    args = [jnp.asarray(a) for a in (bd, bi, cd, ci)]
+    xd, xi = ops.topk_merge(*args, impl="xla")
+    rd, ri = ops.topk_merge(*args, impl="ref")
+    np.testing.assert_array_equal(np.asarray(xd), np.asarray(rd))
+    np.testing.assert_array_equal(np.asarray(xi), np.asarray(ri))
+
+
 @settings(deadline=None, max_examples=20)
 @given(st.integers(1, 12), st.integers(1, 64), st.integers(2, 48),
        st.integers(0, 2**31 - 1))

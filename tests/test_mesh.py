@@ -109,7 +109,8 @@ _PRELUDE = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np
-    from repro.core import JoinConfig, TraversalConfig, exact_join_pairs
+    from repro.core import (JoinConfig, TraversalConfig, exact_join_pairs,
+                            theta_band)
     from repro.data.vectors import make_dataset, thresholds
     from repro.engine import JoinEngine
 
@@ -118,6 +119,13 @@ _PRELUDE = textwrap.dedent("""
     ds = make_dataset("manifold", n_data=1501, n_query=64, dim=40, seed=42)
     theta = float(thresholds(ds, 3)[0])
     truth = set(map(tuple, exact_join_pairs(ds.X, ds.Y, theta).tolist()))
+    # pairs within f32 rounding of θ: matmul-form and difference-form
+    # evaluations may round them to either side
+    band = theta_band(ds.X, ds.Y, theta)
+
+    def agree(a, b):
+        return not ((a ^ b) - band)
+
     tc = TraversalConfig(beam_width=64, expand_per_iter=4, pool_cap=1024,
                          hybrid_beam=64, seeds_max=8, max_iters=2048)
     BK = dict(k=24, degree=12)
@@ -131,7 +139,7 @@ _MATRIX_SCRIPT = _PRELUDE + textwrap.dedent("""
         cfg = JoinConfig(method="es_mi", theta=theta, traversal=tc,
                          wave_size=32, quant=quant)
         ref = JoinEngine(ds.Y, build_kw=BK, n_shards=1).join(ds.X, cfg)
-        assert ref.pair_set() == truth, (quant, "single-device != truth")
+        assert agree(ref.pair_set(), truth), (quant, "single != truth")
         for s in (2, 4, 8):
             e = JoinEngine(ds.Y, build_kw=BK, n_shards=s)
             r = e.join(ds.X, cfg)
@@ -145,7 +153,7 @@ _MATRIX_SCRIPT = _PRELUDE + textwrap.dedent("""
     cfgn = JoinConfig(method="nlj", theta=theta, traversal=tc, wave_size=32)
     for s in (2, 4, 8):
         rn = JoinEngine(ds.Y, build_kw=BK, n_shards=s).join(ds.X, cfgn)
-        assert rn.pair_set() == truth, (s, len(rn.pair_set() ^ truth))
+        assert agree(rn.pair_set(), truth), (s, len(rn.pair_set() ^ truth))
     print("MESH_MATRIX_OK")
 """)
 
@@ -168,7 +176,8 @@ _STREAM_SCRIPT = _PRELUDE + textwrap.dedent("""
         for b0 in range(0, 64, 16):
             got_ref |= ref.submit(ds.X[b0:b0 + 16], cfg).pair_set()
             got |= e.submit(ds.X[b0:b0 + 16], cfg).pair_set()
-        assert got == got_ref == truth, (method, len(got ^ truth))
+        assert agree(got, got_ref) and agree(got, truth), (
+            method, len(got ^ truth))
         assert len(e._stream_cache) == len(ref._stream_cache)
         assert e.n_submitted == ref.n_submitted == 64
     print("MESH_STREAM_OK")
@@ -240,7 +249,6 @@ def test_hybrid_partition_admissibility_8dev():
 _MI_RING_SCRIPT = _PRELUDE + textwrap.dedent("""
     import jax
     import jax.numpy as jnp
-    from repro.core import compat
     from repro.core import distributed as D
 
     smi = D.build_sharded_merged_index(ds.Y, ds.X, 8, **BK)
@@ -251,8 +259,9 @@ _MI_RING_SCRIPT = _PRELUDE + textwrap.dedent("""
     pp, sp = D.distributed_mi_join(
         ds.X, smi, plan=D.MeshPlan(n_shards=8, pool_combine="ppermute"),
         **kw)
-    assert (set(map(tuple, pp.tolist())) == set(map(tuple, pa.tolist()))
-            == truth), (len(set(map(tuple, pp.tolist())) ^ truth))
+    assert set(map(tuple, pp.tolist())) == set(map(tuple, pa.tolist()))
+    assert agree(set(map(tuple, pp.tolist())), truth), (
+        len(set(map(tuple, pp.tolist())) ^ truth))
     assert sp.bytes_ppermute > 0 and sp.bytes_allgather == 0
     assert sa.bytes_allgather > 0 and sa.bytes_ppermute == 0
 
@@ -267,7 +276,7 @@ _MI_RING_SCRIPT = _PRELUDE + textwrap.dedent("""
             mesh, plan.data_axis, smi, theta=theta, cfg=tc, n_data=1501,
             pool_combine=combine)
         B = 32
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jxp = str(jax.make_jaxpr(step)(
                 smi.vecs, smi.nbrs, smi.mean_nbr_dist, smi.start, *qargs,
                 jnp.asarray(ds.X[:B]), jnp.zeros((B,), jnp.int32),
@@ -291,7 +300,7 @@ _SERVE_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import numpy as np
     from repro.configs.vectorjoin import preset
-    from repro.core import exact_join_pairs
+    from repro.core import exact_join_pairs, theta_band
     from repro.data.vectors import make_dataset, thresholds
     from repro.obs import metrics as obs_metrics
     from repro.serve import JoinRequest, JoinService, ServiceConfig
@@ -310,11 +319,12 @@ _SERVE_SCRIPT = textwrap.dedent("""
     done = svc.run()
     assert obs_metrics.compile_count() == c0, "sharded serve recompiled"
     truth = set(map(tuple, exact_join_pairs(ds.X, ds.Y, theta).tolist()))
+    band = theta_band(ds.X, ds.Y, theta)
     for sj in done.values():
         assert sj.ok
         n = sj.n_queries
         t = {p for p in truth if p[0] < n}
-        assert sj.pair_set_local() == t, (sj.uid, n)
+        assert not ((sj.pair_set_local() ^ t) - band), (sj.uid, n)
     print("MESH_SERVE_OK")
 """)
 

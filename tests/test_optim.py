@@ -91,10 +91,10 @@ def test_ef_psum_single_device_mesh():
     import functools
     from repro.optim.compress import ef_quantized_psum
     mesh = jax.make_mesh((1,), ("data",))
-    from repro.core.compat import P, shard_map
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         functools.partial(ef_quantized_psum, axes=("data",)),
-        mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+        mesh=mesh, in_specs=(jax.P(), jax.P()),
+        out_specs=(jax.P(), jax.P()),
         check_vma=False))
     rng = np.random.default_rng(1)
     g = jnp.asarray(rng.normal(0, 1, 1024).astype(np.float32))

@@ -97,7 +97,8 @@ _SHARD_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     import numpy as np
-    from repro.core import JoinConfig, TraversalConfig, exact_join_pairs
+    from repro.core import (JoinConfig, TraversalConfig, exact_join_pairs,
+                            theta_band)
     from repro.data.vectors import make_dataset, thresholds
     from repro.engine import JoinEngine
 
@@ -108,6 +109,8 @@ _SHARD_SCRIPT = textwrap.dedent("""
     theta = float(thresholds(ds, 3)[0])
     truth = set(map(tuple, exact_join_pairs(ds.X, ds.Y, theta).tolist()))
     assert len(truth) > 0
+    # pairs within f32 rounding of θ may fall to either side
+    band = theta_band(ds.X, ds.Y, theta)
     tc = TraversalConfig(beam_width=64, expand_per_iter=4, pool_cap=1024,
                          hybrid_beam=64, seeds_max=8, max_iters=2048)
     e2 = JoinEngine(ds.Y, build_kw=dict(k=24, degree=12), n_shards=2)
@@ -116,7 +119,8 @@ _SHARD_SCRIPT = textwrap.dedent("""
         cfg = JoinConfig(method="es_mi", theta=theta, traversal=tc,
                          wave_size=32, quant=quant)
         sets[quant] = e2.join(ds.X, cfg).pair_set()
-        assert sets[quant] == truth, (quant, len(sets[quant] ^ truth))
+        assert not ((sets[quant] ^ truth) - band), (
+            quant, len(sets[quant] ^ truth))
     print("QUANT_MODES_SHARDED_OK")
 """)
 

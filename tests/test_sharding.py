@@ -6,13 +6,12 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get
-from repro.core.compat import abstract_mesh
 from repro.models import model as M
 from repro.models import sharding as S
 
 MESHES = {
-    "single": abstract_mesh((16, 16), ("data", "model")),
-    "multi": abstract_mesh((2, 16, 16), ("pod", "data", "model")),
+    "single": jax.sharding.AbstractMesh((16, 16), ("data", "model")),
+    "multi": jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model")),
 }
 
 
