@@ -169,13 +169,14 @@ def memory(dev) -> dict:
 
 def check_impls() -> None:
     """Every kernels.ops entry that ran took the compiled Pallas kernel
-    (``topk_merge``: XLA's ``top_k``, its default on every backend) —
+    (``topk_merge``: XLA's ``top_k``, its one path on every backend) —
     never the ``ref`` oracle or interpret mode."""
     from repro.kernels import ops
     for entry, impls in sorted(ops.IMPLS_USED.items()):
         say(f"kernels.ops.{entry}: {sorted(impls)}")
-        check(impls <= {"pallas", "xla"},
-              f"{entry} ran {sorted(impls - {'pallas', 'xla'})} on the chip")
+        want = {"xla"} if entry == "topk_merge" else {"pallas"}
+        check(impls <= want,
+              f"{entry} ran {sorted(impls - want)} on the chip")
 
 
 def wave_epilogue_hlo(eng, X, cfg) -> str:

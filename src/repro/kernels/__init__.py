@@ -8,4 +8,4 @@
 #                       codes (uint32 XOR + SWAR popcount, VPU)
 #   nlj.py              fused exact join count (distance+compare+count)
 #   gather_distance.py  scalar-prefetch fused neighbor-gather + distance
-#   topk_merge.py       sort-free rank-select beam merge
+#   pdx.py              dimension-partitioned early-exit distances

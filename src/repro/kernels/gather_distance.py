@@ -17,7 +17,10 @@ step instead DMAs the aligned 8-row block holding the candidate
 exact zeros, so the distance is the same f32 sum as a one-row reduction.
 The d axis is taken whole (legal for any d, 100 and 960 included). The
 query row is selected from its 8-row block the same way, and results land
-in an (8, K) output block written back once per 8 queries. Rows past the
+in an (8, K) output block written back once per 8 queries. The price: each
+candidate moves an 8-row block of ``vecs`` (8·d·4 bytes in f32), 8× one
+row, and each step
+rewrites the whole (8, K) block in VMEM to set one slot. Rows past the
 table's end in its last, partial block are never selected. Invalid ids
 (NO_NODE) must be pre-clamped to 0 by the wrapper and masked afterwards.
 

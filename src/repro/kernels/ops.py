@@ -6,7 +6,7 @@ Implementation selection:
                             exact kernel bodies; used by tests).
   * ``ref``               — pure-jnp oracle (fast on CPU; default off-TPU).
   * ``xla``               — ``topk_merge`` only: ``lax.top_k``, its
-                            default on every backend.
+                            one path on every backend.
 
 Wrappers pad inputs to block-divisible shapes and slice results back, with
 padding arranged so it can never contaminate results (padded data rows get
@@ -177,24 +177,20 @@ def topk_merge(beam_dist: Array, beam_idx: Array, cand_dist: Array,
                ) -> tuple[Array, Array]:
     """Merge sorted beam with candidates; keep L smallest.
 
-    The default on every backend is ``xla``: ``lax.top_k`` over the
+    The one path on every backend is ``xla``: ``lax.top_k`` over the
     concatenated row, which keeps the ``ref`` argsort's stable order
     (equal distances keep their position order) without sorting the
-    whole row. The Pallas rank-select kernel materializes a (B, K, K)
-    comparison tensor, far too large at the index build's K = 8192."""
+    whole row. ``ref`` is the oracle."""
     impl = _resolve("topk_merge", impl or "xla")
-    if impl == "xla":
-        L = beam_dist.shape[-1]
-        alld = jnp.concatenate([beam_dist, cand_dist], axis=-1)
-        alli = jnp.concatenate([beam_idx, cand_idx], axis=-1)
-        neg, pos = jax.lax.top_k(-alld, L)
-        return -neg, jnp.take_along_axis(alli, pos, axis=-1)
     if impl == "ref":
         return _ref.topk_merge(beam_dist, beam_idx, cand_dist, cand_idx)
-    from repro.kernels import topk_merge as _tk
-    return _tk.topk_merge_pallas(
-        beam_dist, beam_idx, cand_dist, cand_idx,
-        interpret=(impl == "pallas_interpret"))
+    if impl != "xla":
+        raise ValueError(f"topk_merge has no {impl!r} implementation")
+    L = beam_dist.shape[-1]
+    alld = jnp.concatenate([beam_dist, cand_dist], axis=-1)
+    alli = jnp.concatenate([beam_idx, cand_idx], axis=-1)
+    neg, pos = jax.lax.top_k(-alld, L)
+    return -neg, jnp.take_along_axis(alli, pos, axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))

@@ -44,7 +44,10 @@ only slab) is its own legal block. A narrower slab (the default 64) is
 scanned through a full-width block: the pairwise kernel takes a per-slab
 copy of the queries with every other slab's codes zeroed (an int8 dot
 over exact zeros adds exactly 0), and the gather kernel masks the
-squared differences to slab ``k``'s lanes. Per-slab tables are taken
+squared differences to slab ``k``'s lanes. That keeps the results exact
+but not the work: each slab step does a full-width dot or reduction, so
+a lane that scans all ``S`` slabs costs ``S`` times one full pass (at
+slab 128 a step does one slab's work). Per-slab tables are taken
 whole along ``S`` and the column for slab ``k`` is picked with a one-hot
 mask (exact); scales and the threshold are scalars in SMEM; the gather
 kernel reads 8-row blocks and selects its row, as in

@@ -68,34 +68,6 @@ def test_pairwise_ref_is_true_distance(B, N, d, seed):
     assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
-@settings(max_examples=15, deadline=None)
-@given(st.integers(2, 32), st.integers(2, 40), st.integers(1, 6),
-       st.integers(0, 2**31 - 1))
-def test_topk_merge_pallas_matches_oracle(L, K, B, seed):
-    """Pallas merge == oracle merge on arbitrary beams: distances equal;
-    index multisets equal wherever distances are unique."""
-    rng = np.random.default_rng(seed)
-    bd = np.sort(rng.normal(0, 1, (B, L)).astype(np.float32), axis=1)
-    n_inf = int(rng.integers(0, L))
-    if n_inf:
-        bd[:, L - n_inf:] = np.inf
-    bi = rng.integers(0, 10_000, (B, L)).astype(np.int32)
-    bi[~np.isfinite(bd)] = -1
-    cd = rng.normal(0, 1, (B, K)).astype(np.float32)
-    cd[rng.random((B, K)) < 0.2] = np.inf
-    ci = rng.integers(0, 10_000, (B, K)).astype(np.int32)
-    rd, ri = ops.topk_merge(jnp.asarray(bd), jnp.asarray(bi),
-                            jnp.asarray(cd), jnp.asarray(ci))
-    pd_, pi_ = ops.topk_merge(jnp.asarray(bd), jnp.asarray(bi),
-                              jnp.asarray(cd), jnp.asarray(ci),
-                              impl="pallas_interpret")
-    np.testing.assert_allclose(np.asarray(rd), np.asarray(pd_), rtol=1e-6)
-    fin = np.isfinite(np.asarray(rd))
-    np.testing.assert_array_equal(
-        np.sort(np.where(fin, np.asarray(ri), -2), axis=1),
-        np.sort(np.where(fin, np.asarray(pi_), -2), axis=1))
-
-
 @settings(max_examples=20, deadline=None)
 @given(st.integers(1, 8), st.integers(2, 64), st.integers(1, 96),
        st.sampled_from([8, 16, 64, 128]), st.integers(0, 2**31 - 1))

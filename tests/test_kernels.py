@@ -180,9 +180,10 @@ def test_int8_bounds_bracket_f32_distance(B, N, d, gs):
 
 SHAPES_PDX = [
     # (B, N, d, slab) — slab-multiple / d∤slab / single-slab / d<slab /
-    # tiny slab / ragged B,N below the block sizes
+    # tiny slab / ragged B,N below the block sizes / several lane-aligned
+    # slabs (each slab its own block, no masking)
     (8, 128, 128, 64), (10, 130, 70, 64), (8, 96, 64, 64), (5, 77, 40, 64),
-    (3, 50, 129, 16), (1, 1, 7, 64),
+    (3, 50, 129, 16), (1, 1, 7, 64), (9, 130, 300, 128),
 ]
 
 

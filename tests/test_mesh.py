@@ -176,8 +176,14 @@ _STREAM_SCRIPT = _PRELUDE + textwrap.dedent("""
         for b0 in range(0, 64, 16):
             got_ref |= ref.submit(ds.X[b0:b0 + 16], cfg).pair_set()
             got |= e.submit(ds.X[b0:b0 + 16], cfg).pair_set()
-        assert agree(got, got_ref) and agree(got, truth), (
-            method, len(got ^ truth))
+        if method == "nlj":
+            # one (B, N_y) matmul against four (B, N_y/4) ones: XLA blocks
+            # the reduction by shape, so a pair within f32 rounding of θ
+            # may fall to either side ((18, 733), 4.6e-8 above θ, does)
+            assert agree(got, got_ref), (method, got ^ got_ref)
+        else:
+            assert got == got_ref, (method, len(got ^ got_ref))
+        assert agree(got, truth), (method, len(got ^ truth))
         assert len(e._stream_cache) == len(ref._stream_cache)
         assert e.n_submitted == ref.n_submitted == 64
     print("MESH_STREAM_OK")

@@ -1,5 +1,6 @@
-"""Interpret-mode validation of the gather-distance and topk-merge Pallas
-kernels against the pure-jnp oracles. Hypothesis sweeps live in
+"""Interpret-mode validation of the gather-distance Pallas kernel, and the
+``lax.top_k`` beam merge, against the pure-jnp oracles. Hypothesis sweeps
+live in
 tests/test_kernel_properties.py (they self-skip without the dev extra)."""
 import jax
 import jax.numpy as jnp
@@ -27,8 +28,15 @@ def test_topk_merge_keeps_smallest():
     bi = jnp.asarray([[10, 11, -1, -1]], jnp.int32)
     cd = jnp.asarray([[0.3, 0.05, 0.7]])
     ci = jnp.asarray([[20, 21, 22]], jnp.int32)
-    for impl in ("ref", "pallas_interpret"):
+    for impl in ("ref", "xla"):
         rd, ri = ops.topk_merge(bd, bi, cd, ci, impl=impl)
         np.testing.assert_allclose(np.asarray(rd[0]),
                                    [0.05, 0.1, 0.3, 0.5], rtol=1e-6)
         np.testing.assert_array_equal(np.asarray(ri[0]), [21, 10, 20, 11])
+
+
+def test_topk_merge_rejects_unknown_impl():
+    z = jnp.zeros((1, 2))
+    i = jnp.zeros((1, 2), jnp.int32)
+    with pytest.raises(ValueError, match="pallas"):
+        ops.topk_merge(z, i, z, i, impl="pallas")

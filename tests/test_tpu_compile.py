@@ -30,12 +30,11 @@ f32, i8, i32, u32 = jnp.float32, jnp.int8, jnp.int32, jnp.uint32
 
 @pytest.fixture(scope="module")
 def topo():
+    # the pinned JAX ships the TPU compiler: a failure here is a broken
+    # install, and must show up as one
     from jax.experimental import topologies
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
 
 
 @pytest.fixture(scope="module")
@@ -68,18 +67,18 @@ def _int8_rowwise(S, d):
     return (S((B, d), i8), S((B, K, d), i8), S((-(-d // 128),), f32)), {}
 
 
-def _pdx_pairwise(S, d):
-    ns = -(-d // DEFAULT_SLAB)
-    dp = ns * DEFAULT_SLAB
+def _pdx_pairwise(S, d, slab=DEFAULT_SLAB):
+    ns = -(-d // slab)
+    dp = ns * slab
     args = (S((B, dp), i8), S((N, dp), i8), S((ns,), f32), S((B, ns), f32),
             S((N, ns), f32), S((B, ns), f32), S((N, ns), f32), S((B,), f32),
             S((N,), f32), S((B,), f32), S((N,), f32), S((), f32))
-    return args, dict(slab=DEFAULT_SLAB, dim=d, early_exit=True)
+    return args, dict(slab=slab, dim=d, early_exit=True)
 
 
-def _pdx_gather(S, d):
-    ns = -(-d // DEFAULT_SLAB)
-    dp = ns * DEFAULT_SLAB
+def _pdx_gather(S, d, slab=DEFAULT_SLAB):
+    ns = -(-d // slab)
+    dp = ns * slab
     args = (S((N, dp), f32), S((N, ns), f32), S((N,), f32), S((B, dp), f32),
             S((B, ns), f32), S((B,), f32), S((B, POOL), i32), S((), f32))
     return args, dict(dim=d, early_exit=True)
@@ -107,6 +106,12 @@ KERNELS = {
     "rowwise_sq_dists_int8": (ops.rowwise_sq_dists_int8, _int8_rowwise),
     "pairwise_sq_dists_pdx": (ops.pairwise_sq_dists_pdx, _pdx_pairwise),
     "pdx_gather_sq_dists": (ops.pdx_gather_sq_dists, _pdx_gather),
+    # slab 128: each slab is its own lane-aligned block (no masking)
+    "pairwise_sq_dists_pdx_slab128": (
+        ops.pairwise_sq_dists_pdx,
+        lambda S, d: _pdx_pairwise(S, d, slab=128)),
+    "pdx_gather_sq_dists_slab128": (
+        ops.pdx_gather_sq_dists, lambda S, d: _pdx_gather(S, d, slab=128)),
     "pairwise_hamming": (ops.pairwise_hamming, _hamming_pairwise),
     "rowwise_hamming": (ops.rowwise_hamming, _hamming_rowwise),
 }
