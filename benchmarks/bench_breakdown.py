@@ -14,13 +14,6 @@ slack-table bytes per 1-bit sketch probe, d×4 per exact re-rank). For
 NLJ prefilter at d ≥ 256 at the tight thresholds) → ``n_rerank`` f32
 evaluations. The *offline* half of the story — the cascade driving the
 index build itself — is ``bench_offline.py``.
-
-``run_pipeline`` is the breakdown the sequential table cannot give: the
-*pipelined* path's per-phase seconds, recovered from TraceKit span
-summaries (launch/band/feedback/assemble/refinalize/cache-update host
-spans + the exclusive device lane) instead of blocking timers, alongside
-``wait_seconds`` (the drain's blocking device_get) and the
-per-transfer-class byte counters (seed-feedback / band / assembly).
 """
 from __future__ import annotations
 
@@ -97,60 +90,9 @@ def run_quant(scale: str = "ci_hd", *, regime: str = "manifold",
     return rows
 
 
-def run_pipeline(scale: str = "ci", *, regime: str = "manifold",
-                 theta_idxs=(2,), methods=("es_mi", "es_mi_adapt"),
-                 quant: str = "sq8") -> list[dict]:
-    """Per-phase breakdown of the *pipelined* (overlap=True) path.
-
-    The sequential table above blocks between phases, so its timers are
-    meaningless under overlap; here each cell runs the double-buffered
-    pipeline under a TraceKit tracer and reports the per-phase seconds
-    from the span summary: ``device_s`` is the exclusive traversal lane
-    (serial device execution under double-buffered dispatch), the
-    ``*_s`` host columns are the assembly-lane spans, ``wait_s`` is
-    ``JoinStats.wait_seconds`` (blocking device_get in the drain), and
-    ``bytes_{feedback,band,assembly}`` are the transfer-class byte
-    counters the wave loop accumulates.
-    """
-    from repro.obs import trace as obs_trace
-    rows = []
-    grid = theta_grid(regime, scale)
-    host_spans = ("launch", "band", "feedback", "assemble", "refinalize",
-                  "cache_update")
-    for ti in theta_idxs:
-        theta = grid[ti - 1]
-        for method in methods:
-            tr = obs_trace.enable(obs_trace.Tracer())
-            try:
-                res, dt, rec = run_method(regime, method, theta,
-                                          scale=scale, quant=quant,
-                                          overlap=True)
-            finally:
-                obs_trace.disable()
-            summ = tr.summary()
-            s = res.stats
-            row = dict(dataset=regime, theta_idx=ti, method=method,
-                       quant=quant, total_s=dt,
-                       device_s=summ.get(("traversal", "wave/device"),
-                                         (0, 0.0))[1])
-            for name in host_spans:
-                row[f"{name}_s"] = summ.get(
-                    ("assembly", f"wave/{name}"), (0, 0.0))[1]
-            row.update(wait_s=s.wait_seconds,
-                       bytes_feedback=s.bytes_feedback,
-                       bytes_band=s.bytes_band,
-                       bytes_assembly=s.bytes_assembly,
-                       pairs=len(res.pairs), recall=rec)
-            rows.append(row)
-    return rows
-
-
 def main(scale: str = "ci") -> None:
     emit(run(scale))
-    # separate sections: different schemas than the breakdown table above
-    print("\n# pipeline: per-phase seconds from TraceKit spans + "
-          "transfer-class bytes (overlap on)")
-    emit(run_pipeline(scale))
+    # a separate section: a different schema than the breakdown above
     print("\n# quant: per-tier distance work, bytes, and dims scanned — "
           "f32 vs sq8 vs sketch8 vs pdx8 vs sketchpdx8 (d >= 256)")
     emit(run_quant("full_hd" if scale == "full" else "ci_hd"))

@@ -14,10 +14,10 @@ reporting wall-clock plus the band-compacted re-rank's f32 gather bytes
 per pair. ``run_early_exit`` is the PDX analogue: exit-on vs exit-off
 wall-clock under ``pdx8`` on the clustered high-dim dataset, asserting
 identical pair sets and reporting ``dims_scanned_frac``.
-``run_trace_overhead`` is the TraceKit guard: the same cell min-of-N
-timed with the span tracer off vs on, asserting identical pair sets and
-that tracing costs < 5% wall-clock (plus a small additive slack for
-sub-second CI cells). ``run_planner`` is the JoinPlanner parity table:
+``run_trace_overhead`` is the tracing guard: the same cell min-of-N
+timed with the profiler off vs on, asserting identical pair sets and
+counters and that the profiler costs < 5% wall-clock (plus a small
+additive slack for sub-second CI cells). ``run_planner`` is the JoinPlanner parity table:
 hand-tuned knobs vs ``plan_config``'s choice per dataset, asserting
 admissibility (identical pair sets at a matching operating point;
 soundness + no recall loss when calibration steers to a different one)
@@ -114,39 +114,42 @@ def run_trace_overhead(scale: str = "ci", *, regime: str = "manifold",
                        theta_idx: int = 2, method: str = "es_mi",
                        quant: str = "sq8", repeats: int = 3,
                        slack_s: float = 0.15) -> list[dict]:
-    """TraceKit overhead guard: one pipelined MI-join cell timed with the
-    span tracer disabled vs enabled, min-of-``repeats`` per arm.
+    """Tracing overhead guard: one pipelined MI-join cell timed without
+    and under a ``jax.profiler`` session (``obs.trace.profile``: the
+    program spans and the device ops), min-of-``repeats`` per arm.
 
-    Asserts (a) the emitted pair sets are bit-identical — tracing is
-    observation, never scheduling — and (b) the traced arm's best
+    Asserts (a) the emitted pair sets and the work counters are
+    identical — tracing is observation, never scheduling — and (b) the
+    traced arm's best
     wall-clock stays within 5% of the untraced best plus ``slack_s``
     seconds of additive slack (CI cells are sub-second, where a fixed 5%
     would be dominated by scheduler noise; the relative bound is what
     matters at paper scale).
     """
+    import contextlib
+    import tempfile
+
     from repro.obs import trace as obs_trace
     theta = theta_grid(regime, scale)[theta_idx - 1]
 
     def arm(traced: bool):
-        times, res, n_events = [], None, 0
+        times, res = [], None
         for _ in range(repeats):
-            tr = obs_trace.enable() if traced else None
-            try:
-                res, dt, _ = run_method(regime, method, theta, scale=scale,
-                                        quant=quant)
-            finally:
-                if traced:
-                    obs_trace.disable()
-            if tr is not None:
-                n_events = tr.n_events
+            with tempfile.TemporaryDirectory() as log_dir:
+                with (obs_trace.profile(log_dir) if traced
+                      else contextlib.nullcontext()):
+                    res, dt, _ = run_method(regime, method, theta,
+                                            scale=scale, quant=quant)
             times.append(dt)
-        return res, min(times), n_events
+        return res, min(times)
 
-    res_off, t_off, _ = arm(False)
-    res_on, t_on, n_events = arm(True)
+    res_off, t_off = arm(False)
+    res_on, t_on = arm(True)
     match = res_on.pair_set() == res_off.pair_set()
     assert match, (method, quant,
                    len(res_on.pair_set() ^ res_off.pair_set()))
+    for k in ("n_dist", "n_rerank", "n_slots", "n_lane_iters", "n_iters"):
+        assert getattr(res_on.stats, k) == getattr(res_off.stats, k), k
     budget = 1.05 * t_off + slack_s
     assert t_on <= budget, (
         f"tracing overhead over budget: traced {t_on:.3f}s vs "
@@ -156,7 +159,6 @@ def run_trace_overhead(scale: str = "ci", *, regime: str = "manifold",
         method=method, quant=quant,
         trace_off_s=t_off, trace_on_s=t_on,
         overhead_frac=(t_on - t_off) / max(t_off, 1e-9),
-        trace_events=n_events,
         pairs=len(res_on.pairs), pairs_match=match)]
 
 

@@ -565,7 +565,8 @@ def _local_mi_join(vecs, nbrs, mnd, start, qq, qscales, qnorms, qerr,
         index, xw, theta, cfg=cfg, n_data=shard_size, hybrid=hybrid,
         traverse_nondata=hybrid, init_idx=rows, init_dist=dist,
         init_valid=valid, visited=visited, best_dist=best, best_idx=besti,
-        n_dist=n_new, cascade=cascade, qc=qc, init_ub=ub, n_esc=n_esc0)
+        n_dist=n_new, cascade=cascade, qc=qc, init_ub=ub, n_esc=n_esc0,
+        n_slots=jnp.int32(rows.size))
     C = r.pool_idx.shape[1]
     keep = jnp.arange(C)[None, :] < r.n_pool[:, None]
     n_rerank = jnp.zeros((B,), jnp.int32)
@@ -617,7 +618,8 @@ def _local_mi_join(vecs, nbrs, mnd, start, qq, qscales, qnorms, qerr,
         merged = merged.reshape(n_shards, *cand.shape)
     return (merged, n_keep[None], r.overflow[None],
             r.n_dist[None], n_rerank[None], r.n_esc[None],
-            n_band_over[None], n_dims_scanned[None], n_dims_total[None])
+            n_band_over[None], n_dims_scanned[None], n_dims_total[None],
+            r.n_iters[None], r.n_slots[None], r.n_lane_iters[None])
 
 
 def make_distributed_mi_join(mesh: Mesh, shard_axes, smi: ShardedMergedIndex,
@@ -697,7 +699,8 @@ def make_distributed_mi_join(mesh: Mesh, shard_axes, smi: ShardedMergedIndex,
         # collective → replicated out-spec: the host fetch is ONE fused
         # (S, B, merge_cap) block, not S per-shard pools
         out_specs=(P(), spec_idx, spec_idx, spec_idx, spec_idx,
-                   spec_idx, spec_idx, spec_idx, spec_idx),
+                   spec_idx, spec_idx, spec_idx, spec_idx,
+                   spec_idx, spec_idx, spec_idx),
         check_vma=False)
 
     S = smi.n_shards
@@ -826,13 +829,10 @@ def distributed_mi_join(X, smi: ShardedMergedIndex, mesh: Mesh | None = None,
     pairs_out = []
     shard_stats = [JoinStats() for _ in range(S)]
     band = np.zeros(S, np.int64)
-    tr = obs_trace.tracer()
 
-    def dispatch(padded, lane_valid):
+    def dispatch(padded, lane_valid, wave):
         step, qargs = get_step()
-        dev = tr.begin("wave/device", lane="traversal", cap=rcap.cap,
-                       merge_cap=mcap.cap, shards=S)
-        with jax.set_mesh(mesh):
+        with obs_trace.span("wave/launch", wave=wave), jax.set_mesh(mesh):
             outs = step(
                 smi.vecs, smi.nbrs, smi.mean_nbr_dist, smi.start, *qargs,
                 X[jnp.asarray(padded)], jnp.asarray(padded),
@@ -847,23 +847,23 @@ def distributed_mi_join(X, smi: ShardedMergedIndex, mesh: Mesh | None = None,
                 st.bytes_ppermute += combine_bytes
             else:
                 st.bytes_allgather += combine_bytes
-        return outs, dev
+        return outs
 
-    def fetch(outs, dev):
+    def fetch(outs, wave):
         """The blocking per-wave transfer: one fused merged-pool block
         plus the per-shard scalar stats."""
         t0 = time.perf_counter()
-        outs = jax.device_get(outs)
-        if dev:
-            dev.end()
+        with obs_trace.span("wave/fetch", wave=wave):
+            outs = jax.device_get(outs)
         shard_stats[0].wait_seconds += time.perf_counter() - t0
         shard_stats[0].bytes_assembly += sum(a.nbytes for a in outs)
         return outs
 
-    def assemble(wave) -> None:
-        padded, lane_valid, outs, dev = wave
+    def assemble(pending) -> None:
+        padded, lane_valid, outs, wave = pending
 
-        def tally(n_dist, overflow, n_rerank, n_esc, n_dims_s, n_dims_t):
+        def tally(n_dist, overflow, n_rerank, n_esc, n_dims_s, n_dims_t,
+                  n_iters, n_slots, n_lane_iters):
             # per-attempt accounting: an overflow retry re-runs the FULL
             # per-shard wave (traversal included), so the work counters
             # accumulate on every fetch — the same style as dispatch()'s
@@ -875,16 +875,19 @@ def distributed_mi_join(X, smi: ShardedMergedIndex, mesh: Mesh | None = None,
                 "n_esc8": n_esc[:, lane_valid].sum(axis=1),
                 "n_dims_scanned": np.asarray(n_dims_s).reshape(-1),
                 "n_dims_total": np.asarray(n_dims_t).reshape(-1),
+                "n_iters": np.asarray(n_iters).reshape(-1),
+                "n_slots": np.asarray(n_slots).reshape(-1),
+                "n_lane_iters": n_lane_iters[:, lane_valid].sum(axis=1),
             }
             for s, st in enumerate(shard_stats):
                 for k, v in per.items():
                     setattr(st, k, getattr(st, k) + int(v[s]))
             band[:] += n_rerank[:, lane_valid].sum(axis=1).astype(np.int64)
 
-        with tr.span("wave/assemble", lane="assembly") as sp:
+        with obs_trace.span("wave/assemble", wave=wave) as sp:
             (merged, n_keep, overflow, n_dist, n_rerank, n_esc,
-             n_band_over, n_dims_s, n_dims_t) = fetch(outs, dev)
-            tally(n_dist, overflow, n_rerank, n_esc, n_dims_s, n_dims_t)
+             n_band_over, *counts) = fetch(outs, wave)
+            tally(n_dist, overflow, n_rerank, n_esc, *counts)
             # grow-and-retry: the band capacity (in-shard re-rank) and
             # the merge capacity (kept pairs per lane per shard) are both
             # exact after one measurement, but growing the band can admit
@@ -906,41 +909,36 @@ def distributed_mi_join(X, smi: ShardedMergedIndex, mesh: Mesh | None = None,
                               else 0)
                 if not need_band and not need_merge:
                     break
-                if tr:
-                    tr.instant("wave/overflow_retry", lane="traversal",
-                               band=need_band, merge=need_merge,
-                               cap=rcap.cap, merge_cap=mcap.cap)
                 shard_stats[0].overflow_retries += 1
                 if need_band:
                     rcap.grow(need_band)
                 if need_merge:
                     mcap.grow(need_merge)
                 (merged, n_keep, overflow, n_dist, n_rerank, n_esc,
-                 n_band_over, n_dims_s, n_dims_t) = fetch(
-                    *dispatch(padded, lane_valid))
-                tally(n_dist, overflow, n_rerank, n_esc, n_dims_s,
-                      n_dims_t)
+                 n_band_over, *counts) = fetch(
+                    dispatch(padded, lane_valid, wave), wave)
+                tally(n_dist, overflow, n_rerank, n_esc, *counts)
             t1 = time.perf_counter()
             # (S, B, K) merged id block: every non-sentinel entry is a
             # kept (shard-global) pair for its lane
             sh, ln, sl = np.nonzero(merged != NO_NODE)
             pairs_out.append(np.stack([padded[ln], merged[sh, ln, sl]],
                                       axis=1))
-            if sp:
-                sp.set(pairs=int(ln.size))
+            if obs_trace.recording():
+                sp.set_metadata(pairs=int(ln.size))
             shard_stats[0].other_seconds += time.perf_counter() - t1
 
     pending = None
-    for q0 in range(0, nq, wave_size):
+    for k, q0 in enumerate(range(0, nq, wave_size)):
         ids = np.arange(q0, min(q0 + wave_size, nq))
         padded, lane_valid = W.pad_wave(ids.astype(np.int32), wave_size)
-        outs, dev = dispatch(padded, lane_valid)
+        outs = dispatch(padded, lane_valid, k)
         if overlap:
             if pending is not None:
                 assemble(pending)
-            pending = (padded, lane_valid, outs, dev)
+            pending = (padded, lane_valid, outs, k)
         else:
-            assemble((padded, lane_valid, outs, dev))
+            assemble((padded, lane_valid, outs, k))
     if pending is not None:
         assemble(pending)
     pairs = (np.concatenate(pairs_out, axis=0) if pairs_out
@@ -1149,7 +1147,6 @@ def distributed_nlj_join(X, Y, plan: MeshPlan, *, theta: float,
     th2 = jnp.float32(theta) ** 2
     stats = JoinStats()
     pairs_out = []
-    tr = obs_trace.tracer()
 
     def dispatch(xw, lane_valid):
         step = get_step()
@@ -1187,9 +1184,6 @@ def distributed_nlj_join(X, Y, plan: MeshPlan, *, theta: float,
             if not (n_keep[:, lane_valid] > merged.shape[2]).any():
                 break
             need = int(n_keep[:, lane_valid].max())
-            if tr:
-                tr.instant("wave/merge_retry", lane="traversal",
-                           needed=need, merge_cap=mcap.cap)
             stats.overflow_retries += 1
             mcap.grow(need)
             outs = dispatch(xw, lane_valid)

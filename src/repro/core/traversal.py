@@ -14,7 +14,12 @@ The paper's single-thread pointer-chasing loops become batched, fixed-shape
 
 Distance-computation counts (`n_dist`) replicate the paper's work metric
 exactly: a distance is counted once per (query, node) — the shared-visited
-invariant of Alg. 2 — enforced by the bitmap plus in-batch dedup.
+invariant of Alg. 2 — enforced by the bitmap plus in-batch dedup. Two
+counters measure the work the fixed shapes do besides: `n_slots`, the
+candidate slots the probes evaluate (B × K per probe, masked slots
+included), and `n_lane_iters`, the loop iterations in which each lane was
+still active. The loop bodies' phases carry `jax.named_scope`s (`select`,
+`gather`, `distance`, `visited`, `merge`) that name their device ops.
 
 All distances are squared L2 internally; thresholds are squared on entry.
 """
@@ -118,40 +123,47 @@ def _probe(vecs: Array, x: Array, cand: Array, valid: Array, visited: Array,
        n_new (B,), n_esc (B,) — candidates escalated into tier 1).
     """
     B, K = cand.shape
-    valid = valid & (cand != NO_NODE)
-    if not traverse_nondata:
-        valid = valid & (cand < n_data)
-    cand_c = jnp.where(valid, cand, 0)
-    # visited test
-    w = (cand_c >> 5).astype(jnp.int32)
-    bit = jnp.uint32(1) << (cand_c & 31).astype(jnp.uint32)
-    words = jnp.take_along_axis(visited, w, axis=1)
-    valid = valid & ((words & bit) == 0)
-    # in-batch dedup (two expanded nodes sharing a neighbor)
-    sort_key = jnp.where(valid, cand, _SORT_PAD)
-    order = jnp.argsort(sort_key, axis=1)
-    sorted_ids = jnp.take_along_axis(sort_key, order, axis=1)
-    dup = jnp.concatenate(
-        [jnp.zeros((B, 1), bool), sorted_ids[:, 1:] == sorted_ids[:, :-1]],
-        axis=1) & (sorted_ids != _SORT_PAD)
-    keep = jnp.put_along_axis(jnp.ones_like(valid), order, ~dup,
-                              axis=1, inplace=False)
-    valid = valid & keep
+    with jax.named_scope("visited"):
+        valid = valid & (cand != NO_NODE)
+        if not traverse_nondata:
+            valid = valid & (cand < n_data)
+        cand_c = jnp.where(valid, cand, 0)
+        # visited test
+        w = (cand_c >> 5).astype(jnp.int32)
+        bit = jnp.uint32(1) << (cand_c & 31).astype(jnp.uint32)
+        words = jnp.take_along_axis(visited, w, axis=1)
+        valid = valid & ((words & bit) == 0)
+        # in-batch dedup (two expanded nodes sharing a neighbor)
+        sort_key = jnp.where(valid, cand, _SORT_PAD)
+        order = jnp.argsort(sort_key, axis=1)
+        sorted_ids = jnp.take_along_axis(sort_key, order, axis=1)
+        dup = jnp.concatenate(
+            [jnp.zeros((B, 1), bool),
+             sorted_ids[:, 1:] == sorted_ids[:, :-1]],
+            axis=1) & (sorted_ids != _SORT_PAD)
+        keep = jnp.put_along_axis(jnp.ones_like(valid), order, ~dup,
+                                  axis=1, inplace=False)
+        valid = valid & keep
     # distances (masked)
     n_esc = jnp.zeros((B,), jnp.int32)
     if cascade is not None:
-        dist, ub, n_esc = cascade_bounds(cascade, qc, cand_c, valid,
-                                         esc_th2, dist_impl=dist_impl)
+        with jax.named_scope("distance"):
+            dist, ub, n_esc = cascade_bounds(cascade, qc, cand_c, valid,
+                                             esc_th2, dist_impl=dist_impl)
     else:
-        cvec = vecs[cand_c]                                 # (B, K, d)
-        dist = ops.rowwise_sq_dists(x, cvec, impl=dist_impl)
+        with jax.named_scope("gather"):
+            cvec = vecs[cand_c]                             # (B, K, d)
+        with jax.named_scope("distance"):
+            dist = ops.rowwise_sq_dists(x, cvec, impl=dist_impl)
         ub = dist
     dist = jnp.where(valid, dist, _INF)
     ub = jnp.where(valid, ub, _INF)
-    # mark visited: deduped ⇒ each (word,bit) contributed once ⇒ add == or
-    add = jnp.where(valid, bit, jnp.uint32(0))
-    lane = jnp.arange(B, dtype=jnp.int32)[:, None]
-    visited = visited.at[lane, w].add(add)
+    with jax.named_scope("visited"):
+        # mark visited: deduped ⇒ each (word,bit) contributed once ⇒
+        # add == or
+        add = jnp.where(valid, bit, jnp.uint32(0))
+        lane = jnp.arange(B, dtype=jnp.int32)[:, None]
+        visited = visited.at[lane, w].add(add)
     n_new = jnp.sum(valid, axis=1).astype(jnp.int32)
     return dist, ub, valid, visited, n_new, n_esc
 
@@ -163,7 +175,8 @@ def _expand(index_vecs: Array, index_nbrs: Array, x: Array, sel_ids: Array,
     """Gather neighbor rows of selected nodes and probe them."""
     B, E = sel_ids.shape
     R = index_nbrs.shape[1]
-    rows = index_nbrs[jnp.clip(sel_ids, 0)]                 # (B, E, R)
+    with jax.named_scope("gather"):
+        rows = index_nbrs[jnp.clip(sel_ids, 0)]             # (B, E, R)
     cand = rows.reshape(B, E * R)
     valid = jnp.broadcast_to(sel_valid[:, :, None], (B, E, R)).reshape(B, E * R)
     dist, ub, valid, visited, n_new, n_esc = _probe(
@@ -229,6 +242,8 @@ class GreedyState(NamedTuple):
     n_dist: Array          # (B,)
     n_esc: Array           # (B,) sketch8: candidates escalated to int8
     n_iters: Array         # ()
+    n_slots: Array         # () candidate slots probed, masked included
+    n_lane_iters: Array    # (B,) iterations in which the lane was active
 
 
 @functools.partial(
@@ -277,23 +292,27 @@ def greedy_search(index: GraphIndex, x: Array, seeds: Array,
         beam_dist=bd, beam_idx=bi, beam_exp=bexp, visited=visited0,
         best_dist=best0, best_idx=besti0,
         since_improve=jnp.zeros((B,), jnp.int32),
-        done=found0, n_dist=n0, n_esc=e0, n_iters=jnp.int32(0))
+        done=found0, n_dist=n0, n_esc=e0, n_iters=jnp.int32(0),
+        n_slots=jnp.int32(seeds.size),
+        n_lane_iters=jnp.zeros((B,), jnp.int32))
 
     def cond(s: GreedyState):
         return (~jnp.all(s.done)) & (s.n_iters < cfg.max_iters)
 
     def body(s: GreedyState) -> GreedyState:
         active = ~s.done
-        # pick top-E unexpanded beam entries (closest first)
-        key = jnp.where((~s.beam_exp) & (s.beam_idx != NO_NODE)
-                        & jnp.isfinite(s.beam_dist), -s.beam_dist, -_INF)
-        selk, selpos = jax.lax.top_k(key, E)                # (B, E)
-        sel_valid = (selk > -_INF) & active[:, None]
-        sel_ids = jnp.take_along_axis(s.beam_idx, selpos, axis=1)
-        # mark them expanded (only where selected & active)
-        lane = jnp.arange(B, dtype=jnp.int32)[:, None]
-        new_exp = s.beam_exp.at[lane, selpos].max(sel_valid)
-        exhausted = ~jnp.any(sel_valid, axis=1) & active
+        with jax.named_scope("select"):
+            # pick top-E unexpanded beam entries (closest first)
+            key = jnp.where((~s.beam_exp) & (s.beam_idx != NO_NODE)
+                            & jnp.isfinite(s.beam_dist), -s.beam_dist,
+                            -_INF)
+            selk, selpos = jax.lax.top_k(key, E)            # (B, E)
+            sel_valid = (selk > -_INF) & active[:, None]
+            sel_ids = jnp.take_along_axis(s.beam_idx, selpos, axis=1)
+            # mark them expanded (only where selected & active)
+            lane = jnp.arange(B, dtype=jnp.int32)[:, None]
+            new_exp = s.beam_exp.at[lane, selpos].max(sel_valid)
+            exhausted = ~jnp.any(sel_valid, axis=1) & active
 
         cand, cd, _, cv, visited, n_new, n_esc = _expand(
             vecs, nbrs, x, sel_ids, sel_valid, s.visited, n_data=n_data,
@@ -303,12 +322,13 @@ def greedy_search(index: GraphIndex, x: Array, seeds: Array,
         n_dist = s.n_dist + jnp.where(active, n_new, 0)
         n_esc2 = s.n_esc + jnp.where(active, n_esc, 0)
 
-        bd2, bi2, be2 = _beam_merge(
-            s.beam_dist, s.beam_idx, new_exp, cd,
-            jnp.where(cv, cand, NO_NODE), jnp.zeros_like(cv))
-        bd2 = jnp.where(active[:, None], bd2, s.beam_dist)
-        bi2 = jnp.where(active[:, None], bi2, s.beam_idx)
-        be2 = jnp.where(active[:, None], be2, s.beam_exp)
+        with jax.named_scope("merge"):
+            bd2, bi2, be2 = _beam_merge(
+                s.beam_dist, s.beam_idx, new_exp, cd,
+                jnp.where(cv, cand, NO_NODE), jnp.zeros_like(cv))
+            bd2 = jnp.where(active[:, None], bd2, s.beam_dist)
+            bi2 = jnp.where(active[:, None], bi2, s.beam_idx)
+            be2 = jnp.where(active[:, None], be2, s.beam_exp)
 
         cbest = jnp.min(cd, axis=1)
         improved = cbest < s.best_dist
@@ -326,7 +346,9 @@ def greedy_search(index: GraphIndex, x: Array, seeds: Array,
             (B,), bool)
         done = s.done | found | plateau | exhausted
         return GreedyState(bd2, bi2, be2, visited, best_dist, best_idx,
-                           since, done, n_dist, n_esc2, s.n_iters + 1)
+                           since, done, n_dist, n_esc2, s.n_iters + 1,
+                           s.n_slots + cand.size,
+                           s.n_lane_iters + active.astype(jnp.int32))
 
     return jax.lax.while_loop(cond, body, state)
 
@@ -346,6 +368,8 @@ class ExpandResult(NamedTuple):
     n_esc: Array           # (B,) sketch8: escalations (incl. greedy's)
     n_iters: Array         # ()
     visited: Array         # (B, W)
+    n_slots: Array         # () candidate slots probed (incl. the init's)
+    n_lane_iters: Array    # (B,) active iterations (incl. greedy's)
 
 
 class _ExpState(NamedTuple):
@@ -367,6 +391,8 @@ class _ExpState(NamedTuple):
     n_dist: Array
     n_esc: Array
     n_iters: Array
+    n_slots: Array
+    n_lane_iters: Array
 
 
 @functools.partial(
@@ -379,7 +405,9 @@ def range_expand(index: GraphIndex, x: Array, theta: float | Array, *,
                  visited: Array, best_dist: Array, best_idx: Array,
                  n_dist: Array, cascade=None, qc=None,
                  init_ub: Array | None = None,
-                 n_esc: Array | None = None) -> ExpandResult:
+                 n_esc: Array | None = None,
+                 n_slots: Array | None = None,
+                 n_lane_iters: Array | None = None) -> ExpandResult:
     """Enumerate all reachable in-range data points from initial candidates.
 
     ``init_*`` (B, K0) are already-visited candidates with known distances
@@ -395,7 +423,9 @@ def range_expand(index: GraphIndex, x: Array, theta: float | Array, *,
     out-range beam carries (lb, ub) pairs so eviction can never drop a
     candidate whose certified upper bound beats the protection radius
     ``cfg.hybrid_guard · θ²`` (the OOD recall floor — see
-    ``_hybrid_merge``).
+    ``_hybrid_merge``). ``n_dist``/``n_esc``/``n_slots``/``n_lane_iters``
+    carry on the counts of the probes that produced the initial
+    candidates.
     """
     vecs, nbrs = index.vecs, index.nbrs
     B, K0 = init_idx.shape
@@ -410,6 +440,10 @@ def range_expand(index: GraphIndex, x: Array, theta: float | Array, *,
         n_esc = jnp.zeros((B,), jnp.int32)
     if init_ub is None:
         init_ub = jnp.full(init_dist.shape, _INF)
+    if n_slots is None:
+        n_slots = jnp.int32(0)
+    if n_lane_iters is None:
+        n_lane_iters = jnp.zeros((B,), jnp.int32)
 
     is_data = (init_idx >= 0) & (init_idx < n_data)
     inr = init_valid & is_data & (init_dist < th2)
@@ -450,37 +484,41 @@ def range_expand(index: GraphIndex, x: Array, theta: float | Array, *,
         visited=visited, best_dist=best_dist, best_idx=best_idx,
         qmax_prev=jnp.full((B,), _INF), stall=jnp.zeros((B,), jnp.int32),
         done=jnp.zeros((B,), bool), n_dist=n_dist, n_esc=n_esc,
-        n_iters=jnp.int32(0))
+        n_iters=jnp.int32(0), n_slots=n_slots, n_lane_iters=n_lane_iters)
 
     def cond(s: _ExpState):
         return (~jnp.all(s.done)) & (s.n_iters < cfg.max_iters)
 
     def body(s: _ExpState) -> _ExpState:
         active = ~s.done
-        # --- select up to E unexpanded entries: pool (in-range) first ---
-        pkey = jnp.where((~s.pool_exp) & (s.pool_idx != NO_NODE),
-                         2e30 - s.pool_dist, -_INF)          # (B, C+1)
-        if hybrid and Lh > 0:
-            hkey = jnp.where((~s.hb_exp) & (s.hb_idx != NO_NODE)
-                             & jnp.isfinite(s.hb_dist), -s.hb_dist, -_INF)
-            key = jnp.concatenate([pkey, hkey], axis=1)
-        else:
-            key = pkey
-        selk, selpos = jax.lax.top_k(key, E)
-        sel_valid = (selk > -_INF) & active[:, None]
-        from_pool = selpos < (C + 1)
-        pool_pos = jnp.where(from_pool, selpos, 0)
-        hb_pos = jnp.where(from_pool, 0, selpos - (C + 1))
-        sel_ids = jnp.where(
-            from_pool,
-            jnp.take_along_axis(s.pool_idx, pool_pos, axis=1),
-            jnp.take_along_axis(s.hb_idx, hb_pos, axis=1))
-        lane2 = jnp.arange(B, dtype=jnp.int32)[:, None]
-        pool_exp = s.pool_exp.at[lane2, pool_pos].max(sel_valid & from_pool)
-        hb_exp2 = s.hb_exp.at[lane2, hb_pos].max(sel_valid & ~from_pool)
-        any_inrange_unexp = jnp.any(
-            (~pool_exp) & (s.pool_idx != NO_NODE), axis=1)
-        exhausted = ~jnp.any(sel_valid, axis=1) & active
+        with jax.named_scope("select"):
+            # --- select up to E unexpanded entries: pool (in-range)
+            # first ---
+            pkey = jnp.where((~s.pool_exp) & (s.pool_idx != NO_NODE),
+                             2e30 - s.pool_dist, -_INF)      # (B, C+1)
+            if hybrid and Lh > 0:
+                hkey = jnp.where((~s.hb_exp) & (s.hb_idx != NO_NODE)
+                                 & jnp.isfinite(s.hb_dist), -s.hb_dist,
+                                 -_INF)
+                key = jnp.concatenate([pkey, hkey], axis=1)
+            else:
+                key = pkey
+            selk, selpos = jax.lax.top_k(key, E)
+            sel_valid = (selk > -_INF) & active[:, None]
+            from_pool = selpos < (C + 1)
+            pool_pos = jnp.where(from_pool, selpos, 0)
+            hb_pos = jnp.where(from_pool, 0, selpos - (C + 1))
+            sel_ids = jnp.where(
+                from_pool,
+                jnp.take_along_axis(s.pool_idx, pool_pos, axis=1),
+                jnp.take_along_axis(s.hb_idx, hb_pos, axis=1))
+            lane2 = jnp.arange(B, dtype=jnp.int32)[:, None]
+            pool_exp = s.pool_exp.at[lane2, pool_pos].max(
+                sel_valid & from_pool)
+            hb_exp2 = s.hb_exp.at[lane2, hb_pos].max(sel_valid & ~from_pool)
+            any_inrange_unexp = jnp.any(
+                (~pool_exp) & (s.pool_idx != NO_NODE), axis=1)
+            exhausted = ~jnp.any(sel_valid, axis=1) & active
 
         cand, cd, cub, cv, visited, n_new, n_esc_new = _expand(
             vecs, nbrs, x, sel_ids, sel_valid, s.visited, n_data=n_data,
@@ -493,34 +531,36 @@ def range_expand(index: GraphIndex, x: Array, theta: float | Array, *,
         cis_data = (cand >= 0) & (cand < n_data)
         cinr = cv & cis_data & (cd < th2) & active[:, None]
 
-        # --- append in-range hits to the pool ---
-        cpos = s.n_pool[:, None] + jnp.cumsum(cinr, axis=1) - 1
-        cpos = jnp.where(cinr, jnp.minimum(cpos, C), C)
-        pool_idx2 = s.pool_idx.at[lane2, cpos].set(
-            jnp.where(cinr, cand, NO_NODE))
-        pool_dist2 = s.pool_dist.at[lane2, cpos].set(
-            jnp.where(cinr, cd, _INF))
-        pool_idx2 = pool_idx2.at[:, C].set(NO_NODE)
-        pool_dist2 = pool_dist2.at[:, C].set(_INF)
-        pool_exp = pool_exp.at[:, C].set(True)
-        n_hits = jnp.sum(cinr, axis=1).astype(jnp.int32)
-        n_pool2 = jnp.minimum(s.n_pool + n_hits, C)
-        overflow2 = s.overflow + jnp.maximum(
-            s.n_pool + n_hits - C, 0) - jnp.maximum(s.n_pool - C, 0)
+        with jax.named_scope("merge"):
+            # --- append in-range hits to the pool ---
+            cpos = s.n_pool[:, None] + jnp.cumsum(cinr, axis=1) - 1
+            cpos = jnp.where(cinr, jnp.minimum(cpos, C), C)
+            pool_idx2 = s.pool_idx.at[lane2, cpos].set(
+                jnp.where(cinr, cand, NO_NODE))
+            pool_dist2 = s.pool_dist.at[lane2, cpos].set(
+                jnp.where(cinr, cd, _INF))
+            pool_idx2 = pool_idx2.at[:, C].set(NO_NODE)
+            pool_dist2 = pool_dist2.at[:, C].set(_INF)
+            pool_exp = pool_exp.at[:, C].set(True)
+            n_hits = jnp.sum(cinr, axis=1).astype(jnp.int32)
+            n_pool2 = jnp.minimum(s.n_pool + n_hits, C)
+            overflow2 = s.overflow + jnp.maximum(
+                s.n_pool + n_hits - C, 0) - jnp.maximum(s.n_pool - C, 0)
 
-        # --- hybrid beam absorbs the rest (bounded, Alg. 4 lines 12–16) ---
-        if hybrid and Lh > 0:
-            cout = cv & ~cinr & active[:, None]
-            hb_dist2, hb_idx2, hb_exp3, hb_ub2 = _hybrid_merge(
-                s.hb_dist, s.hb_idx, hb_exp2, s.hb_ub,
-                jnp.where(cout, cd, _INF),
-                jnp.where(cout, cand, NO_NODE),
-                jnp.zeros_like(cout),
-                jnp.where(cout, cub, _INF),
-                protect_th2=protect_th2)
-        else:
-            hb_dist2, hb_idx2, hb_exp3, hb_ub2 = (
-                s.hb_dist, s.hb_idx, hb_exp2, s.hb_ub)
+            # --- hybrid beam absorbs the rest (bounded, Alg. 4 lines
+            # 12–16) ---
+            if hybrid and Lh > 0:
+                cout = cv & ~cinr & active[:, None]
+                hb_dist2, hb_idx2, hb_exp3, hb_ub2 = _hybrid_merge(
+                    s.hb_dist, s.hb_idx, hb_exp2, s.hb_ub,
+                    jnp.where(cout, cd, _INF),
+                    jnp.where(cout, cand, NO_NODE),
+                    jnp.zeros_like(cout),
+                    jnp.where(cout, cub, _INF),
+                    protect_th2=protect_th2)
+            else:
+                hb_dist2, hb_idx2, hb_exp3, hb_ub2 = (
+                    s.hb_dist, s.hb_idx, hb_exp2, s.hb_ub)
 
         # --- best-seen tracking (Alg. 2 lines 38–39; feeds SWS cache) ---
         cbest = jnp.min(cd, axis=1)
@@ -562,7 +602,9 @@ def range_expand(index: GraphIndex, x: Array, theta: float | Array, *,
                          jnp.where(keep, overflow2, s.overflow),
                          hb_dist2, hb_idx2, hb_exp3, hb_ub2, visited,
                          best_dist2, best_idx2, qmax_prev2, stall2, done2,
-                         n_dist2, n_esc2, s.n_iters + 1)
+                         n_dist2, n_esc2, s.n_iters + 1,
+                         s.n_slots + cand.size,
+                         s.n_lane_iters + active.astype(jnp.int32))
 
     fin = jax.lax.while_loop(cond, body, state)
     return ExpandResult(
@@ -570,4 +612,5 @@ def range_expand(index: GraphIndex, x: Array, theta: float | Array, *,
         n_pool=fin.n_pool, overflow=fin.overflow,
         best_dist=fin.best_dist, best_idx=fin.best_idx,
         n_dist=fin.n_dist, n_esc=fin.n_esc, n_iters=fin.n_iters,
-        visited=fin.visited)
+        visited=fin.visited, n_slots=fin.n_slots,
+        n_lane_iters=fin.n_lane_iters)

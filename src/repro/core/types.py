@@ -174,6 +174,14 @@ class JoinConfig:
 class JoinStats:
     n_dist: int = 0                # distance computations (paper's C4 metric)
     n_iters: int = 0               # traversal loop iterations
+    n_slots: int = 0               # candidate slots the traversal probes
+    #                                evaluated: B × K per probe, slots
+    #                                masked by the visited test, the dedup
+    #                                or an inactive lane included (n_dist
+    #                                counts the kept ones)
+    n_lane_iters: int = 0          # loop iterations in which each valid
+    #                                lane was still active, summed over
+    #                                lanes (wave_size × n_iters bounds it)
     n_overflow: int = 0            # in-range pool overflow (missed results)
     greedy_seconds: float = 0.0
     expand_seconds: float = 0.0    # BFS / BBFS phase

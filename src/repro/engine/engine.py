@@ -226,8 +226,9 @@ class JoinEngine:
         if self._index_y is None:
             from repro.core import graph
             t0 = time.perf_counter()
-            self._index_y = graph.build_index(
-                self.Y, **self._build_kw_for(("index_y",), self.Y))
+            with obs_trace.span("index/build", kind="index_y"):
+                self._index_y = graph.build_index(
+                    self.Y, **self._build_kw_for(("index_y",), self.Y))
             self.build_seconds += time.perf_counter() - t0
             self.build_counts["index_y"] += 1
         return self._index_y
@@ -241,8 +242,9 @@ class JoinEngine:
             from repro.core import graph
             X = jnp.asarray(X)
             t0 = time.perf_counter()
-            hit = graph.build_index(
-                X, **self._build_kw_for(("index_x", fp), X))
+            with obs_trace.span("index/build", kind="index_x"):
+                hit = graph.build_index(
+                    X, **self._build_kw_for(("index_x", fp), X))
             self.build_seconds += time.perf_counter() - t0
             self.build_counts["index_x"] += 1
             self._index_x.put(fp, hit)
@@ -256,11 +258,12 @@ class JoinEngine:
         if hit is None:
             from repro.core import graph
             t0 = time.perf_counter()
-            merged_vecs = jnp.concatenate(
-                [self.Y, jnp.asarray(X, self.Y.dtype)], axis=0)
-            hit = graph.build_index(
-                merged_vecs, n_data=int(self.Y.shape[0]),
-                **self._build_kw_for(("merged", fp), merged_vecs))
+            with obs_trace.span("index/build", kind="merged"):
+                merged_vecs = jnp.concatenate(
+                    [self.Y, jnp.asarray(X, self.Y.dtype)], axis=0)
+                hit = graph.build_index(
+                    merged_vecs, n_data=int(self.Y.shape[0]),
+                    **self._build_kw_for(("merged", fp), merged_vecs))
             self.build_seconds += time.perf_counter() - t0
             self.build_counts["merged"] += 1
             self._merged.put(fp, hit)
@@ -274,8 +277,9 @@ class JoinEngine:
         self._cache_event("sharded", hit is not None)
         if hit is None:
             t0 = time.perf_counter()
-            hit = distributed.build_sharded_merged_index(
-                self.Y, np.asarray(X), self.n_shards, **self.build_kw)
+            with obs_trace.span("index/build", kind="sharded"):
+                hit = distributed.build_sharded_merged_index(
+                    self.Y, np.asarray(X), self.n_shards, **self.build_kw)
             if self._mesh is not None:
                 mesh, axes = self._mesh, self._shard_axes
             else:
@@ -410,6 +414,7 @@ class JoinEngine:
 
     # -- one-shot joins -----------------------------------------------------
 
+    @obs_trace.call_span("join")
     def join(self, X, cfg: JoinConfig | None = None, *,
              method: str | None = None, theta: float | None = None,
              index_y: GraphIndex | None = None,
@@ -426,7 +431,8 @@ class JoinEngine:
         from repro.core.join import cascade_join_pairs
 
         cfg = self._resolve(cfg, method, theta)
-        X = jnp.asarray(X)
+        with obs_trace.span("join/prepare"):
+            X = jnp.asarray(X)
         stats = JoinStats()
         if index_y is not None or index_x is not None \
                 or index_merged is not None:
@@ -461,16 +467,18 @@ class JoinEngine:
         all_pairs: list[np.ndarray] = []
         t0 = time.perf_counter()
         if cfg.method in _MI_METHODS:
-            merged = self.merged_index(X)
-            casc = self.cascade_for(
-                ("merged", _fingerprint(X)), merged.vecs, cfg, stats)
+            with obs_trace.span("join/prepare"):
+                merged = self.merged_index(X)
+                casc = self.cascade_for(
+                    ("merged", _fingerprint(X)), merged.vecs, cfg, stats)
             stats.other_seconds += time.perf_counter() - t0
             W.run_mi_join(X, merged, cfg, stats, all_pairs, cascade=casc)
         else:
-            iy = self.index_y()
-            ix = (self.index_x(X)
-                  if cfg.method in ("es_hws", "es_sws") else None)
-            casc = self.cascade_for(("index_y",), iy.vecs, cfg, stats)
+            with obs_trace.span("join/prepare"):
+                iy = self.index_y()
+                ix = (self.index_x(X)
+                      if cfg.method in ("es_hws", "es_sws") else None)
+                casc = self.cascade_for(("index_y",), iy.vecs, cfg, stats)
             stats.other_seconds += time.perf_counter() - t0
             W.run_search_join(X, iy, ix, cfg, stats, all_pairs,
                               cascade=casc)
@@ -585,6 +593,7 @@ class JoinEngine:
         self._carry_norms = None
         self._carry_qids = np.empty(0, np.int64)
 
+    @obs_trace.call_span("join")
     def submit(self, X_batch, cfg: JoinConfig | None = None, *,
                method: str | None = None,
                theta: float | None = None) -> JoinResult:
@@ -606,7 +615,8 @@ class JoinEngine:
                 "sharded streaming supports 'nlj' and the merged-index "
                 "methods; the work-sharing-cache methods "
                 f"{_SEARCH_METHODS} run single-device (n_shards=1)")
-        X_batch = jnp.asarray(X_batch)
+        with obs_trace.span("join/prepare"):
+            X_batch = jnp.asarray(X_batch)
         nb = int(X_batch.shape[0])
         offset = self._stream_n
         stats = JoinStats()
@@ -638,9 +648,11 @@ class JoinEngine:
             # streaming pays one (cached, fingerprint-keyed) build per
             # distinct batch — greedy work offloaded to construction.
             all_pairs: list[np.ndarray] = []
-            merged = self.merged_index(X_batch)
-            casc = self.cascade_for(
-                ("merged", _fingerprint(X_batch)), merged.vecs, cfg, stats)
+            with obs_trace.span("join/prepare"):
+                merged = self.merged_index(X_batch)
+                casc = self.cascade_for(
+                    ("merged", _fingerprint(X_batch)), merged.vecs, cfg,
+                    stats)
             W.run_mi_join(X_batch, merged, cfg, stats, all_pairs,
                           qid_offset=offset, cascade=casc,
                           capctl=self._seeded_capctl(X_batch, cfg,
@@ -711,7 +723,8 @@ class JoinEngine:
                 offset = self._stream_n
                 self._stream_n += int(X2.shape[0])
                 group.append((jnp.asarray(X2), c2, JoinStats(), offset))
-            outs = self._submit_search_group(group)
+            with obs_trace.call_span("join"):
+                outs = self._submit_search_group(group)
             for (X2, c2, _, _), res in zip(group, outs):
                 self._batch_done(res, int(X2.shape[0]), c2)
             results.extend(outs)
@@ -752,6 +765,7 @@ class JoinEngine:
         overlay: dict[int, np.ndarray] = {}
         seed_cache = ChainMap(overlay, self._stream_cache)
         pending: tuple[int, W.WaveHandles] | None = None
+        k = 0                            # wave index across the group
 
         def drain(j: int, h: W.WaveHandles) -> None:
             _, cfg_j, stats_j, _ = group[j]
@@ -759,8 +773,7 @@ class JoinEngine:
             all_pairs[j].append(out.pairs)
             if cfg_j.method in _CACHING_METHODS:
                 t1 = time.perf_counter()
-                with obs_trace.tracer().span("wave/cache_update",
-                                             lane="assembly"):
+                with obs_trace.span("wave/cache_update", wave=h.wave):
                     self._stream_entry_n = W.update_sws_cache(
                         self._stream_cache, out, h.qids, cfg_j, stats_j,
                         self._stream_entry_n)
@@ -818,7 +831,9 @@ class JoinEngine:
                                          seeds_valid=seeds_valid,
                                          cascade=casc, qc=qc,
                                          capctl=capctl, sync=not ov,
-                                         collect_seeds=caching and ov)
+                                         collect_seeds=caching and ov,
+                                         wave=k)
+                k += 1
                 if ov and pending is not None:
                     drain(*pending)
                     pending = None

@@ -259,6 +259,8 @@ class WaveHandles:
     n_esc: Array
     overflow: Array
     n_iters: tuple                 # device scalars, summed at assembly
+    n_slots: Array                 # () candidate slots probed
+    n_lane_iters: Array            # (B,) active iterations per lane
     # epilogue outputs (replaced wholesale on a capacity retry)
     keep: Array
     dist: Array
@@ -273,9 +275,7 @@ class WaveHandles:
     seed_mode: str
     seeds_max: int
     early_exit: bool = False
-    # device-phase trace span ("traversal" lane), opened at dispatch and
-    # closed at the first host contact with the results (_resolve_band)
-    span: object = None
+    wave: int = 0                  # index of the wave in its call (spans)
     # host-side state filled by the feedback fetch
     n_amb_host: np.ndarray | None = None
     tombstones: list = dataclasses.field(default_factory=list)
@@ -283,8 +283,7 @@ class WaveHandles:
 
 def _refinalize(h: WaveHandles, stats: JoinStats) -> None:
     """Re-run the device epilogue at the (grown) capacity."""
-    with obs_trace.tracer().span("wave/refinalize", lane="assembly",
-                                 cap=h.capctl.cap):
+    with obs_trace.span("wave/refinalize", wave=h.wave, cap=h.capctl.cap):
         (h.keep, h.dist, h.n_amb, h.seed_ids, h.seed_valid, h.n_dims_scanned,
          h.n_dims_total) = _finalize_wave(
             h.cascade, h.qc, h.vecs, h.xw, h.pool_idx, h.raw_pool_dist,
@@ -303,23 +302,19 @@ def _resolve_band(h: WaveHandles, stats: JoinStats) -> None:
     emitted set never depends on the capacity choice."""
     if h.n_amb_host is not None:
         return
-    tr = obs_trace.tracer()
     t0 = time.perf_counter()
-    with tr.span("wave/band", lane="assembly") as sp:
-        n_amb = np.asarray(jax.device_get(h.n_amb))
+    with obs_trace.span("wave/band", wave=h.wave) as sp:
+        with obs_trace.span("wave/fetch", wave=h.wave):
+            n_amb = np.asarray(jax.device_get(h.n_amb))
         max_amb = int(n_amb.max()) if n_amb.size else 0
         if h.cascade is not None and max_amb > h.capctl.cap:
-            if tr:
-                tr.instant("wave/overflow_retry", lane="traversal",
-                           needed=max_amb, cap=h.capctl.cap)
             stats.overflow_retries += 1
             h.capctl.grow(max_amb)
             _refinalize(h, stats)
-            n_amb = np.asarray(jax.device_get(h.n_amb))
-        if sp:
-            sp.set(band_occ=max_amb, cap=h.capctl.cap)
-    if h.span:
-        h.span.end(band_occ=max_amb, cap=h.capctl.cap)
+            with obs_trace.span("wave/fetch", wave=h.wave):
+                n_amb = np.asarray(jax.device_get(h.n_amb))
+        if obs_trace.recording():
+            sp.set_metadata(band_occ=max_amb, cap=h.capctl.cap)
     h.n_amb_host = n_amb
     stats.wait_seconds += time.perf_counter() - t0
     stats.bytes_feedback += n_amb.nbytes
@@ -339,7 +334,8 @@ def fetch_feedback(h: WaveHandles, stats: JoinStats) -> dict[int, np.ndarray]:
     if h.seed_mode == "none":
         return {}
     t0 = time.perf_counter()
-    with obs_trace.tracer().span("wave/feedback", lane="assembly"):
+    with obs_trace.span("wave/feedback", wave=h.wave), \
+            obs_trace.span("wave/fetch", wave=h.wave):
         seed_ids, seed_valid = jax.device_get((h.seed_ids, h.seed_valid))
     stats.wait_seconds += time.perf_counter() - t0
     stats.bytes_feedback += seed_ids.nbytes + seed_valid.nbytes
@@ -358,12 +354,14 @@ def assemble_wave(h: WaveHandles, stats: JoinStats, *,
     run this executes while the device traverses the next wave."""
     _resolve_band(h, stats)
     t0 = time.perf_counter()
-    with obs_trace.tracer().span("wave/assemble", lane="assembly") as sp:
-        (pool_idx, pool_dist, keep, n_pool, best_idx, n_dist, n_esc,
-         overflow, nds, ndt, *iters) = jax.device_get(
-            (h.pool_idx, h.dist, h.keep, h.n_pool, h.best_idx, h.n_dist,
-             h.n_esc, h.overflow, h.n_dims_scanned, h.n_dims_total)
-            + h.n_iters)
+    with obs_trace.span("wave/assemble", wave=h.wave) as sp:
+        with obs_trace.span("wave/fetch", wave=h.wave):
+            (pool_idx, pool_dist, keep, n_pool, best_idx, n_dist, n_esc,
+             overflow, nds, ndt, n_slots, n_lane_iters,
+             *iters) = jax.device_get(
+                (h.pool_idx, h.dist, h.keep, h.n_pool, h.best_idx,
+                 h.n_dist, h.n_esc, h.overflow, h.n_dims_scanned,
+                 h.n_dims_total, h.n_slots, h.n_lane_iters) + h.n_iters)
         lv = h.lane_valid
         pairs = collect_pairs(h.qids + qid_offset, keep, pool_idx)
         stats.n_dist += int(n_dist[lv].sum())
@@ -373,13 +371,15 @@ def assemble_wave(h: WaveHandles, stats: JoinStats, *,
         stats.n_dims_scanned += int(nds)
         stats.n_dims_total += int(ndt)
         stats.n_iters += sum(int(i) for i in iters)
+        stats.n_slots += int(n_slots)
+        stats.n_lane_iters += int(n_lane_iters[lv].sum())
         stats.bytes_assembly += (
             pool_idx.nbytes + pool_dist.nbytes + keep.nbytes + n_pool.nbytes
             + best_idx.nbytes + n_dist.nbytes + n_esc.nbytes
-            + overflow.nbytes)
-        if sp:
-            sp.set(pairs=int(pairs.shape[0]),
-                   lanes=int(np.count_nonzero(lv)))
+            + overflow.nbytes + n_lane_iters.nbytes)
+        if obs_trace.recording():
+            sp.set_metadata(pairs=int(pairs.shape[0]),
+                            lanes=int(np.count_nonzero(lv)))
     stats.other_seconds += time.perf_counter() - t0
     obs_metrics.metrics().histogram(
         "wave.pairs", help="result pairs emitted per wave"
@@ -451,7 +451,8 @@ def launch_search_wave(index_y: GraphIndex, xw: Array, qids: np.ndarray,
                        stats: JoinStats, *, seeds: np.ndarray,
                        seeds_valid: np.ndarray, cascade=None, qc=None,
                        capctl: RerankCap | None = None, sync: bool = True,
-                       collect_seeds: bool = False) -> WaveHandles:
+                       collect_seeds: bool = False,
+                       wave: int = 0) -> WaveHandles:
     """Dispatch the device phase of one search wave (Alg. 1 online):
     greedy search, range expansion, and the band-compacted re-rank +
     seed-feedback epilogue. With ``sync`` the greedy/expand phases are
@@ -469,64 +470,64 @@ def launch_search_wave(index_y: GraphIndex, xw: Array, qids: np.ndarray,
     escalation counts land in ``stats.n_dist`` / ``stats.n_esc8``).
     ``qc`` optionally supplies queries already encoded on the cascade's
     grids (the streaming path encodes once per wave and reuses the codes
-    for parent assignment).
+    for parent assignment). ``wave`` is the wave's index in its call,
+    for the spans.
     """
     tcfg = effective_tcfg(cfg)
     if capctl is None:
         capctl = RerankCap(tcfg)
-    tr = obs_trace.tracer()
-    lsp = tr.span("wave/launch", lane="assembly")
-    seeds_j = jnp.asarray(seeds)
-    sv_j = jnp.asarray(seeds_valid) & jnp.asarray(lane_valid)[:, None]
-    if cascade is not None and qc is None:
-        qc = cascade.encode(xw)
-    th2 = jnp.float32(cfg.theta) ** 2
+    with obs_trace.span("wave/launch", wave=wave):
+        seeds_j = jnp.asarray(seeds)
+        sv_j = jnp.asarray(seeds_valid) & jnp.asarray(lane_valid)[:, None]
+        if cascade is not None and qc is None:
+            qc = cascade.encode(xw)
+        th2 = jnp.float32(cfg.theta) ** 2
 
-    dev = tr.begin("wave/device", lane="traversal", cap=capctl.cap)
-    t0 = time.perf_counter()
-    g = traversal.greedy_search(
-        index_y, xw, seeds_j, sv_j, cfg.theta, cfg=tcfg,
-        n_data=index_y.n_data, traverse_nondata=True,
-        cascade=cascade, qc=qc)
-    if sync:
-        jax.block_until_ready(g.beam_dist)
-        stats.greedy_seconds += time.perf_counter() - t0
         t0 = time.perf_counter()
+        g = traversal.greedy_search(
+            index_y, xw, seeds_j, sv_j, cfg.theta, cfg=tcfg,
+            n_data=index_y.n_data, traverse_nondata=True,
+            cascade=cascade, qc=qc)
+        if sync:
+            jax.block_until_ready(g.beam_dist)
+            stats.greedy_seconds += time.perf_counter() - t0
+            t0 = time.perf_counter()
 
-    init_valid = (g.beam_idx != NO_NODE) & jnp.isfinite(g.beam_dist)
-    r = traversal.range_expand(
-        index_y, xw, cfg.theta, cfg=tcfg, n_data=index_y.n_data,
-        hybrid=False, traverse_nondata=True,
-        init_idx=g.beam_idx, init_dist=g.beam_dist, init_valid=init_valid,
-        visited=g.visited, best_dist=g.best_dist, best_idx=g.best_idx,
-        n_dist=g.n_dist, cascade=cascade, qc=qc, n_esc=g.n_esc)
-    if sync:
-        jax.block_until_ready(r.pool_idx)
-        stats.expand_seconds += time.perf_counter() - t0
+        init_valid = (g.beam_idx != NO_NODE) & jnp.isfinite(g.beam_dist)
+        r = traversal.range_expand(
+            index_y, xw, cfg.theta, cfg=tcfg, n_data=index_y.n_data,
+            hybrid=False, traverse_nondata=True,
+            init_idx=g.beam_idx, init_dist=g.beam_dist, init_valid=init_valid,
+            visited=g.visited, best_dist=g.best_dist, best_idx=g.best_idx,
+            n_dist=g.n_dist, cascade=cascade, qc=qc, n_esc=g.n_esc,
+            n_slots=g.n_slots, n_lane_iters=g.n_lane_iters)
+        if sync:
+            jax.block_until_ready(r.pool_idx)
+            stats.expand_seconds += time.perf_counter() - t0
 
-    seed_mode = cfg.method if collect_seeds else "none"
-    ee = early_exit_enabled(tcfg)
-    keep, dist, n_amb, seed_ids, seed_valid2, nds, ndt = _finalize_wave(
-        cascade, qc, index_y.vecs, xw, r.pool_idx, r.pool_dist, r.n_pool,
-        jnp.asarray(lane_valid), r.best_idx, th2, cap=capctl.cap,
-        dist_impl=tcfg.dist_impl, seed_mode=seed_mode,
-        seeds_max=tcfg.seeds_max, early_exit=ee)
-    if cascade is not None:
-        stats.n_rerank_gather += int(xw.shape[0]) * capctl.cap
-        stats.bytes_band += (int(xw.shape[0]) * capctl.cap
-                             * int(xw.shape[1]) * 4)
-    lsp.end(lanes=int(np.count_nonzero(lane_valid)), cap=capctl.cap)
-    return WaveHandles(
-        qids=qids, lane_valid=np.asarray(lane_valid), xw=xw,
-        vecs=index_y.vecs, cascade=cascade, qc=qc, th2=th2,
-        pool_idx=r.pool_idx, raw_pool_dist=r.pool_dist, n_pool=r.n_pool,
-        best_idx=r.best_idx, n_dist=r.n_dist, n_esc=r.n_esc,
-        overflow=r.overflow, n_iters=(g.n_iters, r.n_iters),
-        keep=keep, dist=dist, n_amb=n_amb, seed_ids=seed_ids,
-        seed_valid=seed_valid2, n_dims_scanned=nds, n_dims_total=ndt,
-        capctl=capctl, dist_impl=tcfg.dist_impl,
-        seed_mode=seed_mode, seeds_max=tcfg.seeds_max, early_exit=ee,
-        span=dev)
+        seed_mode = cfg.method if collect_seeds else "none"
+        ee = early_exit_enabled(tcfg)
+        keep, dist, n_amb, seed_ids, seed_valid2, nds, ndt = _finalize_wave(
+            cascade, qc, index_y.vecs, xw, r.pool_idx, r.pool_dist, r.n_pool,
+            jnp.asarray(lane_valid), r.best_idx, th2, cap=capctl.cap,
+            dist_impl=tcfg.dist_impl, seed_mode=seed_mode,
+            seeds_max=tcfg.seeds_max, early_exit=ee)
+        if cascade is not None:
+            stats.n_rerank_gather += int(xw.shape[0]) * capctl.cap
+            stats.bytes_band += (int(xw.shape[0]) * capctl.cap
+                                 * int(xw.shape[1]) * 4)
+        return WaveHandles(
+            qids=qids, lane_valid=np.asarray(lane_valid), xw=xw,
+            vecs=index_y.vecs, cascade=cascade, qc=qc, th2=th2,
+            pool_idx=r.pool_idx, raw_pool_dist=r.pool_dist, n_pool=r.n_pool,
+            best_idx=r.best_idx, n_dist=r.n_dist, n_esc=r.n_esc,
+            overflow=r.overflow, n_iters=(g.n_iters, r.n_iters),
+            n_slots=r.n_slots, n_lane_iters=r.n_lane_iters,
+            keep=keep, dist=dist, n_amb=n_amb, seed_ids=seed_ids,
+            seed_valid=seed_valid2, n_dims_scanned=nds, n_dims_total=ndt,
+            capctl=capctl, dist_impl=tcfg.dist_impl,
+            seed_mode=seed_mode, seeds_max=tcfg.seeds_max, early_exit=ee,
+            wave=wave)
 
 
 def run_search_wave(index_y: GraphIndex, xw: Array, qids: np.ndarray,
@@ -662,14 +663,14 @@ def run_search_join(X: Array, index_y: GraphIndex,
         out = assemble_wave(h, stats)
         all_pairs.append(out.pairs)
         t1 = time.perf_counter()
-        with obs_trace.tracer().span("wave/cache_update", lane="assembly"):
+        with obs_trace.span("wave/cache_update", wave=h.wave):
             cache_n = update_sws_cache(cache, out, h.qids, cfg, stats,
                                        cache_n)
             for q in h.qids[h.lane_valid]:
                 overlay.pop(int(q), None)
         stats.other_seconds += time.perf_counter() - t1
 
-    for wave in waves:
+    for k, wave in enumerate(waves):
         qids, lane_valid = pad_wave(wave, cfg.wave_size)
         xw = X[jnp.asarray(qids)]
         t0 = time.perf_counter()
@@ -684,7 +685,8 @@ def run_search_join(X: Array, index_y: GraphIndex,
         h = launch_search_wave(index_y, xw, qids, lane_valid, cfg, stats,
                                seeds=seeds, seeds_valid=seeds_valid,
                                cascade=cascade, capctl=capctl,
-                               sync=not ov, collect_seeds=needs_mst and ov)
+                               sync=not ov, collect_seeds=needs_mst and ov,
+                               wave=k)
         if ov and pending is not None:
             drain(pending)
             pending = None
@@ -706,66 +708,64 @@ def launch_mi_wave(merged: GraphIndex, xw: Array, qids: np.ndarray,
                    lane_valid: np.ndarray, cfg: JoinConfig,
                    stats: JoinStats, *, hybrid: bool, cascade=None,
                    qc=None, capctl: RerankCap | None = None,
-                   sync: bool = True) -> WaveHandles:
+                   sync: bool = True, wave: int = 0) -> WaveHandles:
     """Dispatch the device phase of one merged-index wave (probe +
     BFS/BBFS expansion + band-compacted re-rank). MI waves carry no
     work-sharing cache, so there is no seed feedback — the pipeline
     overlaps the next wave with pure pair assembly."""
-    tcfg = cfg.traversal
-    n_data = merged.n_data
-    node_ids = jnp.asarray(qids, jnp.int32) + n_data
-    lv_j = jnp.asarray(lane_valid)
-    if cascade is not None and qc is None:
-        qc = cascade.encode(xw)
-    th2 = jnp.float32(cfg.theta) ** 2
-    if capctl is None:
-        capctl = RerankCap(tcfg)
-    tr = obs_trace.tracer()
-    lsp = tr.span("wave/launch", lane="assembly")
+    with obs_trace.span("wave/launch", wave=wave):
+        tcfg = cfg.traversal
+        n_data = merged.n_data
+        node_ids = jnp.asarray(qids, jnp.int32) + n_data
+        lv_j = jnp.asarray(lane_valid)
+        if cascade is not None and qc is None:
+            qc = cascade.encode(xw)
+        th2 = jnp.float32(cfg.theta) ** 2
+        if capctl is None:
+            capctl = RerankCap(tcfg)
 
-    dev = tr.begin("wave/device", lane="traversal", cap=capctl.cap)
-    t0 = time.perf_counter()
-    rows, dist, ub, valid, visited, n_new, n_esc0, best, besti = _mi_probe(
-        merged, xw, node_ids, lv_j,
-        traverse_nondata=hybrid, dist_impl=tcfg.dist_impl,
-        cascade=cascade, qc=qc, esc_th2=th2)
-    if sync:
-        jax.block_until_ready(dist)
-        stats.greedy_seconds += time.perf_counter() - t0
         t0 = time.perf_counter()
+        rows, dist, ub, valid, visited, n_new, n_esc0, best, besti = _mi_probe(
+            merged, xw, node_ids, lv_j,
+            traverse_nondata=hybrid, dist_impl=tcfg.dist_impl,
+            cascade=cascade, qc=qc, esc_th2=th2)
+        if sync:
+            jax.block_until_ready(dist)
+            stats.greedy_seconds += time.perf_counter() - t0
+            t0 = time.perf_counter()
 
-    r = traversal.range_expand(
-        merged, xw, cfg.theta, cfg=tcfg, n_data=n_data,
-        hybrid=hybrid, traverse_nondata=hybrid,
-        init_idx=rows, init_dist=dist, init_valid=valid,
-        visited=visited, best_dist=best, best_idx=besti,
-        n_dist=n_new, cascade=cascade, qc=qc, init_ub=ub, n_esc=n_esc0)
-    if sync:
-        jax.block_until_ready(r.pool_idx)
-        stats.expand_seconds += time.perf_counter() - t0
+        r = traversal.range_expand(
+            merged, xw, cfg.theta, cfg=tcfg, n_data=n_data,
+            hybrid=hybrid, traverse_nondata=hybrid,
+            init_idx=rows, init_dist=dist, init_valid=valid,
+            visited=visited, best_dist=best, best_idx=besti,
+            n_dist=n_new, cascade=cascade, qc=qc, init_ub=ub, n_esc=n_esc0,
+            n_slots=jnp.int32(rows.size))
+        if sync:
+            jax.block_until_ready(r.pool_idx)
+            stats.expand_seconds += time.perf_counter() - t0
 
-    ee = early_exit_enabled(tcfg)
-    keep, dist2, n_amb, seed_ids, seed_valid, nds, ndt = _finalize_wave(
-        cascade, qc, merged.vecs, xw, r.pool_idx, r.pool_dist, r.n_pool,
-        lv_j, r.best_idx, th2, cap=capctl.cap, dist_impl=tcfg.dist_impl,
-        seed_mode="none", seeds_max=tcfg.seeds_max, early_exit=ee)
-    if cascade is not None:
-        stats.n_rerank_gather += int(xw.shape[0]) * capctl.cap
-        stats.bytes_band += (int(xw.shape[0]) * capctl.cap
-                             * int(xw.shape[1]) * 4)
-    lsp.end(lanes=int(np.count_nonzero(lane_valid)), cap=capctl.cap,
-            hybrid=hybrid)
-    return WaveHandles(
-        qids=qids, lane_valid=np.asarray(lane_valid), xw=xw,
-        vecs=merged.vecs, cascade=cascade, qc=qc, th2=th2,
-        pool_idx=r.pool_idx, raw_pool_dist=r.pool_dist, n_pool=r.n_pool,
-        best_idx=r.best_idx, n_dist=r.n_dist, n_esc=r.n_esc,
-        overflow=r.overflow, n_iters=(r.n_iters,),
-        keep=keep, dist=dist2, n_amb=n_amb, seed_ids=seed_ids,
-        seed_valid=seed_valid, n_dims_scanned=nds, n_dims_total=ndt,
-        capctl=capctl, dist_impl=tcfg.dist_impl,
-        seed_mode="none", seeds_max=tcfg.seeds_max, early_exit=ee,
-        span=dev)
+        ee = early_exit_enabled(tcfg)
+        keep, dist2, n_amb, seed_ids, seed_valid, nds, ndt = _finalize_wave(
+            cascade, qc, merged.vecs, xw, r.pool_idx, r.pool_dist, r.n_pool,
+            lv_j, r.best_idx, th2, cap=capctl.cap, dist_impl=tcfg.dist_impl,
+            seed_mode="none", seeds_max=tcfg.seeds_max, early_exit=ee)
+        if cascade is not None:
+            stats.n_rerank_gather += int(xw.shape[0]) * capctl.cap
+            stats.bytes_band += (int(xw.shape[0]) * capctl.cap
+                                 * int(xw.shape[1]) * 4)
+        return WaveHandles(
+            qids=qids, lane_valid=np.asarray(lane_valid), xw=xw,
+            vecs=merged.vecs, cascade=cascade, qc=qc, th2=th2,
+            pool_idx=r.pool_idx, raw_pool_dist=r.pool_dist, n_pool=r.n_pool,
+            best_idx=r.best_idx, n_dist=r.n_dist, n_esc=r.n_esc,
+            overflow=r.overflow, n_iters=(r.n_iters,),
+            n_slots=r.n_slots, n_lane_iters=r.n_lane_iters,
+            keep=keep, dist=dist2, n_amb=n_amb, seed_ids=seed_ids,
+            seed_valid=seed_valid, n_dims_scanned=nds, n_dims_total=ndt,
+            capctl=capctl, dist_impl=tcfg.dist_impl,
+            seed_mode="none", seeds_max=tcfg.seeds_max, early_exit=ee,
+            wave=wave)
 
 
 def run_mi_join(X: Array, merged: GraphIndex, cfg: JoinConfig,
@@ -788,11 +788,12 @@ def run_mi_join(X: Array, merged: GraphIndex, cfg: JoinConfig,
     t0 = time.perf_counter()
     if cfg.method == "es_mi_adapt":
         flags = []
-        for q0 in range(0, nq, 4096):
-            q1 = min(q0 + 4096, nq)
-            qid = n_data + jnp.arange(q0, q1, dtype=jnp.int32)
-            flags.append(np.asarray(predict_ood(
-                merged, X[q0:q1], qid, factor=cfg.ood_factor)))
+        with obs_trace.span("join/ood"):
+            for q0 in range(0, nq, 4096):
+                q1 = min(q0 + 4096, nq)
+                qid = n_data + jnp.arange(q0, q1, dtype=jnp.int32)
+                flags.append(np.asarray(predict_ood(
+                    merged, X[q0:q1], qid, factor=cfg.ood_factor)))
         ood = np.concatenate(flags)
         stats.n_ood = int(ood.sum())
     else:
@@ -809,6 +810,7 @@ def run_mi_join(X: Array, merged: GraphIndex, cfg: JoinConfig,
         out = assemble_wave(h, stats, qid_offset=qid_offset)
         all_pairs.append(out.pairs)
 
+    k = 0
     for ids_all, hybrid in groups:
         for c0 in range(0, ids_all.size, cfg.wave_size):
             wave = ids_all[c0:c0 + cfg.wave_size]
@@ -817,7 +819,8 @@ def run_mi_join(X: Array, merged: GraphIndex, cfg: JoinConfig,
             qc = cascade.encode(xw) if cascade is not None else None
             h = launch_mi_wave(merged, xw, qids, lane_valid, cfg, stats,
                                hybrid=hybrid, cascade=cascade, qc=qc,
-                               capctl=capctl, sync=not ov)
+                               capctl=capctl, sync=not ov, wave=k)
+            k += 1
             if ov:
                 if pending is not None:
                     drain(pending)

@@ -68,6 +68,7 @@ def pairwise_hamming_pallas(cx: Array, cy: Array, *, bm: int = 128,
     grid = (B // bm, N // bn)
     return pl.pallas_call(
         _pairwise_hamming_kernel,
+        name="pairwise_hamming",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, W), lambda i, j: (i, 0)),
@@ -101,6 +102,7 @@ def rowwise_hamming_pallas(cx: Array, ccands: Array, *, bm: int = 8,
     grid = (B // bm, K // bkk)
     return pl.pallas_call(
         _rowwise_hamming_kernel,
+        name="rowwise_hamming",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, W), lambda i, j: (i, 0)),

@@ -85,6 +85,7 @@ def pairwise_sq_dists_pallas(x: Array, y: Array, *, bm: int = 256,
     grid = (B // bm, N // bn, nk)
     return pl.pallas_call(
         functools.partial(_pairwise_kernel, nk=nk),
+        name="pairwise_sq_dists",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
@@ -127,6 +128,7 @@ def rowwise_sq_dists_pallas(x: Array, cands: Array, *, bm: int = 8,
     grid = (B // bm, K // bkk, nd)
     return pl.pallas_call(
         functools.partial(_rowwise_kernel, nd=nd),
+        name="rowwise_sq_dists",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, dk), lambda i, j, k: (i, k)),

@@ -92,6 +92,7 @@ def gather_sq_dists_pallas(vecs: Array, x: Array, idx: Array, *,
     )
     return pl.pallas_call(
         _kernel,
+        name="gather_sq_dists",
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((B, K), jnp.float32),
         interpret=interpret,

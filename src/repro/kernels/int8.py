@@ -85,6 +85,7 @@ def pairwise_sq_dists_int8_pallas(qx: Array, qy: Array, scales: Array,
     grid = (B // bm, N // bn, nk)
     return pl.pallas_call(
         functools.partial(_pairwise_i8_kernel, nk=nk),
+        name="pairwise_sq_dists_int8",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, group_size), lambda i, j, k: (i, k)),
@@ -133,6 +134,7 @@ def rowwise_sq_dists_int8_pallas(qx: Array, qcands: Array, scales: Array, *,
     grid = (B // bm, K // bkk, nk)
     return pl.pallas_call(
         _rowwise_i8_kernel,
+        name="rowwise_sq_dists_int8",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, group_size), lambda i, j, k: (i, k)),

@@ -78,6 +78,7 @@ def nlj_count_pallas(x: Array, y: Array, theta: float, *, bm: int = 256,
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
     return pl.pallas_call(
         kernel,
+        name="nlj_count",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

@@ -183,6 +183,7 @@ def pairwise_sq_dists_pdx_pallas(qx: Array, qy: Array, scales: Array,
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
+        name="pairwise_sq_dists_pdx",
         grid=grid,
         in_specs=[
             x_spec,
@@ -323,6 +324,7 @@ def pdx_gather_sq_dists_pallas(vp: Array, vtail: Array, vnorm: Array,
     )
     return pl.pallas_call(
         kernel,
+        name="pdx_gather_sq_dists",
         grid_spec=spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, K), jnp.float32),

@@ -13,6 +13,7 @@ devices; ``--stream B`` feeds queries as streaming batches of B through
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import time
 
@@ -117,13 +118,12 @@ def main(argv=None) -> int:
                     help="alias for --shards 0 (all local devices)")
     ap.add_argument("--no-truth", action="store_true",
                     help="skip the exact NLJ ground truth (big inputs)")
-    ap.add_argument("--trace", metavar="OUT.json", default=None,
-                    help="record per-wave spans and export a Chrome/"
-                         "Perfetto trace (load at ui.perfetto.dev; the "
-                         "traversal and assembly lanes show the pipeline "
-                         "overlap). The REPRO_TRACE env var also enables "
-                         "tracing: 1/on traces to trace.json, any other "
-                         "value is the output path")
+    ap.add_argument("--trace", metavar="DIR", default=None,
+                    help="run the joins under a jax.profiler session "
+                         "that writes to DIR: the program's join/wave "
+                         "spans and the device ops on one clock (an "
+                         ".xplane.pb for TensorBoard/XProf and a "
+                         ".trace.json.gz to load at ui.perfetto.dev)")
     ap.add_argument("--metrics-dump", action="store_true",
                     help="print the engine's metrics registry in "
                          "Prometheus exposition format after the run "
@@ -175,55 +175,49 @@ def main(argv=None) -> int:
         ap.error(f"--stream with --shards supports nlj/es_mi/"
                  f"es_mi_adapt, not {method}")
 
-    trace_path = args.trace or (
-        (obs_trace.env_trace_path() or "trace.json")
-        if obs_trace.env_trace_enabled() else None)
-    if trace_path:
-        tracer = obs_trace.enable()
     print(f"[join] {args.regime} |X|={args.n_query} |Y|={args.n_data} "
           f"dim={args.dim} θ={theta:.4f} method={method} "
           f"shards={eng.n_shards} quant={quant} quant_build={quant_build} "
           f"overlap={'off' if args.no_overlap else 'on'}")
 
-    t0 = time.perf_counter()
-    if args.stream:
-        parts = [eng.submit(ds.X[b0:b0 + args.stream], cfg)
-                 for b0 in range(0, args.n_query, args.stream)]
-        pairs = np.concatenate([r.pairs for r in parts], axis=0)
-        n_dist = sum(r.stats.n_dist for r in parts)
-        dt = time.perf_counter() - t0
-        print(f"[join] {len(parts)} streamed batches: {len(pairs)} pairs "
-              f"in {dt:.2f}s (n_dist={n_dist})")
-    else:
-        res = eng.join(ds.X, cfg)
-        dt = time.perf_counter() - t0
-        extra = (f", rerank={res.stats.n_rerank}, "
-                 f"quant_bytes={res.stats.quant_bytes}"
-                 if quant != "off" else "")
-        if quant == "sketch8":
-            pruned = res.stats.n_dist - res.stats.n_esc8
-            extra += (f", esc8={res.stats.n_esc8}, sketch_pruned={pruned}"
-                      f" ({pruned / max(res.stats.n_dist, 1):.0%})")
-        if quant in ("pdx8", "sketchpdx8"):
-            extra += f", dims_frac={res.stats.dims_scanned_frac:.3f}"
-        print(f"[join] {len(res.pairs)} pairs in {dt:.2f}s "
-              f"(n_dist={res.stats.n_dist}, ood={res.stats.n_ood}, "
-              f"builds={eng.n_index_builds}{extra})")
-        pairs = res.pairs
+    prof = (obs_trace.profile(args.trace) if args.trace
+            else contextlib.nullcontext())
+    with prof:
+        t0 = time.perf_counter()
+        if args.stream:
+            parts = [eng.submit(ds.X[b0:b0 + args.stream], cfg)
+                     for b0 in range(0, args.n_query, args.stream)]
+            pairs = np.concatenate([r.pairs for r in parts], axis=0)
+            n_dist = sum(r.stats.n_dist for r in parts)
+            dt = time.perf_counter() - t0
+            print(f"[join] {len(parts)} streamed batches: {len(pairs)} pairs "
+                  f"in {dt:.2f}s (n_dist={n_dist})")
+        else:
+            res = eng.join(ds.X, cfg)
+            dt = time.perf_counter() - t0
+            extra = (f", rerank={res.stats.n_rerank}, "
+                     f"quant_bytes={res.stats.quant_bytes}"
+                     if quant != "off" else "")
+            if quant == "sketch8":
+                pruned = res.stats.n_dist - res.stats.n_esc8
+                extra += (f", esc8={res.stats.n_esc8}, sketch_pruned={pruned}"
+                          f" ({pruned / max(res.stats.n_dist, 1):.0%})")
+            if quant in ("pdx8", "sketchpdx8"):
+                extra += f", dims_frac={res.stats.dims_scanned_frac:.3f}"
+            print(f"[join] {len(res.pairs)} pairs in {dt:.2f}s "
+                  f"(n_dist={res.stats.n_dist}, ood={res.stats.n_ood}, "
+                  f"builds={eng.n_index_builds}{extra})")
+            pairs = res.pairs
 
-    if args.sweep:
-        for i, th in enumerate(grid):
-            t0 = time.perf_counter()
-            r = eng.join(ds.X, cfg, theta=th)
-            print(f"[sweep] θ{i + 1}={th:.4f}: {len(r.pairs)} pairs in "
-                  f"{time.perf_counter() - t0:.2f}s "
-                  f"(builds={eng.n_index_builds})")
-
-    if trace_path:
-        obs_trace.disable()
-        tracer.export(trace_path)
-        print(f"[join] wrote {tracer.n_events} trace events to "
-              f"{trace_path} (load at ui.perfetto.dev)")
+        if args.sweep:
+            for i, th in enumerate(grid):
+                t0 = time.perf_counter()
+                r = eng.join(ds.X, cfg, theta=th)
+                print(f"[sweep] θ{i + 1}={th:.4f}: {len(r.pairs)} pairs in "
+                      f"{time.perf_counter() - t0:.2f}s "
+                      f"(builds={eng.n_index_builds})")
+    if args.trace:
+        print(f"[join] wrote a profiler trace under {args.trace}")
     if args.metrics_dump:
         print(eng.metrics.prometheus_text(), end="")
 

@@ -13,6 +13,7 @@ after warmup is the service's core invariant).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import time
 
@@ -82,9 +83,11 @@ def main(argv=None) -> int:
                     help="write the metrics snapshot (serve_join.* "
                          "gauges/histograms, engine counters, compile "
                          "counter) as JSON — the CI smoke artifact")
-    ap.add_argument("--trace", metavar="OUT.json", default=None,
-                    help="TraceKit span capture of the serving rounds "
-                         "(load at ui.perfetto.dev)")
+    ap.add_argument("--trace", metavar="DIR", default=None,
+                    help="run warmup and serving under a jax.profiler "
+                         "session that writes to DIR: the service's "
+                         "round/batch spans, the join/wave spans and the "
+                         "device ops on one clock")
     args = ap.parse_args(argv)
     enable_compile_cache()
 
@@ -99,94 +102,87 @@ def main(argv=None) -> int:
                  "(search methods need the whole graph resident)")
     engine_kw = {"n_shards": args.shards} if args.shards != 1 else None
 
-    trace_path = args.trace or (
-        (obs_trace.env_trace_path() or "trace.json")
-        if obs_trace.env_trace_enabled() else None)
-    if trace_path:
-        tracer = obs_trace.enable()
+    prof = (obs_trace.profile(args.trace) if args.trace
+            else contextlib.nullcontext())
+    with prof:
+        svc = JoinService(ServiceConfig(
+            buckets=buckets, max_queue=args.max_queue,
+            max_tenants=args.max_tenants,
+            interleave=not args.no_interleave))
+        base = preset(args.method, theta=1.0)
 
-    svc = JoinService(ServiceConfig(
-        buckets=buckets, max_queue=args.max_queue,
-        max_tenants=args.max_tenants,
-        interleave=not args.no_interleave))
-    base = preset(args.method, theta=1.0)
+        rng = np.random.default_rng(args.seed)
+        tenants: dict[str, tuple] = {}
+        for i in range(args.tenants):
+            regime = _REGIMES[i % len(_REGIMES)]
+            name = f"{regime}-{i}"
+            ds = make_dataset(regime, n_data=args.n_data,
+                              n_query=args.max_request, dim=args.dim,
+                              seed=args.seed + i)
+            theta = float(thresholds(ds, 7)[args.theta_q - 1])
+            svc.load(name, ds.Y, default=base, engine_kw=engine_kw)
+            tenants[name] = (ds, theta)
 
-    rng = np.random.default_rng(args.seed)
-    tenants: dict[str, tuple] = {}
-    for i in range(args.tenants):
-        regime = _REGIMES[i % len(_REGIMES)]
-        name = f"{regime}-{i}"
-        ds = make_dataset(regime, n_data=args.n_data,
-                          n_query=args.max_request, dim=args.dim,
-                          seed=args.seed + i)
-        theta = float(thresholds(ds, 7)[args.theta_q - 1])
-        svc.load(name, ds.Y, default=base, engine_kw=engine_kw)
-        tenants[name] = (ds, theta)
+        t0 = time.perf_counter()
+        n_warm = 0
+        # planner-routed requests resolve to the engine-default quant when
+        # the cost table has nothing cheaper — make sure that point is in
+        # the warmed set so --plan auto cannot mint a new specialization
+        warm_quants = (tuple(dict.fromkeys(quants + (base.quant,)))
+                       if args.plan == "auto" else quants)
+        if not args.no_warmup:
+            for name, (ds, theta) in tenants.items():
+                n_warm += svc.warmup(name, thetas=[theta],
+                                     methods=(args.method,),
+                                     quants=warm_quants)
+        t_warm = time.perf_counter() - t0
+        c_warm = obs_metrics.compile_count()
+        print(f"[serve_join] {len(tenants)} tenants "
+              f"(|Y|={args.n_data} d={args.dim}), ladder={buckets}, "
+              f"warmup: {n_warm} joins in {t_warm:.2f}s "
+              f"({c_warm} compiles)")
 
-    t0 = time.perf_counter()
-    n_warm = 0
-    # planner-routed requests resolve to the engine-default quant when
-    # the cost table has nothing cheaper — make sure that point is in
-    # the warmed set so --plan auto cannot mint a new specialization
-    warm_quants = (tuple(dict.fromkeys(quants + (base.quant,)))
-                   if args.plan == "auto" else quants)
-    if not args.no_warmup:
-        for name, (ds, theta) in tenants.items():
-            n_warm += svc.warmup(name, thetas=[theta],
-                                 methods=(args.method,),
-                                 quants=warm_quants)
-    t_warm = time.perf_counter() - t0
-    c_warm = obs_metrics.compile_count()
-    print(f"[serve_join] {len(tenants)} tenants "
-          f"(|Y|={args.n_data} d={args.dim}), ladder={buckets}, "
-          f"warmup: {n_warm} joins in {t_warm:.2f}s "
-          f"({c_warm} compiles)")
+        names = list(tenants)
+        reqs = []
+        for uid in range(args.requests):
+            name = names[int(rng.integers(len(names)))]
+            ds, theta = tenants[name]
+            n = int(rng.integers(1, args.max_request + 1))
+            lo = int(rng.integers(0, args.max_request - n + 1))
+            if args.plan == "auto":
+                reqs.append(JoinRequest(
+                    uid=uid, tenant=name,
+                    X=np.asarray(ds.X, np.float32)[lo:lo + n], theta=theta))
+            else:
+                reqs.append(JoinRequest(
+                    uid=uid, tenant=name,
+                    X=np.asarray(ds.X, np.float32)[lo:lo + n], theta=theta,
+                    method=args.method, quant=quants[uid % len(quants)]))
+        for r in reqs:
+            svc.submit(r)
 
-    names = list(tenants)
-    reqs = []
-    for uid in range(args.requests):
-        name = names[int(rng.integers(len(names)))]
-        ds, theta = tenants[name]
-        n = int(rng.integers(1, args.max_request + 1))
-        lo = int(rng.integers(0, args.max_request - n + 1))
-        if args.plan == "auto":
-            reqs.append(JoinRequest(
-                uid=uid, tenant=name,
-                X=np.asarray(ds.X, np.float32)[lo:lo + n], theta=theta))
-        else:
-            reqs.append(JoinRequest(
-                uid=uid, tenant=name,
-                X=np.asarray(ds.X, np.float32)[lo:lo + n], theta=theta,
-                method=args.method, quant=quants[uid % len(quants)]))
-    for r in reqs:
-        svc.submit(r)
+        c0 = obs_metrics.compile_count()
+        t0 = time.perf_counter()
+        done = svc.run()
+        dt = time.perf_counter() - t0
+        c1 = obs_metrics.compile_count()
 
-    c0 = obs_metrics.compile_count()
-    t0 = time.perf_counter()
-    done = svc.run()
-    dt = time.perf_counter() - t0
-    c1 = obs_metrics.compile_count()
-
-    served = [sj for sj in done.values() if sj.ok]
-    n_q = sum(len(r.X) for r in reqs if r.uid in done and done[r.uid].ok)
-    n_pairs = sum(len(sj.pairs) for sj in served)
-    h = svc.metrics.get("serve_join.admission_seconds")
-    admit_mean = h.sum / max(h.count, 1)
-    occ = svc.metrics.get("serve_join.occupancy")
-    print(f"[serve_join] served {len(served)}/{len(reqs)} requests "
-          f"({n_q} queries, {n_pairs} pairs) in {dt:.2f}s "
-          f"({n_q / max(dt, 1e-9):.0f} q/s), "
-          f"rejected={svc.stats['rejected']}")
-    print(f"[serve_join] admission latency mean={admit_mean * 1e3:.1f}ms, "
-          f"occupancy mean={occ.sum / max(occ.count, 1):.2f}, "
-          f"compiles during serve: {c1 - c0} "
-          f"({'flat' if c1 == c0 else 'RECOMPILED'})")
-
-    if trace_path:
-        obs_trace.disable()
-        tracer.export(trace_path)
-        print(f"[serve_join] wrote {tracer.n_events} trace events to "
-              f"{trace_path}")
+        served = [sj for sj in done.values() if sj.ok]
+        n_q = sum(len(r.X) for r in reqs if r.uid in done and done[r.uid].ok)
+        n_pairs = sum(len(sj.pairs) for sj in served)
+        h = svc.metrics.get("serve_join.admission_seconds")
+        admit_mean = h.sum / max(h.count, 1)
+        occ = svc.metrics.get("serve_join.occupancy")
+        print(f"[serve_join] served {len(served)}/{len(reqs)} requests "
+              f"({n_q} queries, {n_pairs} pairs) in {dt:.2f}s "
+              f"({n_q / max(dt, 1e-9):.0f} q/s), "
+              f"rejected={svc.stats['rejected']}")
+        print(f"[serve_join] admission latency mean={admit_mean * 1e3:.1f}ms, "
+              f"occupancy mean={occ.sum / max(occ.count, 1):.2f}, "
+              f"compiles during serve: {c1 - c0} "
+              f"({'flat' if c1 == c0 else 'RECOMPILED'})")
+    if args.trace:
+        print(f"[serve_join] wrote a profiler trace under {args.trace}")
     if args.metrics_json:
         snap = svc.metrics_snapshot()
         snap["counters"]["jax.compiles.serve_delta"] = c1 - c0

@@ -1,28 +1,23 @@
-"""TraceKit — tracing + metrics for the join pipeline (obs/trace.py,
-obs/metrics.py).
+"""Observability for the join pipeline: program spans on the profiler's
+clock (obs/trace.py) and the metrics registry (obs/metrics.py).
 
-Ambient accessors: ``trace.tracer()`` is the active span recorder (a
-falsy no-op unless enabled — guard costly attribute computation with
-``if tr:``); ``metrics.metrics()`` is the process-global registry. See
-the submodule docstrings and ARCHITECTURE.md §6 for the span taxonomy
-and transfer-class byte accounting.
+``trace.span(name)`` opens a ``jax.profiler.TraceAnnotation`` (recorded
+only under a profiler session); ``metrics.metrics()`` is the
+process-global registry. See the submodule docstrings and
+ARCHITECTURE.md §6 for the span names and transfer-class byte
+accounting.
 
 ``metrics`` and ``trace`` are exported as submodules (the accessor
 functions keep their short names inside each submodule), so consumers
 import ``from repro.obs import metrics, trace`` and call
-``metrics.metrics()`` / ``trace.tracer()``.
+``metrics.metrics()`` / ``trace.span(...)``.
 """
 from repro.obs import metrics, trace
 from repro.obs.metrics import (LATENCY_BUCKETS, POW2_BUCKETS, Counter,
                                Gauge, Histogram, Metrics)
-from repro.obs.trace import (NOOP_TRACER, Span, Tracer, disable, enable,
-                             env_trace_enabled, env_trace_path, tracer,
-                             tracing)
 
 __all__ = [
     "metrics", "trace",
     "Counter", "Gauge", "Histogram", "Metrics",
     "POW2_BUCKETS", "LATENCY_BUCKETS",
-    "Span", "Tracer", "NOOP_TRACER", "tracer", "enable", "disable",
-    "tracing", "env_trace_enabled", "env_trace_path",
 ]

@@ -15,13 +15,12 @@ runners keep their cheap in-band counter threading (device-side counts
 must ride the shard_map/jit signatures regardless).
 
 A process-global default registry (``metrics()``) serves ambient
-instrumentation (wave-level histograms in engine/waves.py) exactly like
-``trace.tracer()`` serves spans; engines default to it but accept a
-private registry for isolation.
+instrumentation (wave-level histograms in engine/waves.py); engines
+default to it but accept a private registry for isolation.
 
 Everything here is host-side Python on wave/join granularity — dict
 lookups and integer adds, never per-candidate work — so metrics stay on
-unconditionally (unlike spans, which are opt-in).
+unconditionally.
 """
 from __future__ import annotations
 

@@ -39,7 +39,7 @@ stores are actually released, not just unlinked.
 
 Backpressure surfaces through the shared registry plumbing
 (``_MetricsDict`` over ``serve_join.*`` gauges, admission-latency and
-occupancy histograms, TraceKit spans per round/tenant batch); a full
+occupancy histograms, profiler spans per round/tenant batch); a full
 queue or invalid request is recorded as failed via the same
 ``RequestRejected`` path ``ServeEngine`` uses — admission never raises
 into the serving loop.
@@ -190,11 +190,9 @@ class JoinService:
             self._tenants[tenant] = eng
         self._tenants.move_to_end(tenant)
         while len(self._tenants) > self.cfg.max_tenants:
-            name, old = self._tenants.popitem(last=False)
+            _, old = self._tenants.popitem(last=False)
             old.drop_caches()
             self.stats["tenant_evictions"] += 1
-            obs_trace.tracer().instant("serve_join/tenant_evict",
-                                       lane="serve", tenant=name)
         self.stats["tenants"] = len(self._tenants)
         return eng
 
@@ -315,8 +313,6 @@ class JoinService:
             bucket=0, admit_seconds=0.0, ok=False)
         self.failed[req.uid] = reason
         self.stats["rejected"] += 1
-        obs_trace.tracer().instant("serve_join/reject", lane="serve",
-                                   uid=req.uid, reason=reason)
 
     def submit(self, req: JoinRequest) -> bool:
         """Admit one request. Returns False (and records the request as
@@ -351,7 +347,7 @@ class JoinService:
             by_tenant.setdefault(req.tenant, []).append((req, t_enq))
         self.stats["queue_depth"] = 0
         out: list[ServedJoin] = []
-        with obs_trace.tracer().span("serve_join/round", lane="serve"):
+        with obs_trace.span("serve_join/round"):
             for tenant, items in by_tenant.items():
                 out.extend(self._serve_tenant(tenant, items))
         return out
@@ -374,9 +370,8 @@ class JoinService:
             jobs.append((req.X, cfg))
             meta.append((req, t_disp - t_enq, b, offset))
             offset += n
-        with obs_trace.tracer().span("serve_join/tenant_batch",
-                                     lane="serve", tenant=tenant,
-                                     n_requests=len(jobs)):
+        with obs_trace.span("serve_join/tenant_batch", tenant=tenant,
+                            n_requests=len(jobs)):
             if self.interleave:
                 results = eng.submit_many(jobs)
             else:
@@ -422,8 +417,7 @@ class JoinService:
         rng = np.random.default_rng(seed)
         mu = np.asarray(eng.Y, np.float32).mean(axis=0)
         n_run = 0
-        with obs_trace.tracer().span("serve_join/warmup", lane="serve",
-                                     tenant=tenant):
+        with obs_trace.span("serve_join/warmup", tenant=tenant):
             for b in self.cfg.buckets:
                 X = (mu[None, :]
                      + rng.normal(0, 1, (2 * b, d))).astype(np.float32)

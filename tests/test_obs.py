@@ -1,19 +1,22 @@
-"""TraceKit suite — the observability contracts (obs/trace, obs/metrics).
+"""Observability suite — program spans on the profiler's clock, the
+traversal's in-loop counters, and the metrics registry (obs/trace,
+obs/metrics, core/traversal).
 
 Four contracts:
 
-  * **Observation never perturbs** — trace-on and trace-off runs of the
-    same engine emit *identical* pair sets and identical work counters,
-    across quant modes × overlap on/off; the disabled tracer is the
-    falsy ``NOOP_TRACER`` singleton (no events, no allocation).
-  * **Span trees are well-formed** — the exclusive device lane
-    ("traversal") is a serial timeline (clamped async spans never
-    overlap); host-lane spans ("assembly") are disjoint-or-nested like
-    the call stack that produced them; pipelined runs show the two lanes
-    actually overlapping in wall-clock.
-  * **Export is loadable** — ``Tracer.export`` writes Chrome Trace Event
-    JSON (Perfetto-loadable): lane/process metadata, ``X`` complete
-    events with non-negative µs timestamps, thread-scoped instants.
+  * **Observation never perturbs** — runs under a profiler session and
+    runs without one emit *identical* pair sets and identical counters,
+    across quant modes × overlap on/off.
+  * **Spans are profiler annotations** — under a CPU profiler session
+    the program's span names appear bare in ``ProfileData`` (the MI
+    path, the ``es_sws`` search path, a service round), every ``wave/*``
+    span lies inside a ``join`` span, every ``wave/fetch`` inside its
+    parent, and ``launch/join.py --trace DIR`` writes an ``.xplane.pb``
+    that holds them.
+  * **The counters count the work done** — ``n_slots`` is every probed
+    candidate slot (B·K per probe, masked ones included) and
+    ``n_lane_iters`` each valid lane's active iterations, checked against
+    the loop shapes and against each lane traversed alone.
   * **The registry is the single backend** — ``JoinStats.merge`` is an
     associative, field-complete combine (hypothesis); ``publish`` /
     ``from_metrics`` roundtrip through a ``Metrics`` registry;
@@ -22,26 +25,38 @@ Four contracts:
     work-sharing paths.
 
 CI runs this module in the quant-mode matrix (``REPRO_QUANT_MODE``
-narrows the golden parametrization) and in the ``REPRO_TRACE=1`` leg,
-where the launcher smoke additionally exports a ``trace.json`` artifact.
+narrows the golden parametrization).
 """
 import dataclasses
+import glob
 import json
 import os
+import subprocess
+import sys
+import textwrap
+import warnings
+from pathlib import Path
 
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import JoinConfig, TraversalConfig
 from repro.core.types import JoinStats
 from repro.data.vectors import make_dataset, thresholds
 from repro.engine import JoinEngine
+from repro.engine import waves as W
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
 _ENV_MODE = os.environ.get("REPRO_QUANT_MODE")
 GOLDEN_MODES = (_ENV_MODE,) if _ENV_MODE else ("off", "sq8", "pdx8")
+ROOT = Path(__file__).resolve().parents[1]
 
 BK = dict(k=24, degree=12)
+# wall-clock fields: the only ones a profiler session may move
+_CLOCKS = {"greedy_seconds", "expand_seconds", "other_seconds",
+           "wait_seconds", "total_seconds"}
 
 
 def _tc(**kw):
@@ -56,6 +71,10 @@ def _cfg(method, theta, quant="off", *, overlap=True, wave=32, tc=None):
                       wave_size=wave, quant=quant, overlap=overlap)
 
 
+def _counters(stats: JoinStats) -> dict:
+    return {k: v for k, v in stats.as_dict().items() if k not in _CLOCKS}
+
+
 @pytest.fixture(scope="module")
 def ds():
     return make_dataset("manifold", n_data=1500, n_query=96, dim=40,
@@ -67,11 +86,35 @@ def theta(ds):
     return float(thresholds(ds, 3)[1])
 
 
-@pytest.fixture(autouse=True)
-def _tracer_hygiene():
-    """No test may leak an enabled tracer into the rest of the suite."""
-    yield
-    obs_trace.disable()
+class Spans(list):
+    """Host spans of one profiler trace: (name, start_ns, end_ns, stats)."""
+
+    def named(self, name):
+        return [sp for sp in self if sp[0] == name]
+
+
+def _profile_spans(log_dir) -> Spans:
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(f"{log_dir}/plugins/profile/*/*.xplane.pb"))[-1]
+    out = Spans()
+    with warnings.catch_warnings():
+        # reading jaxlib's event stats warns of their type's missing module
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for e in line.events:
+                        out.append((e.name, e.start_ns,
+                                    e.start_ns + e.duration_ns,
+                                    dict(e.stats)))
+    return out
+
+
+def _traced(log_dir, fn):
+    with obs_trace.profile(str(log_dir)):
+        assert obs_trace.recording()
+        res = fn()
+    return res, _profile_spans(log_dir)
 
 
 # -- observation never perturbs ----------------------------------------------
@@ -79,186 +122,243 @@ def _tracer_hygiene():
 
 @pytest.mark.parametrize("quant", GOLDEN_MODES)
 @pytest.mark.parametrize("overlap", [True, False])
-def test_traced_matches_untraced(ds, theta, quant, overlap):
+def test_traced_matches_untraced(ds, theta, quant, overlap, tmp_path):
     """Golden equivalence: tracing is observation, never scheduling —
-    same engine, same config, identical pair sets and work counters with
-    the tracer off vs on."""
+    same engine, same config, identical pair sets and counters with the
+    profiler off vs on."""
     eng = JoinEngine(ds.Y, build_kw=BK, metrics=obs_metrics.Metrics())
     cfg = _cfg("es_mi", theta, quant, overlap=overlap)
     r_plain = eng.join(ds.X, cfg)
-    with obs_trace.tracing() as tr:
-        r_traced = eng.join(ds.X, cfg)
+    r_traced, spans = _traced(tmp_path, lambda: eng.join(ds.X, cfg))
     assert r_traced.pair_set() == r_plain.pair_set(), (quant, overlap)
-    assert r_traced.stats.n_dist == r_plain.stats.n_dist
-    assert r_traced.stats.n_rerank == r_plain.stats.n_rerank
-    assert tr.n_events > 0
+    assert _counters(r_traced.stats) == _counters(r_plain.stats)
+    assert spans.named("wave/assemble")
 
 
-def test_traced_matches_untraced_search_path(ds, theta):
+def test_traced_matches_untraced_search_path(ds, theta, tmp_path):
     """Same contract on the work-sharing search path (hit/miss counters
     and the cache-update span live there)."""
     eng = JoinEngine(ds.Y, build_kw=BK, metrics=obs_metrics.Metrics())
     cfg = _cfg("es_hws", theta)
     r_plain = eng.join(ds.X, cfg)
-    with obs_trace.tracing() as tr:
-        r_traced = eng.join(ds.X, cfg)
+    r_traced, spans = _traced(tmp_path, lambda: eng.join(ds.X, cfg))
     assert r_traced.pair_set() == r_plain.pair_set()
-    assert r_traced.stats.cache_hits == r_plain.stats.cache_hits
-    assert r_traced.stats.cache_misses == r_plain.stats.cache_misses
-    assert tr.n_events > 0
+    assert _counters(r_traced.stats) == _counters(r_plain.stats)
+    assert r_plain.stats.cache_hits > 0
+    assert spans.named("wave/cache_update")
 
 
-def test_noop_tracer_is_falsy_singleton():
-    tr = obs_trace.tracer()
-    assert tr is obs_trace.NOOP_TRACER
-    assert not tr and not tr.enabled
-    sp = tr.span("x", lane="l", a=1)
-    assert sp is tr.begin("y")          # one shared no-op span
-    assert not sp
-    with sp as s:
-        assert s.set(b=2) is s          # chainable, records nothing
-    assert sp.end() is None
-    assert tr.instant("z", n=3) is None
+def test_spans_open_without_a_session_and_calls_nest():
+    """Spans always open; without a profiler session ``recording()`` is
+    false, so call sites compute no attributes. Each call gets a fresh
+    id, and a nested call its own."""
+    assert not obs_trace.recording()
+    with obs_trace.span("wave/assemble", wave=3) as sp:
+        sp.set_metadata(pairs=1)
+    with obs_trace.call_span("join") as call:
+        assert call > 0
+        with obs_trace.call_span("join") as inner:
+            assert inner > call
 
 
-def test_enable_disable_roundtrip():
-    t = obs_trace.enable()
-    assert obs_trace.tracer() is t and t and t.enabled
-    assert obs_trace.disable() is t
-    assert obs_trace.tracer() is obs_trace.NOOP_TRACER
+# -- spans are profiler annotations ------------------------------------------
 
 
-def test_tracing_scope_restores_previous():
-    outer = obs_trace.enable()
-    with obs_trace.tracing() as inner:
-        assert obs_trace.tracer() is inner is not outer
-    assert obs_trace.tracer() is outer
+def _serve_round(ds, theta):
+    from repro.serve import JoinRequest, JoinService, ServiceConfig
+    svc = JoinService(ServiceConfig(buckets=(32,)),
+                      metrics=obs_metrics.Metrics())
+    svc.load("t", ds.Y, build_kw=BK, default=_cfg("es_sws", theta))
+    svc.warmup("t", thetas=[theta])
+    for uid in range(2):
+        svc.submit(JoinRequest(uid=uid, tenant="t",
+                               X=np.asarray(ds.X[uid * 20:uid * 20 + 20]),
+                               theta=theta, method="es_sws"))
+    return svc.run()
 
 
-def test_env_trace_tokens(monkeypatch):
-    monkeypatch.delenv("REPRO_TRACE", raising=False)
-    assert not obs_trace.env_trace_enabled()
-    assert obs_trace.env_trace_path() is None
-    for v in ("", "  ", "0", "off", "FALSE", "no"):
-        monkeypatch.setenv("REPRO_TRACE", v)
-        assert not obs_trace.env_trace_enabled(), v
-    for v in ("1", "on", "TRUE", "yes"):
-        monkeypatch.setenv("REPRO_TRACE", v)
-        assert obs_trace.env_trace_enabled(), v
-        assert obs_trace.env_trace_path() is None, v
-    monkeypatch.setenv("REPRO_TRACE", "/tmp/run.json")
-    assert obs_trace.env_trace_enabled()
-    assert obs_trace.env_trace_path() == "/tmp/run.json"
-
-
-# -- span trees are well-formed ----------------------------------------------
-
-
-def test_span_end_is_idempotent():
-    with obs_trace.tracing() as tr:
-        sp = tr.span("a")
-        sp.end(n=1)
-        sp.end(n=2)
-    assert tr.n_events == 1
-    assert tr.lanes()["host"][0]["attrs"] == {"n": 1}
-
-
-def test_exclusive_lane_clamps_to_serial():
-    """Two async spans opened back-to-back (double-buffered dispatch):
-    the second's start is clamped to the first's end."""
-    with obs_trace.tracing() as tr:
-        a = tr.begin("d1", lane="dev")
-        b = tr.begin("d2", lane="dev")
-        a.end()
-        b.end()
-    evs = tr.lanes()["dev"]
-    assert len(evs) == 2
-    assert evs[1]["ts_ns"] >= evs[0]["ts_ns"] + evs[0]["dur_ns"]
-
-
-def _intervals(events):
-    return [(e["ts_ns"], e["ts_ns"] + e["dur_ns"]) for e in events]
+# what each traced run must show, by path
+TRACED = {
+    "es_mi_adapt": {"join", "join/prepare", "join/ood", "index/build",
+                    "wave/launch", "wave/band", "wave/fetch",
+                    "wave/assemble"},
+    "es_sws": {"join", "join/prepare", "index/build", "wave/launch",
+               "wave/band", "wave/feedback", "wave/fetch", "wave/assemble",
+               "wave/cache_update"},
+    "serve": {"serve_join/warmup", "serve_join/round",
+              "serve_join/tenant_batch", "join", "join/prepare",
+              "wave/launch", "wave/band", "wave/feedback", "wave/fetch",
+              "wave/assemble", "wave/cache_update"},
+}
 
 
 @pytest.fixture(scope="module")
-def traced_run(ds, theta):
-    """One pipelined sq8 es_mi join under a tracer (shared by the
-    well-formedness and export tests)."""
-    eng = JoinEngine(ds.Y, build_kw=BK, metrics=obs_metrics.Metrics())
-    with obs_trace.tracing() as tr:
-        res = eng.join(ds.X, _cfg("es_mi", theta, "sq8", overlap=True))
-    return tr, res
+def traced_runs(ds, theta, tmp_path_factory):
+    """One traced run per path, from a cold engine (so the index builds
+    fall inside the session)."""
+    out = {}
+    for path in TRACED:
+        log = tmp_path_factory.mktemp(f"prof-{path}")
+        if path == "serve":
+            fn = lambda: _serve_round(ds, theta)        # noqa: E731
+        else:
+            def fn(m=path):
+                eng = JoinEngine(ds.Y, build_kw=BK,
+                                 metrics=obs_metrics.Metrics())
+                return eng.join(ds.X, _cfg(m, theta))
+        out[path] = _traced(log, fn)
+    return out
 
 
-def test_trace_lanes_well_formed(traced_run):
-    tr, _ = traced_run
-    lanes = tr.lanes()
-    assert "traversal" in lanes and "assembly" in lanes
-    for evs in lanes.values():
-        for ev in evs:
-            assert ev["ts_ns"] >= 0 and ev["dur_ns"] >= 0
-    # exclusive device lane: a serial timeline (instants may land inside)
-    prev_end = -1
-    for ev in lanes["traversal"]:
-        if ev["dur_ns"] == 0:
+@pytest.mark.parametrize("path", sorted(TRACED))
+def test_span_names_appear_bare(traced_runs, path):
+    res, spans = traced_runs[path]
+    names = {sp[0] for sp in spans}
+    assert TRACED[path] <= names, TRACED[path] - names
+    # the retired device lane and its instants are gone
+    assert not names & {"wave/device", "wave/overflow_retry",
+                        "serve_join/reject"}
+    # attributes ride as event stats, never in the name
+    waves = [sp for sp in spans if sp[0].startswith("wave/")]
+    assert all("wave" in sp[3] and "call" in sp[3] for sp in waves)
+
+
+@pytest.mark.parametrize("path", sorted(TRACED))
+def test_wave_spans_nest_in_join_and_fetch_in_parent(traced_runs, path):
+    _, spans = traced_runs[path]
+    joins = spans.named("join")
+    parents = [sp for sp in spans
+               if sp[0] in ("wave/band", "wave/feedback", "wave/assemble")]
+    for name, t0, t1, st in spans:
+        if not name.startswith("wave/"):
             continue
-        assert ev["ts_ns"] >= prev_end
-        prev_end = ev["ts_ns"] + ev["dur_ns"]
-    # host lane: spans nest like the call stack — disjoint or contained
-    host = _intervals(lanes["assembly"])
-    for i, (a0, a1) in enumerate(host):
-        for b0, b1 in host[i + 1:]:     # sorted by start: b0 >= a0
-            assert b0 >= a1 or b1 <= a1, ((a0, a1), (b0, b1))
+        outer = [j for j in joins if j[1] <= t0 and t1 <= j[2]]
+        assert outer, name
+        # the wave's spans carry the call id of the join around them
+        assert st["call"] in {j[3]["call"] for j in outer}, (name, st)
+        if name == "wave/fetch":
+            assert any(p[1] <= t0 and t1 <= p[2] and p[3]["wave"] ==
+                       st["wave"] for p in parents), st
 
 
-def test_pipelined_lanes_overlap_in_time(traced_run):
-    """The acceptance criterion: with overlap on, device (traversal)
-    spans and host (assembly) spans intersect in wall-clock — the
-    pipeline actually hides host work behind the device."""
-    tr, _ = traced_run
-    lanes = tr.lanes()
-    dev = [iv for iv, e in zip(_intervals(lanes["traversal"]),
-                               lanes["traversal"]) if e["dur_ns"] > 0]
-    host = _intervals(lanes["assembly"])
-    assert any(h0 < d1 and d0 < h1
-               for d0, d1 in dev for h0, h1 in host)
+def test_launch_join_trace_dir_writes_xplane(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(ROOT / "src"))
+    p = subprocess.run(
+        [sys.executable, "-m", "repro.launch.join", "--method",
+         "es_mi_adapt", "--n-data", "800", "--n-query", "48", "--dim",
+         "16", "--wave", "16", "--engine-spec", "ci", "--trace",
+         str(tmp_path / "prof")],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stdout + p.stderr
+    assert "sound=True" in p.stdout, p.stdout
+    spans = _profile_spans(tmp_path / "prof")
+    names = {sp[0] for sp in spans}
+    assert {"join", "index/build", "wave/launch", "wave/assemble",
+            "wave/fetch"} <= names, names
+    assert glob.glob(str(tmp_path / "prof/plugins/profile/*/*.trace.json.gz"))
 
 
-def test_span_summary_and_attrs(traced_run):
-    tr, res = traced_run
-    summ = tr.summary()
-    assert summ[("traversal", "wave/device")][0] >= 1
-    assert summ[("assembly", "wave/assemble")][0] >= 1
-    # every device span carries the re-rank cap attribute
-    for ev in tr.lanes()["traversal"]:
-        if ev["name"] == "wave/device":
-            assert "cap" in ev["attrs"]
-    # transfer-class byte counters moved alongside the spans
-    assert res.stats.bytes_assembly > 0
-    assert res.stats.bytes_band > 0
+# -- the counters count the work done ----------------------------------------
 
 
-# -- export is loadable ------------------------------------------------------
+@pytest.fixture(scope="module")
+def eng(ds):
+    return JoinEngine(ds.Y, build_kw=BK, metrics=obs_metrics.Metrics())
 
 
-def test_perfetto_export_schema(tmp_path, traced_run):
-    tr, _ = traced_run
-    path = tmp_path / "trace.json"
-    tr.export(str(path))
-    doc = json.loads(path.read_text())
-    evs = doc["traceEvents"]
-    assert isinstance(evs, list) and evs
-    lanes = {e["args"]["name"] for e in evs
-             if e["ph"] == "M" and e["name"] == "thread_name"}
-    assert {"traversal", "assembly"} <= lanes
-    assert any(e["ph"] == "M" and e["name"] == "process_name" for e in evs)
-    for e in evs:
-        if e["ph"] == "X":
-            assert e["ts"] >= 0 and e["dur"] >= 0
-        elif e["ph"] == "i":
-            assert e["s"] == "t"
-        if "args" in e:
-            json.dumps(e["args"])       # attrs stayed JSON-serializable
+def _one_wave(eng, ds, theta, path, lanes, B):
+    """Launch and assemble one wave of ``lanes`` real queries padded to
+    ``B``; returns (stats, per-lane active iterations, the config)."""
+    cfg = _cfg("es_mi" if path == "mi" else "es", theta, wave=B)
+    qids, lane_valid = W.pad_wave(np.asarray(lanes, np.int32), B)
+    xw = jnp.asarray(np.asarray(ds.X)[qids])
+    stats = JoinStats()
+    if path == "mi":
+        h = W.launch_mi_wave(eng.merged_index(ds.X), xw, qids, lane_valid,
+                             cfg, stats, hybrid=False)
+    else:
+        iy = eng.index_y()
+        S = cfg.traversal.seeds_max
+        seeds, sv = W.seeds_from_cache(qids, lane_valid, {}, {},
+                                       int(iy.start), B, S)
+        h = W.launch_search_wave(iy, xw, qids, lane_valid, cfg, stats,
+                                 seeds=seeds, seeds_valid=sv)
+    W.assemble_wave(h, stats)
+    return stats, np.asarray(h.n_lane_iters), cfg
+
+
+@pytest.mark.parametrize("path", ["mi", "search"])
+def test_n_slots_counts_every_probed_slot(eng, ds, theta, path):
+    """B·K per probe, masked slots included: the initial probe (the
+    merged index's neighbour row, B·R, or the search's seeds, B·S) and
+    B·E·R per loop iteration."""
+    B = 8
+    stats, _, cfg = _one_wave(eng, ds, theta, path, range(6), B)
+    E = cfg.traversal.expand_per_iter
+    if path == "mi":
+        R = eng.merged_index(ds.X).nbrs.shape[1]
+        first = B * R
+    else:
+        R = eng.index_y().nbrs.shape[1]
+        first = B * cfg.traversal.seeds_max
+    assert stats.n_iters > 0
+    assert stats.n_slots == first + stats.n_iters * B * E * R
+    assert 0 < stats.n_dist < stats.n_slots
+
+
+@pytest.mark.parametrize("path", ["mi", "search"])
+def test_n_lane_iters_matches_each_lane_alone(eng, ds, theta, path):
+    """A lane's active iterations in a wave equal the iterations its
+    traversal takes alone (a one-lane wave runs until that lane is
+    done); padding lanes are left out of the sum."""
+    lanes = [3, 17, 40, 41, 77, 90]
+    B = 8
+    stats, per_lane, _ = _one_wave(eng, ds, theta, path, lanes, B)
+    ref = [_one_wave(eng, ds, theta, path, [q], 1)[0].n_iters
+           for q in lanes]
+    assert per_lane[:len(lanes)].tolist() == ref
+    assert stats.n_lane_iters == sum(ref)
+    assert stats.n_lane_iters <= B * stats.n_iters
+
+
+_SHARDED = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    import jax
+    from repro.core import TraversalConfig
+    from repro.core.distributed import (build_sharded_merged_index,
+                                        distributed_mi_join)
+    from repro.data.vectors import make_dataset, thresholds
+
+    ds = make_dataset("manifold", n_data=600, n_query=40, dim=16, seed=5)
+    theta = float(thresholds(ds, 3)[1])
+    mesh = jax.make_mesh((2,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    smi = build_sharded_merged_index(ds.Y, ds.X, 2, k=16, degree=8)
+    tc = TraversalConfig(beam_width=32, expand_per_iter=4, pool_cap=256,
+                         hybrid_beam=32, seeds_max=8, max_iters=512)
+    B, S = 16, 2
+    _, st = distributed_mi_join(ds.X, smi, mesh, "data", theta=theta,
+                                cfg=tc, wave_size=B)
+    R = smi.nbrs.shape[-1]
+    waves = -(-40 // B)
+    assert st.n_iters > 0
+    assert st.n_slots == waves * S * B * R + st.n_iters * B * 4 * R, st
+    assert 0 < st.n_lane_iters <= B * st.n_iters, st
+    assert st.n_dist < st.n_slots
+    print("SHARDED_COUNTERS_OK")
+""")
+
+
+def test_sharded_path_counts_slots_and_lane_iters():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(ROOT / "src"))
+    p = subprocess.run([sys.executable, "-c", _SHARDED], env=env,
+                       capture_output=True, text=True, timeout=600,
+                       cwd=ROOT)
+    assert p.returncode == 0, p.stdout + p.stderr
+    assert "SHARDED_COUNTERS_OK" in p.stdout
 
 
 # -- metrics registry --------------------------------------------------------

@@ -6,12 +6,15 @@ placement, fast-memory limits. Each test here lowers one ``kernels.ops``
 entry with ``impl="pallas"`` for one chip of a described ``v5e:2x2``
 (nothing runs; no chip is needed) at the SIFT width d=128 and the GIST
 width d=960, and asserts the compiled program holds the kernel
-(``tpu_custom_call``).
+(``tpu_custom_call``) under the stable name its ``pallas_call`` gives it,
+the name a profiler trace shows for its device op.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every xdist worker imports this
 file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -125,5 +128,9 @@ def test_kernel_compiles_for_v5e(one_chip, name, d):
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     args, kw = shapes(S, d)
-    compiled = fn.lower(*args, impl="pallas", **kw).compile()
-    assert "tpu_custom_call" in compiled.as_text(), (name, d)
+    text = fn.lower(*args, impl="pallas", **kw).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert calls, (name, d)
+    kernel = name.removesuffix("_slab128")
+    assert all(re.search(rf"%{kernel}(\.\d+)? = ", ln) for ln in calls), (
+        name, d, [ln.split(" = ")[0] for ln in calls])
