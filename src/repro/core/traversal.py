@@ -3,10 +3,16 @@
 The paper's single-thread pointer-chasing loops become batched, fixed-shape
 `lax.while_loop`s over a wave of B queries:
 
-  * priority queue  → sorted beam (L entries) merged with `argsort`;
-  * `visited` set   → per-lane uint32 bitmap in HBM (bit-scatter with
-                      `.at[].add`, safe because candidates are deduped so
-                      every (word, bit) is contributed at most once);
+  * priority queue  → sorted beam (L entries), merged by one stable row
+                      sort that carries ids, flags and bounds as operands;
+  * `visited` set   → per-lane uint32 bitmap in HBM: one word lookup per
+                      candidate slot, then a bit-scatter with `.at[].add`,
+                      safe because candidates are deduped so every
+                      (word, bit) is contributed at most once;
+  * in-batch dedup  → a stable row sort of (id, slot) flags every repeat
+                      after an id's first slot; a second row sort, keyed
+                      on the slots, returns the flags to their own slots
+                      (no per-slot gather or scatter);
   * per-node dist   → one fused rowwise-distance kernel per iteration over
                       all lanes' gathered neighbor rows (paper C4 hot spot);
   * early stopping  → per-lane plateau counters; converged lanes are masked
@@ -133,16 +139,19 @@ def _probe(vecs: Array, x: Array, cand: Array, valid: Array, visited: Array,
         bit = jnp.uint32(1) << (cand_c & 31).astype(jnp.uint32)
         words = jnp.take_along_axis(visited, w, axis=1)
         valid = valid & ((words & bit) == 0)
-        # in-batch dedup (two expanded nodes sharing a neighbor)
+        # in-batch dedup (two expanded nodes sharing a neighbor): a stable
+        # row sort carries each slot's position with its id, so the first
+        # occurrence of an id is the one kept; sorting the flags by those
+        # positions (a permutation) puts each back in its own slot
         sort_key = jnp.where(valid, cand, _SORT_PAD)
-        order = jnp.argsort(sort_key, axis=1)
-        sorted_ids = jnp.take_along_axis(sort_key, order, axis=1)
+        pos = jax.lax.broadcasted_iota(jnp.int32, (B, K), 1)
+        sorted_ids, order = jax.lax.sort((sort_key, pos), dimension=1,
+                                         is_stable=True, num_keys=1)
         dup = jnp.concatenate(
             [jnp.zeros((B, 1), bool),
              sorted_ids[:, 1:] == sorted_ids[:, :-1]],
             axis=1) & (sorted_ids != _SORT_PAD)
-        keep = jnp.put_along_axis(jnp.ones_like(valid), order, ~dup,
-                                  axis=1, inplace=False)
+        _, keep = jax.lax.sort((order, ~dup), dimension=1, num_keys=1)
         valid = valid & keep
     # distances (masked)
     n_esc = jnp.zeros((B,), jnp.int32)
@@ -186,16 +195,22 @@ def _expand(index_vecs: Array, index_nbrs: Array, x: Array, sel_ids: Array,
     return cand, dist, ub, valid, visited, n_new, n_esc
 
 
+def _sorted_prefix(L: int, key: Array, *cols: Array) -> tuple[Array, ...]:
+    """``(key, *cols)`` reordered by a stable ascending sort of ``key``
+    along each row, first ``L`` entries; the columns ride in the sort as
+    operands, so no permutation is applied afterwards."""
+    out = jax.lax.sort((key, *cols), dimension=1, is_stable=True,
+                       num_keys=1)
+    return tuple(c[:, :L] for c in out)
+
+
 def _beam_merge(bd, bi, bexp, cd, ci, cexp):
     """Merge beam with candidates, keep L smallest; carry expanded flags."""
     L = bd.shape[1]
     alld = jnp.concatenate([bd, cd], axis=1)
     alli = jnp.concatenate([bi, ci], axis=1)
     alle = jnp.concatenate([bexp, cexp], axis=1)
-    order = jnp.argsort(alld, axis=1)[:, :L]
-    return (jnp.take_along_axis(alld, order, axis=1),
-            jnp.take_along_axis(alli, order, axis=1),
-            jnp.take_along_axis(alle, order, axis=1))
+    return _sorted_prefix(L, alld, alli, alle)
 
 
 def _hybrid_merge(bd, bi, bexp, bub, cd, ci, cexp, cub, *, protect_th2):
@@ -216,14 +231,10 @@ def _hybrid_merge(bd, bi, bexp, bub, cd, ci, cexp, cub, *, protect_th2):
     alli = jnp.concatenate([bi, ci], axis=1)
     alle = jnp.concatenate([bexp, cexp], axis=1)
     allu = jnp.concatenate([bub, cub], axis=1)
-    key = alld
-    if protect_th2 is not None:
-        key = jnp.where(allu < protect_th2, allu - _PROTECT_OFF, alld)
-    order = jnp.argsort(key, axis=1)[:, :L]
-    return (jnp.take_along_axis(alld, order, axis=1),
-            jnp.take_along_axis(alli, order, axis=1),
-            jnp.take_along_axis(alle, order, axis=1),
-            jnp.take_along_axis(allu, order, axis=1))
+    if protect_th2 is None:
+        return _sorted_prefix(L, alld, alli, alle, allu)
+    key = jnp.where(allu < protect_th2, allu - _PROTECT_OFF, alld)
+    return _sorted_prefix(L, key, alld, alli, alle, allu)[1:]
 
 
 # ---------------------------------------------------------------------------

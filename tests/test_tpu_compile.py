@@ -13,6 +13,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every xdist worker imports this
 file.
 """
+import math
 import re
 
 import jax
@@ -20,6 +21,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import traversal
+from repro.core.types import GraphIndex, TraversalConfig
 from repro.kernels import ops
 from repro.quant.pdx import DEFAULT_SLAB
 
@@ -134,3 +137,68 @@ def test_kernel_compiles_for_v5e(one_chip, name, d):
     kernel = name.removesuffix("_slab128")
     assert all(re.search(rf"%{kernel}(\.\d+)? = ", ln) for ln in calls), (
         name, d, [ln.split(" = ")[0] for ln in calls])
+
+
+def _wide_gathers_scatters(text, n_idx):
+    """``(op, dtype, scopes)`` of every gather and scatter in a compiled
+    HLO module that takes ``n_idx`` indices. A scatter inside a fusion
+    carries no metadata of its own; its scopes are then those named
+    anywhere in its fused computation."""
+    blocks, cur = [], None
+    for ln in text.splitlines():
+        if cur is None:
+            if ln.endswith("{") and not ln.startswith(" "):
+                cur = []
+        elif ln.startswith("}"):
+            blocks.append(cur)
+            cur = None
+        else:
+            cur.append(ln)
+    found = []
+    for blk in blocks:
+        shapes = {m[1]: [int(v) for v in m[2].split(",") if v]
+                  for m in (re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+"
+                                     r"\[([\d,]*)\]", ln) for ln in blk)
+                  if m}
+        names = re.findall(r'op_name="([^"]*)"', "\n".join(blk))
+        for ln in blk:
+            m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[[\d,]*\]\S* "
+                         r"(gather|scatter)\(%[\w.\-]+, %([\w.\-]+)", ln)
+            if not m:
+                continue
+            dims = shapes[m[3]]
+            ivd = int(re.search(r"index_vector_dim=(\d+)", ln)[1])
+            if math.prod(dims) // (dims[ivd] if ivd < len(dims) else 1) \
+                    != n_idx:
+                continue
+            own = re.search(r'op_name="([^"]*)"', ln)
+            found.append((m[2], m[1], [own[1]] if own else names))
+    return found
+
+
+def test_range_expand_dedup_sorts_without_gather_or_scatter(one_chip):
+    """The BFS loop's in-batch dedup is two row sorts: under ``visited``
+    the only gather and scatter that take all B·K candidate slots are the
+    bitmap's word lookup and its update (u32), and no B·K-wide flag
+    scatter (``pred``) is left anywhere in the compiled loop."""
+    B, K, d, R = 256, 128, 128, 32
+    cfg = TraversalConfig()
+    assert cfg.expand_per_iter * R == K
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    index = GraphIndex(vecs=S((N, d), f32), nbrs=S((N, R), i32),
+                       start=S((), i32), mean_nbr_dist=S((N,), f32),
+                       n_data=N)
+    text = traversal.range_expand.lower(
+        index, S((B, d), f32), S((), f32), cfg=cfg, n_data=N,
+        hybrid=False, traverse_nondata=True, init_idx=S((B, R), i32),
+        init_dist=S((B, R), f32), init_valid=S((B, R), jnp.bool_),
+        visited=S((B, traversal.bitmap_words(N)), u32),
+        best_dist=S((B,), f32), best_idx=S((B,), i32),
+        n_dist=S((B,), i32)).compile().as_text()
+    assert not re.search(rf"= pred\[{B * K}\]\S* scatter\(", text)
+    wide = _wide_gathers_scatters(text, B * K)
+    in_visited = sorted((op, dtype) for op, dtype, scopes in wide
+                        if any("/while/body/visited/" in s for s in scopes))
+    assert in_visited == [("gather", "u32"), ("scatter", "u32")], wide
