@@ -1,19 +1,10 @@
-"""The general traffic generator. A traffic file names it by its
-``driver`` key and gives its parameters; adding a mix is adding a file.
+"""Back-to-back one-shot joins: the cell's whole query set handed to
+``engine.join`` again and again.
 
-A driver has four steps, which ``run.py`` calls in order:
-
-  ``setup()``   data from the seed, the program's state, the index build
-                and a warm-up of exactly the window's shapes;
-  ``window()``  the measured traffic (nothing compiles here);
-  ``release()`` frees the program's state once ``memory_peak_bytes`` is
-                read, so the reference does not set the peak;
-  ``check()``   the plain reference over a sample drawn from the seed,
-                against every answer due in the window.
-
-The program is driven only through ``make_engine`` and ``JoinEngine``,
-with inputs generated here.
-"""
+Traffic parameters: ``method`` and ``quant`` (the program's join preset and
+storage mode), ``index`` (``"merged"`` builds G_(X u Y) in set-up; absent,
+no index is built), ``check_queries`` (queries the reference judges) and
+``trace_seconds`` (the traced run's window)."""
 from __future__ import annotations
 
 import dataclasses
@@ -24,23 +15,8 @@ import time
 import numpy as np
 
 from harness.data import Deployment
+from harness.driver import Call, annotate
 from harness.reference import Tally, reference
-
-
-def annotate(name: str):
-    """A host span in the profiler's trace (free when no trace runs)."""
-    import jax
-    return jax.profiler.TraceAnnotation(name)
-
-
-@dataclasses.dataclass
-class Call:
-    """One timed one-shot join."""
-    t0: float
-    t1: float
-    n_queries: int
-    stats: object          # the program's JoinStats of the call
-    pairs: np.ndarray
 
 
 class OneShot:
@@ -50,14 +26,15 @@ class OneShot:
     for the device."""
     build_s: float | None = None
 
-    def __init__(self, cfg: dict, traffic: dict, seed: int, seconds: float):
-        self.cfg, self.traffic = cfg, traffic
+    def __init__(self, cell, seed: int, seconds: float):
+        self.cell = cell
+        self.cfg, self.traffic = cell.cfg, cell.traffic
         self.seed, self.seconds = seed, seconds
-        self.theta = float(cfg["theta"])
+        self.theta = float(self.cfg["theta"])
         self.calls: list[Call] = []
 
     def make_data(self) -> None:
-        self.dep = Deployment(self.cfg, self.seed)
+        self.dep = Deployment(self.cfg, self.seed, self.cell.regime)
         self.X = self.dep.X
 
     def join_config(self):
@@ -104,7 +81,8 @@ class OneShot:
         rng = np.random.default_rng([self.seed, 1])
         sample = np.sort(rng.choice(n, size=k, replace=False))
         with annotate("bench.reference"):
-            ref = reference(self.X[sample], self.dep.Y, self.theta)
+            ref = reference(self.X[sample], self.dep.Y, self.theta,
+                            self.cell.space)
         qmap = {int(q): i for i, q in enumerate(sample)}
         tally, judged = Tally(), {}
         for c in self.calls:
@@ -118,4 +96,4 @@ class OneShot:
         return tally
 
 
-DRIVERS = {"oneshot": OneShot}
+Driver = OneShot

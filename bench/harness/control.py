@@ -1,9 +1,11 @@
 """The control: the plain reference put in the program's place, computed
 one precision below what the configuration states — bfloat16 vectors for
 a float32 deployment, the step that would tempt a change that halves the
-bytes per candidate row. The benchmark's comparison has to call its
-answers not correct; ``tools/control.py`` runs it at a cell's own size and
-``tests/bench`` keeps it at a small one."""
+bytes per candidate row. The evaluation is the configuration's space's
+(``low_table`` and ``low_within`` of ``bench/spaces/<metric>.py``). The
+benchmark's comparison has to call its answers not correct;
+``tools/control.py`` runs it at a cell's own size and ``tests/bench`` keeps
+it at a small one."""
 from __future__ import annotations
 
 import numpy as np
@@ -11,27 +13,22 @@ import numpy as np
 PRECISIONS = {"float32": "bfloat16"}
 
 
-def control_pairs(X: np.ndarray, Y: np.ndarray, theta: float, *,
+def control_pairs(X: np.ndarray, Y: np.ndarray, theta: float, space, *,
                   precision: str = "float32", block: int = 512
                   ) -> np.ndarray:
-    """Every (query, row) pair closer than θ, by the matmul form on
-    vectors rounded to the precision below ``precision`` and sums in
-    float32, on the default device."""
+    """Every (query, row) pair that joins by the space's evaluation on
+    vectors rounded to the precision below ``precision``, on the default
+    device."""
     import jax
     import jax.numpy as jnp
 
     low = jnp.dtype(PRECISIONS[precision])
-    Yl = jnp.asarray(Y).astype(low)
-    yn = jnp.sum(jnp.square(Yl.astype(jnp.float32)), axis=1)
-    th2 = jnp.float32(theta) ** 2
+    table = space.low_table(jnp.asarray(Y).astype(low))
 
     @jax.jit
-    def packed_mask(xb, Yl, yn):
-        xl = xb.astype(low)
-        xn = jnp.sum(jnp.square(xl.astype(jnp.float32)), axis=1)
-        dot = jnp.matmul(xl, Yl.T, preferred_element_type=jnp.float32)
-        return jnp.packbits(xn[:, None] + yn[None, :] - 2.0 * dot < th2,
-                           axis=1)
+    def packed_mask(xb, table):
+        return jnp.packbits(space.low_within(xb.astype(low), table, theta),
+                            axis=1)
 
     out = []
     n = Y.shape[0]
@@ -39,7 +36,7 @@ def control_pairs(X: np.ndarray, Y: np.ndarray, theta: float, *,
         xb = np.zeros((block, X.shape[1]), np.float32)
         rows = X[q0:q0 + block]
         xb[:len(rows)] = rows
-        bits = np.unpackbits(np.asarray(packed_mask(xb, Yl, yn)),
+        bits = np.unpackbits(np.asarray(packed_mask(xb, table)),
                              axis=1)[:, :n]
         qi, yi = np.nonzero(bits[:len(rows)])
         out.append(np.stack([qi + q0, yi], axis=1))

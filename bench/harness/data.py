@@ -1,10 +1,22 @@
 """Seeded vector data for the benchmark's deployments.
 
-Copied from ``src/repro/data/vectors.py`` at commit cb1b0a7 (the
-``manifold`` and ``weak`` regimes of ``make_dataset`` and
-``thresholds``), so that a change to the program's generator cannot move
-the yardstick. Two departures from the original, both for run-to-run
-steadiness:
+A configuration's ``regime`` key names a file ``bench/regimes/<name>.py``
+that defines ``draw(cfg, n_data, n_query) -> (Y, X)``: ``n_data`` float32
+table rows and ``n_query`` float32 queries, drawn from the configuration's
+fixed ``shape_seed`` and nothing else. A regime may draw the queries from
+another generator than the rows (an out-of-distribution deployment). Its
+``metric`` key names the space (``harness/reference.py``) whose distance
+calibrates θ here.
+
+The benchmark's parts are files under ``bench/``: ``configs/``,
+``traffic/``, ``metrics/``, ``regimes/``, ``spaces/`` and ``drivers/``;
+``harness/registry.py`` says what each file defines.
+
+``ManifoldSampler`` and the threshold rule are copied from
+``src/repro/data/vectors.py`` at commit cb1b0a7 (the ``manifold`` and
+``weak`` regimes of ``make_dataset`` and ``thresholds``), so that a change
+to the program's generator cannot move the yardstick. Two departures from
+the original, both for run-to-run steadiness:
 
   * the generator network, the rows and the queries are drawn from the
     configuration's fixed ``shape_seed``; the run's ``--seed`` only
@@ -45,61 +57,40 @@ class ManifoldSampler:
         return np.ascontiguousarray(out, np.float32)
 
 
-# regime → (latent width rule, data noise, query noise), as make_dataset
-_REGIMES = {
-    "manifold": (lambda latent: latent, 0.0, 0.0),
-    "weak": (lambda latent: max(latent * 2, 12), 0.05, 0.08),
-}
-
-
-def draw(cfg: dict, n_data: int, n_query: int
-         ) -> tuple[np.ndarray, np.ndarray]:
-    """``n_data`` table rows, then ``n_query`` in-distribution queries, of
-    the configuration's distribution, from its ``shape_seed``."""
-    regime = cfg["regime"]
-    if regime not in _REGIMES:
-        raise ValueError(f"unknown regime {regime!r}")
-    latent_rule, y_noise, x_noise = _REGIMES[regime]
-    sampler = ManifoldSampler(np.random.default_rng(cfg["shape_seed"]),
-                              cfg["dim"], latent_rule(cfg["latent"]))
-    rng = np.random.default_rng([cfg["shape_seed"], 1])
-    Y = sampler(rng, n_data, y_noise)
-    return Y, sampler(rng, n_query, x_noise)
-
-
 class Deployment:
     """The table ``Y`` and the query set ``X`` of one configuration: its
-    own vectors, the table's rows in the order the run's seed gives."""
+    regime's vectors, the table's rows in the order the run's seed
+    gives."""
 
-    def __init__(self, cfg: dict, seed: int):
-        Y, self.X = draw(cfg, cfg["n_data"], cfg["n_query"])
+    def __init__(self, cfg: dict, seed: int, regime):
+        Y, self.X = regime.draw(cfg, cfg["n_data"], cfg["n_query"])
         self.Y = Y[np.random.default_rng(seed).permutation(len(Y))]
 
 
-def thresholds(X: np.ndarray, Y: np.ndarray, n: int = 7, *,
+def thresholds(X: np.ndarray, Y: np.ndarray, space, n: int = 7, *,
                lo_q: float = 1e-4, hi_q: float = 5e-2,
                sample: int = 200_000, seed: int = 0,
                block: int = 1 << 16) -> np.ndarray:
-    """n evenly spaced L2 thresholds spanning sparse→dense joins (the
-    paper's Table 2), from the empirical query-to-data distance
-    distribution (``data.vectors.thresholds``, in-distribution quantiles).
-    """
+    """n evenly spaced thresholds spanning sparse→dense joins (the
+    paper's Table 2), from the empirical query-to-data distribution of
+    the space's distance (``data.vectors.thresholds``, in-distribution
+    quantiles)."""
     rng = np.random.default_rng(seed)
     qi = rng.integers(0, X.shape[0], sample)
     yi = rng.integers(0, Y.shape[0], sample)
     d = np.concatenate([
-        np.linalg.norm(X[qi[i:i + block]] - Y[yi[i:i + block]], axis=1)
+        space.distance(X[qi[i:i + block]], Y[yi[i:i + block]])
         for i in range(0, sample, block)])
     return np.linspace(np.quantile(d, lo_q), np.quantile(d, hi_q),
                        n).astype(np.float64)
 
 
-def calibrate_theta(cfg: dict, *, n_query: int = 20_000,
+def calibrate_theta(cfg: dict, regime, space, *, n_query: int = 20_000,
                     n_data: int = 200_000, sample: int = 2_000_000) -> float:
     """θ₁ of the deployment's distribution: the first of the seven
     Table-2 thresholds over a calibration draw from ``shape_seed``, with
     ten times the original's pair sample so that the quantile is steady.
     The configuration file records the value; ``tools/calibrate.py``
     recomputes it."""
-    Y, X = draw(cfg, n_data, n_query)
-    return float(thresholds(X, Y, sample=sample)[0])
+    Y, X = regime.draw(cfg, n_data, n_query)
+    return float(thresholds(X, Y, space, sample=sample)[0])

@@ -11,7 +11,7 @@ class RunRecord:
     cell: str
     cfg: dict
     traffic: dict
-    driver: object               # harness.drivers.OneShot after its window
+    driver: object               # the cell's Driver after its window
     setup_s: float
     memory_peak_bytes: int
     tally: object                # harness.reference.Tally

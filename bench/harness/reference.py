@@ -1,12 +1,27 @@
 """The plain reference: exact float64 threshold join on the host.
 
-``reference`` and ``beyond_theta`` are copied from ``chip_smoke.py`` at
-commit cb1b0a7; they share no code with the join. ``GUARD`` is the
-relative width of the rounding band at θ: a pair whose float64 squared
-distance lies within ``GUARD·(|x|² + |y|²)`` of θ² is a tie that any
-float32 evaluation may round either way (eight float32 ulps of the
-matmul form's norms, as ``quant.cascade.MATMUL_GUARD`` states it in the
-program; restated here so the yardstick does not follow the program).
+The distance is the configuration's: its ``metric`` key names a file
+``bench/spaces/<metric>.py`` (``Registry.deployment``) that defines
+
+  ``within(xs, ys, theta)``   the (len(xs), len(ys)) mask of the pairs of
+                              two float64 blocks that join;
+  ``beyond(xs, ys, theta)``   for matched float64 rows, the pairs that lie
+                              beyond θ past the rounding band, the width of
+                              which (``band``) any float32 evaluation may
+                              round either way;
+  ``distance(xs, ys)``        each matched pair's distance, in the rows'
+                              own precision (θ's calibration,
+                              ``harness/data.py``);
+  ``low_table(Yl)``,          the control's evaluation one precision below
+  ``low_within(xl, table, theta)``  the configuration's
+                              (``harness/control.py``).
+
+The benchmark's parts are files under ``bench/``: ``configs/``,
+``traffic/``, ``metrics/``, ``regimes/``, ``spaces/`` and ``drivers/``;
+``harness/registry.py`` says what each file defines.
+
+A space has no default: a configuration without ``metric``, or one that
+names no file, is an error. Nothing here shares code with the join.
 """
 from __future__ import annotations
 
@@ -14,38 +29,34 @@ import dataclasses
 
 import numpy as np
 
-GUARD = 8 * 1.2e-7
-
 
 @dataclasses.dataclass
 class Reference:
     """Exact pairs of the sample queries. Query ids are positions in the
     sample (0 .. len(sample) - 1)."""
     theta: float
-    truth: set          # (q, y) with float64 distance < θ
+    truth: set          # (q, y) that join in float64
+    space: object       # the spaces/<metric>.py module that judged them
 
 
-def reference(Xs: np.ndarray, Y: np.ndarray, theta: float,
+def reference(Xs: np.ndarray, Y: np.ndarray, theta: float, space,
               block: int = 32768) -> Reference:
-    """Exact join of the sample queries ``Xs`` against all of ``Y``."""
+    """Exact join of the sample queries ``Xs`` against all of ``Y`` in the
+    space's distance."""
     xs = Xs.astype(np.float64)
-    xn = np.sum(xs * xs, axis=1)
-    th2 = theta * theta
     truth = set()
     for y0 in range(0, Y.shape[0], block):
         yb = Y[y0:y0 + block].astype(np.float64)
-        yn = np.sum(yb * yb, axis=1)
-        d2 = xn[:, None] + yn[None, :] - 2.0 * (xs @ yb.T)
-        qi, yi = np.nonzero(d2 < th2)
+        qi, yi = np.nonzero(space.within(xs, yb, theta))
         truth.update(zip(qi.tolist(), (yi + y0).tolist()))
-    return Reference(theta, truth)
+    return Reference(theta, truth, space)
 
 
 def beyond_theta(pairs: np.ndarray, X: np.ndarray, Y: np.ndarray,
-                 theta: float, block: int = 1 << 17) -> int:
-    """Emitted pairs whose float64 distance exceeds θ beyond the band,
-    or whose ids lie outside ``X``/``Y`` (an answer that names no row is
-    as wrong as one beyond θ)."""
+                 theta: float, space, block: int = 1 << 17) -> int:
+    """Emitted pairs that lie beyond θ past the band in float64, or whose
+    ids lie outside ``X``/``Y`` (an answer that names no row is as wrong
+    as one beyond θ)."""
     pairs = np.asarray(pairs, np.int64).reshape(-1, 2)
     ok = ((pairs[:, 0] >= 0) & (pairs[:, 0] < X.shape[0])
           & (pairs[:, 1] >= 0) & (pairs[:, 1] < Y.shape[0]))
@@ -54,9 +65,7 @@ def beyond_theta(pairs: np.ndarray, X: np.ndarray, Y: np.ndarray,
     for p0 in range(0, len(pairs), block):
         q, y = pairs[p0:p0 + block, 0], pairs[p0:p0 + block, 1]
         xq, yy = X[q].astype(np.float64), Y[y].astype(np.float64)
-        d2 = np.sum((xq - yy) ** 2, axis=1)
-        tol = GUARD * (np.sum(xq * xq, axis=1) + np.sum(yy * yy, axis=1))
-        bad += int(np.count_nonzero(d2 >= theta * theta + tol))
+        bad += int(np.count_nonzero(space.beyond(xq, yy, theta)))
     return bad
 
 
@@ -92,7 +101,7 @@ class Tally:
         positions in ``ref``."""
         pairs = np.asarray(pairs, np.int64).reshape(-1, 2)
         self.answers += 1
-        self.beyond += beyond_theta(pairs, X, Y, ref.theta)
+        self.beyond += beyond_theta(pairs, X, Y, ref.theta, ref.space)
         self.duplicates += duplicates(pairs)
         if not qmap:
             return

@@ -4,16 +4,19 @@
     python3 bench/run.py --workload sift1m.join --seed 7 --seconds 10 --trace 0
 
 The cell (``BENCHMARK.json``'s ``workloads``) names a configuration
-(``bench/configs/<name>.json``) and a traffic mix
-(``bench/traffic/<name>.json``); the metrics are read by
-``bench/metrics/<name>.py``. A run: checks that JAX sees a TPU with as
-many chips as the cell asks for (no fallback); sets up data, index and a
-warm-up of the window's shapes (``setup_s``); measures ``--seconds`` of
-traffic with no compile inside the window; reads the device's peak
-memory; frees the program's state; and judges every answer of the window
-against the plain float64 reference. With ``--trace 1`` the window is a
-shorter one under the profiler, and the per-layer metrics are printed
-instead of the end-to-end ones.
+(``bench/configs/<name>.json``, whose ``regime`` and ``metric`` name
+``bench/regimes/<name>.py`` and ``bench/spaces/<name>.py``) and a traffic
+mix (``bench/traffic/<name>.json``, whose ``driver`` names
+``bench/drivers/<name>.py``); the metrics are read by
+``bench/metrics/<name>.py`` (``harness/registry.py``). A run: checks
+that JAX sees a TPU with as many chips as the cell asks for (no
+fallback); sets up data, index and a warm-up of the window's shapes
+(``setup_s``); measures ``--seconds`` of traffic with no compile inside
+the window; reads the device's peak memory; frees the program's state;
+and judges every answer of the window against the plain float64
+reference. With ``--trace 1`` the window is a shorter one under the
+profiler, and the per-layer metrics are printed instead of the
+end-to-end ones.
 
 The last line of stdout is one JSON object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, with ``--trace 1`` ``breakdown``,
@@ -89,26 +92,24 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              ) -> dict:
     """One run of one cell; returns the result line's object."""
     from harness.compiles import CompileCounter
-    from harness.drivers import DRIVERS
     from harness.record import RunRecord
     from harness.registry import Registry
 
     t_start = T_START if t_start is None else t_start
     reg = Registry(root)
-    wl = reg.workload(workload)
-    cfg = dict(reg.config(wl["config"]), name=wl["config"])
-    traffic = reg.traffic(wl["traffic"])
+    cell = reg.cell(workload)
 
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
-    dev = device_info(int(wl["chips"]), require_tpu)
+    dev = device_info(cell.chips, require_tpu)
     if require_tpu:
         reg.peaks(dev["kind"])       # an unknown device is an error
     counter = CompileCounter()
 
     import jax
-    window_s = float(traffic["trace_seconds"]) if trace else float(seconds)
-    drv = DRIVERS[traffic["driver"]](cfg, traffic, seed, window_s)
+    window_s = (float(cell.traffic["trace_seconds"]) if trace
+                else float(seconds))
+    drv = cell.driver(cell, seed, window_s)
     drv.setup()
     setup_s = time.perf_counter() - t_start
 
@@ -145,8 +146,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         if tdir:
             shutil.rmtree(tdir, ignore_errors=True)
 
-    rec = RunRecord(workload, cfg, traffic, drv, setup_s, mem, tally,
-                    records, reduction)
+    rec = RunRecord(workload, cell.cfg, cell.traffic, drv, setup_s, mem,
+                    tally, records, reduction)
     entries = reg.per_layer(workload) if trace else reg.end_to_end(workload)
     metrics = {}
     for m in entries:
@@ -157,7 +158,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     # a join call that fails ends the run, so every attempted query that
     # reaches the result line was answered
     attempted = sum(c.n_queries for c in drv.calls)
-    cks = checks(tally, cfg)
+    cks = checks(tally, cell.cfg)
     out = {"correct": all(passed(c) for c in cks.values()),
            "attempted": attempted, "failed": 0, "metrics": metrics,
            "device": {"platform": dev["platform"], "kind": dev["kind"],

@@ -20,18 +20,17 @@ sys.path.insert(0, str(BENCH))
 
 from run import checks, passed  # noqa: E402
 from harness.control import control_pairs  # noqa: E402
-from harness.drivers import DRIVERS, Call  # noqa: E402
+from harness.driver import Call  # noqa: E402
 from harness.registry import Registry  # noqa: E402
 
 
 def control_run(reg: Registry, workload: str, seed: int) -> dict:
-    wl = reg.workload(workload)
-    cfg = dict(reg.config(wl["config"]), name=wl["config"])
-    traffic = reg.traffic(wl["traffic"])
-    drv = DRIVERS[traffic["driver"]](cfg, traffic, seed, 0.0)
+    cell = reg.cell(workload)
+    cfg = cell.cfg
+    drv = cell.driver(cell, seed, 0.0)
     drv.make_data()
     t0 = time.perf_counter()
-    pairs = control_pairs(drv.X, drv.dep.Y, drv.theta,
+    pairs = control_pairs(drv.X, drv.dep.Y, drv.theta, cell.space,
                           precision=cfg.get("precision", "float32"))
     drv.calls = [Call(0.0, 1.0, len(drv.X), None, pairs)]
     t_ctl = time.perf_counter() - t0

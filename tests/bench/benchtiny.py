@@ -1,6 +1,7 @@
 """Helpers for the benchmark's own tests: a copy of the benchmark whose
-configurations are cut to a size the CPU runs in seconds. The harness's
-code, metric readers and traffic mixes are the real ones."""
+configurations are cut to a size the CPU runs in seconds. Every other part
+(traffic mixes, metric readers, regimes, spaces, drivers, and any part
+directory added later) is the real one."""
 import json
 import shutil
 import sys
@@ -19,11 +20,10 @@ TINY_TRAFFIC = {"check_queries": 64, "trace_seconds": 0.5}
 
 def make_tiny(dst: Path) -> Path:
     """A benchmark root at ``dst`` with the real parts and tiny configs."""
-    (dst / "bench").mkdir(parents=True)
+    dst.mkdir(parents=True, exist_ok=True)
     shutil.copy(ROOT / "BENCHMARK.json", dst / "BENCHMARK.json")
-    for part in ("metrics", "traffic"):
-        shutil.copytree(BENCH / part, dst / "bench" / part)
-    shutil.copy(BENCH / "peaks.json", dst / "bench" / "peaks.json")
+    shutil.copytree(BENCH, dst / "bench", ignore=shutil.ignore_patterns(
+        "__pycache__", "configs"))
     (dst / "bench" / "configs").mkdir()
     for name, over in TINY.items():
         cfg = json.loads((BENCH / "configs" / f"{name}.json").read_text())

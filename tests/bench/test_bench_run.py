@@ -33,20 +33,30 @@ def test_tiny_run_is_correct_with_contract_keys(tiny_root, cell):
     assert out["attempted"] > 0 and out["failed"] == 0
     assert set(out["device"]) == {"platform", "kind", "count",
                                   "memory_peak_bytes"}
-    assert {"setup_s", "recall", "join_qps"} <= set(out["metrics"])
+    from harness.registry import Registry
+    # every end-to-end metric of the cell reads something, but the CPU
+    # reports no peak memory
+    assert set(out["metrics"]) == {
+        m["name"] for m in Registry(tiny_root).end_to_end(cell)} - {
+        "hbm_peak_mb"}
+    assert {"setup_s", "recall"} <= set(out["metrics"])
     for m in out["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
     assert out["checks"]["beyond_theta"]["value"] == 0
 
 
-def test_same_seed_same_inputs(tiny_root):
-    from harness.drivers import DRIVERS
+def oneshot(root):
+    """The one-shot driver class that runs of cells under ``root`` use."""
     from harness.registry import Registry
-    reg = Registry(tiny_root)
-    cfg = dict(reg.config("sift1m"), name="sift1m")
+    return Registry(root).driver("oneshot").OneShot
+
+
+def test_same_seed_same_inputs(tiny_root):
+    from harness.registry import Registry
+    cell = Registry(tiny_root).cell("sift1m.join")
 
     def inputs(seed):
-        d = DRIVERS["oneshot"](cfg, reg.traffic("join"), seed, 1.0)
+        d = cell.driver(cell, seed, 1.0)
         d.make_data()
         return d.dep.Y, d.X
 
@@ -61,7 +71,7 @@ def test_same_seed_same_inputs(tiny_root):
 def test_each_call_of_the_window_is_judged(tiny_root, monkeypatch):
     """Every call of the window counts in the tally, identical answers
     included, and one changed answer among them is caught."""
-    from harness.drivers import OneShot
+    OneShot = oneshot(tiny_root)
     join_window = OneShot.window
 
     def window(self):
@@ -81,7 +91,7 @@ def test_each_call_of_the_window_is_judged(tiny_root, monkeypatch):
 def test_compile_in_window_ends_the_run(tiny_root, monkeypatch):
     import jax
     import jax.numpy as jnp
-    from harness.drivers import OneShot
+    OneShot = oneshot(tiny_root)
     join_window = OneShot.window
 
     def window(self):

@@ -8,7 +8,7 @@ from types import SimpleNamespace
 import pytest
 
 from benchtiny import ROOT
-from harness.drivers import Call, OneShot
+from harness.driver import Call
 from harness.record import RunRecord
 from harness.registry import Registry
 from harness.trace import Event, Records
@@ -79,6 +79,7 @@ def test_reader_reads_nothing_from_a_program_without_it(name):
 
 def test_tiny_traced_run_reports_the_three_metrics(tiny_root, monkeypatch):
     seen = []
+    OneShot = Registry(tiny_root).driver("oneshot").OneShot
     release = OneShot.release
 
     def keep_calls(self):
