@@ -54,6 +54,11 @@ class EngineSpec:
     cascade (``graph.build_index(quant=...)``): the kNN sweep and RNG
     prune run on certified bounds, f32 only for the ambiguous band —
     neighbor lists are identical to the f32 build.
+
+    ``metric`` (``core.types.METRICS``) belongs to the table and its
+    index, as a vector database collection's does: every join the engine
+    serves is judged in it. ``"ip"`` runs with ``quant`` and
+    ``quant_build`` ``"off"`` on one device; other combinations raise.
     """
     k: int = 48                    # kNN candidates per node at build time
     degree: int = 32               # index max out-degree R
@@ -63,6 +68,7 @@ class EngineSpec:
     max_cached_indexes: int = 4    # per-X artifact LRU capacity
     quant: str = "off"             # storage mode (off | sq8 | sketch8)
     quant_build: str = "off"       # cascade-driven index builds
+    metric: str = "l2"             # the table's space (l2 | ip)
 
     def build_kw(self) -> dict:
         kw = dict(k=self.k, degree=self.degree, style=self.style)
@@ -92,6 +98,9 @@ ENGINE_PRESETS = {
     "serving_sketch8": EngineSpec(n_shards=0, carry_window=16_384,
                                   max_cached_indexes=8, quant="sketch8",
                                   quant_build="sq8"),
+    # inner-product table (cross-modal embeddings, MIPS-style joins):
+    # the default build and one device, quant off
+    "ip": EngineSpec(metric="ip"),
 }
 
 
@@ -112,7 +121,8 @@ def make_engine(Y, spec: str | EngineSpec = "default", *,
                                       quant=spec.quant)
     return JoinEngine(Y, build_kw=spec.build_kw(), default=default,
                       n_shards=n_shards, carry_window=spec.carry_window,
-                      max_cached_indexes=spec.max_cached_indexes)
+                      max_cached_indexes=spec.max_cached_indexes,
+                      metric=spec.metric)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -879,6 +879,8 @@ def distributed_mi_join(X, smi: ShardedMergedIndex, mesh: Mesh | None = None,
                 "n_slots": np.asarray(n_slots).reshape(-1),
                 "n_lane_iters": n_lane_iters[:, lane_valid].sum(axis=1),
             }
+            if hybrid:
+                per["n_hybrid_lane_iters"] = per["n_lane_iters"]
             for s, st in enumerate(shard_stats):
                 for k, v in per.items():
                     setattr(st, k, getattr(st, k) + int(v[s]))

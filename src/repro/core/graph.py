@@ -19,6 +19,23 @@ connectivity repair, which is offline and O(repairs)):
 The merged index G_{X∪Y} (paper §4.4) is the same construction over
 concat([Y, X]) with ``n_data = |Y|``.
 
+**Metric** (``build_index(..., metric=...)``, ``core.types.METRICS``):
+the kNN sweep, the prune rule and the navigating node run on the
+metric's dissimilarity — squared L2, or −⟨x, y⟩ under ``ip`` (hnswlib's
+``ip`` space likewise prunes on 1 − ⟨x, y⟩). The connectivity repair and
+the OOD side table stay L2 under either metric (``core/ood.py``): under
+``ip`` the reachable node of largest ⟨x, y⟩ is one of a few large-norm
+hubs for nearly every unreachable node, so a repair on −⟨x, y⟩ piles every
+spoke onto the same full rows, each evicting the last, and never
+converges; the nearest reachable node in space spreads them. Three more
+steps are the ``ip`` build's own. The repair never evicts an edge
+(``_repair_connectivity``). Each row's free slots end up holding the
+candidates its prune dropped (``_fill_pruned``): the prune keeps a few
+edges to hubs, and in-range nodes near θ are otherwise reached by almost
+no edge. The build runs in a canonical row order (``build_index``), so
+the index does not depend on the order of the rows. Cascade-driven builds
+certify L2 bounds only, so they take ``l2``.
+
 **Cascade-driven builds** (``build_index(..., quant="sq8")``): steps 1
 and 2 are the dominant offline f32 traffic (every construction distance
 streams d×4 bytes), and both are *selection* problems — top-k for the
@@ -42,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.types import NO_NODE, GraphIndex
+from repro.core.types import NO_NODE, GraphIndex, check_metric
 from repro.kernels import ops
 
 Array = jax.Array
@@ -86,9 +103,11 @@ class BuildStats:
 # 1. exact kNN graph (blocked)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("k", "dblock", "impl"))
+@functools.partial(jax.jit, static_argnames=("k", "dblock", "impl",
+                                             "metric"))
 def _knn_block(qvecs: Array, vecs: Array, qoff: Array, *, k: int,
-               dblock: int, impl: str | None) -> tuple[Array, Array]:
+               dblock: int, impl: str | None, metric: str = "l2"
+               ) -> tuple[Array, Array]:
     """kNN of a query block against all vecs (excluding self), via scan."""
     n = vecs.shape[0]
     nblocks = -(-n // dblock)
@@ -99,7 +118,8 @@ def _knn_block(qvecs: Array, vecs: Array, qoff: Array, *, k: int,
     def body(carry, j):
         bd, bi = carry
         yblk = jax.lax.dynamic_slice_in_dim(vpad, j * dblock, dblock)
-        d = ops.pairwise_sq_dists(qvecs, yblk, impl=impl)      # (bq, dblock)
+        d = ops.pairwise_dists(qvecs, yblk, metric=metric,
+                               impl=impl)                      # (bq, dblock)
         ids = j * dblock + jnp.arange(dblock, dtype=jnp.int32)[None, :]
         ids = jnp.broadcast_to(ids, d.shape)
         valid = ids < n
@@ -118,9 +138,10 @@ def _knn_block(qvecs: Array, vecs: Array, qoff: Array, *, k: int,
 
 def exact_knn(vecs: Array, k: int, *, qblock: int = 512, dblock: int = 8192,
               impl: str | None = None, cascade=None,
-              stats: BuildStats | None = None
+              stats: BuildStats | None = None, metric: str = "l2"
               ) -> tuple[np.ndarray, np.ndarray]:
-    """Exact kNN graph: returns (dists (N,k) f32, ids (N,k) i32), ascending.
+    """Exact kNN graph: returns (dists (N,k) f32, ids (N,k) i32), ascending
+    in the metric's dissimilarity.
 
     With a ``cascade`` (whose confirming tier must provide upper bounds,
     i.e. carry an int8 tier) the sweep runs filter-then-rerank: certified
@@ -130,6 +151,10 @@ def exact_knn(vecs: Array, k: int, *, qblock: int = 512, dblock: int = 8192,
     n = vecs.shape[0]
     confirm = cascade.tier("int8") if cascade is not None else None
     if confirm is not None:
+        if metric != "l2":
+            raise ValueError(f"a cascade-driven build certifies L2 "
+                             f"bounds; metric {metric!r} builds with quant "
+                             f"'off'")
         return _cascade_knn(vecs, confirm, k, qblock=qblock, dblock=dblock,
                             impl=impl, stats=stats)
     out_d = np.empty((n, k), np.float32)
@@ -138,7 +163,7 @@ def exact_knn(vecs: Array, k: int, *, qblock: int = 512, dblock: int = 8192,
         q1 = min(q0 + qblock, n)
         qv = vecs[q0:q1]
         bd, bi = _knn_block(qv, vecs, jnp.int32(q0), k=k, dblock=dblock,
-                            impl=impl)
+                            impl=impl, metric=metric)
         out_d[q0:q1] = np.asarray(bd)
         out_i[q0:q1] = np.asarray(bi)
     return out_d, out_i
@@ -288,9 +313,14 @@ def _prune_from_lt(lt: Array, valid: Array, cand_ids: Array, R: int
     return out[:, :R]
 
 
-def _pair_sq_dists(cvecs: Array) -> Array:
+def _pair_sq_dists(cvecs: Array, metric: str = "l2") -> Array:
     """(b, k, d) gathered candidate rows → (b, k, k) matmul-form pairwise
-    squared distances (the prune rule's comparison values)."""
+    squared distances (the prune rule's comparison values); under ``ip``,
+    −⟨w, v⟩."""
+    if metric == "ip":
+        return -jnp.einsum("bkd,bjd->bkj", cvecs.astype(jnp.float32),
+                           cvecs.astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST)
     cn = jnp.sum(cvecs.astype(jnp.float32) ** 2, axis=-1)    # (b, k)
     cc = jnp.einsum("bkd,bjd->bkj", cvecs.astype(jnp.float32),
                     cvecs.astype(jnp.float32),
@@ -298,19 +328,20 @@ def _pair_sq_dists(cvecs: Array) -> Array:
     return jnp.maximum(cn[:, :, None] + cn[:, None, :] - 2.0 * cc, 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("R",))
-def _rng_prune_block(vecs: Array, cand_ids: Array, cand_d: Array, *, R: int
-                     ) -> Array:
+@functools.partial(jax.jit, static_argnames=("R", "metric"))
+def _rng_prune_block(vecs: Array, cand_ids: Array, cand_d: Array, *, R: int,
+                     metric: str = "l2") -> Array:
     """Prune candidate lists (ascending by distance) to RNG edges, max R.
 
     Args:
       vecs: (N, d) all vectors.
       cand_ids: (b, k) candidate ids per node (NO_NODE padded, ascending d).
-      cand_d: (b, k) squared distances node→candidate.
+      cand_d: (b, k) dissimilarities node→candidate (squared L2, or
+        −⟨u, v⟩ under ``ip``).
     Returns:
       (b, R) pruned neighbor ids (NO_NODE padded, ascending by distance).
     """
-    pair = _pair_sq_dists(vecs[jnp.clip(cand_ids, 0)])
+    pair = _pair_sq_dists(vecs[jnp.clip(cand_ids, 0)], metric)
     valid = cand_ids != NO_NODE
     return _prune_from_lt(pair < cand_d[:, None, :], valid, cand_ids, R)
 
@@ -371,12 +402,13 @@ def _rng_prune_block_cascade(vecs: Array, q: Array, norms: Array,
 # 3.+4. medoid & connectivity repair
 # ---------------------------------------------------------------------------
 
-def _medoid(vecs: Array, sample: int = 4096, seed: int = 0) -> int:
+def _medoid(vecs: Array, sample: int = 4096, seed: int = 0,
+            metric: str = "l2") -> int:
     n = vecs.shape[0]
     rng = np.random.default_rng(seed)
     idx = rng.choice(n, size=min(sample, n), replace=False)
     sub = vecs[jnp.asarray(idx)]
-    d = ops.pairwise_sq_dists(sub, sub)
+    d = ops.pairwise_dists(sub, sub, metric=metric)
     return int(idx[int(np.argmin(np.asarray(jnp.sum(d, axis=1))))])
 
 
@@ -435,9 +467,38 @@ def _add_reverse_edges(nbrs: np.ndarray) -> np.ndarray:
     return nbrs
 
 
+def _fill_pruned(nbrs: np.ndarray, cand_i: np.ndarray,
+                 block: int = 16384) -> np.ndarray:
+    """Fill each row's free slots with the candidates its prune dropped,
+    nearest first (hnswlib's ``keepPrunedConnections``). Adding edges only
+    adds paths: what the repair reached stays reachable."""
+    n, R = nbrs.shape
+    for b0 in range(0, n, block):
+        nb, ci = nbrs[b0:b0 + block], cand_i[b0:b0 + block]
+        new = (ci >= 0) & ~np.any(ci[:, :, None] == nb[:, None, :], axis=2)
+        free = nb == NO_NODE
+        # the j-th new candidate of a row goes to its j-th free slot
+        rank_new = np.cumsum(new, axis=1) - 1
+        n_take = np.minimum(new.sum(1), free.sum(1))
+        take = new & (rank_new < n_take[:, None])
+        slot_of = np.argsort(~free, axis=1, kind="stable")  # free slots first
+        rows, cols = np.nonzero(take)
+        nb[rows, slot_of[rows, rank_new[rows, cols]]] = ci[rows, cols]
+    return nbrs
+
+
 def _repair_connectivity(vecs_np: np.ndarray, nbrs: np.ndarray, start: int,
-                         impl: str | None) -> np.ndarray:
-    """Attach unreachable nodes to their nearest reachable node (NSG §tree)."""
+                         impl: str | None, *, spare_full: bool = False
+                         ) -> np.ndarray:
+    """Attach unreachable nodes to their nearest reachable node in L2
+    (NSG §tree), under either metric (module header).
+
+    A node whose host row is full evicts the host's last edge. With
+    ``spare_full`` (the ``ip`` build) only rows with a free slot host a
+    node and no edge is evicted, so reachability only grows: the ``ip``
+    prune leaves hubs of full rows that most unreachable nodes lie
+    nearest to, and evicting there cuts off what the last round attached.
+    """
     n, R = nbrs.shape
     for _ in range(64):  # bounded repair rounds
         seen = _reachable(nbrs, start)
@@ -445,21 +506,26 @@ def _repair_connectivity(vecs_np: np.ndarray, nbrs: np.ndarray, start: int,
         if missing.size == 0:
             break
         reach_ids = np.flatnonzero(seen)
+        if spare_full:
+            reach_ids = reach_ids[np.any(nbrs[reach_ids] == NO_NODE, axis=1)]
+            if reach_ids.size == 0:
+                break
         # nearest reachable node for each missing node (exact, blocked
-        # over the missing side so the distance tile stays ~1 GB)
+        # over the missing side so the distance tile stays ~1 GB; the
+        # argmin runs on the device, so only ids come back)
         rv = jnp.asarray(vecs_np[reach_ids])
         step = max(1, _TILE_ELEMS // reach_ids.size)
         host = np.concatenate([
-            reach_ids[np.argmin(np.asarray(ops.pairwise_sq_dists(
+            reach_ids[np.asarray(jnp.argmin(ops.pairwise_sq_dists(
                 jnp.asarray(vecs_np[missing[m0:m0 + step]]), rv,
-                impl=impl)), axis=1)]
+                impl=impl), axis=1))]
             for m0 in range(0, missing.size, step)])
         for m, h in zip(missing, host):
             row = nbrs[h]
             free = np.flatnonzero(row == NO_NODE)
             if free.size:
                 nbrs[h, free[0]] = m
-            else:
+            elif not spare_full:
                 nbrs[h, R - 1] = m  # evict farthest edge (last slot)
     return nbrs
 
@@ -482,7 +548,8 @@ def build_index(vecs, *, k: int = 48, degree: int = 32, n_data: int | None = Non
                 prune_block: int = 1024, seed: int = 0,
                 impl: str | None = None, style: str = "nsg",
                 quant: str | None = None,
-                build_stats: BuildStats | None = None) -> GraphIndex:
+                build_stats: BuildStats | None = None,
+                metric: str = "l2") -> GraphIndex:
     """Build a graph index over ``vecs``.
 
     Args:
@@ -503,7 +570,39 @@ def build_index(vecs, *, k: int = 48, degree: int = 32, n_data: int | None = Non
         RNG prune through certified bounds (identical edges, f32 traffic
         cut to the ambiguous band; see the module header). ``build_stats``
         collects the traffic accounting.
+      metric: the space of the index (``core.types.METRICS``); see the
+        module header.
     """
+    check_metric(metric)
+    kw = dict(k=k, degree=degree, prune_block=prune_block, seed=seed,
+              impl=impl, style=style, quant=quant, build_stats=build_stats,
+              metric=metric)
+    if metric != "ip":
+        return _build_index(vecs, n_data=n_data, **kw)
+    # Under ip, which edges survive at the full rows of the hubs (reverse
+    # edges, repair spokes) depends on node ids. The build runs in a
+    # canonical order of the rows, data and queries each sorted by their
+    # values, so the index depends on the vectors and not on their order.
+    v = np.asarray(vecs)
+    nd = v.shape[0] if n_data is None else int(n_data)
+    perm = np.concatenate([
+        b0 + np.lexsort(v[b0:b1, :2].T[::-1])
+        for b0, b1 in ((0, nd), (nd, v.shape[0])) if b1 > b0])
+    inv = np.argsort(perm)
+    g = _build_index(jnp.asarray(v[perm]), n_data=n_data, **kw)
+    nb = np.asarray(g.nbrs)[inv]
+    nb = np.where(nb >= 0, perm[np.clip(nb, 0, None)], NO_NODE)
+    return dataclasses.replace(
+        g, vecs=jnp.asarray(vecs), nbrs=jnp.asarray(nb, jnp.int32),
+        start=jnp.int32(perm[int(g.start)]),
+        mean_nbr_dist=g.mean_nbr_dist[jnp.asarray(inv)])
+
+
+def _build_index(vecs, *, k: int, degree: int, n_data: int | None,
+                 prune_block: int, seed: int, impl: str | None, style: str,
+                 quant, build_stats: BuildStats | None,
+                 metric: str) -> GraphIndex:
+    """``build_index`` in the rows' own order."""
     vecs = jnp.asarray(vecs)
     n = vecs.shape[0]
     d = int(vecs.shape[1])
@@ -521,7 +620,7 @@ def build_index(vecs, *, k: int = 48, degree: int = 32, n_data: int | None = Non
         else:
             cascade = quant
     cand_d, cand_i = exact_knn(vecs, k, impl=impl, cascade=cascade,
-                               stats=build_stats)
+                               stats=build_stats, metric=metric)
     nbrs = np.empty((n, degree), np.int32)
     cand_d_j = jnp.asarray(cand_d)
     cand_i_j = jnp.asarray(cand_i)
@@ -555,12 +654,16 @@ def build_index(vecs, *, k: int = 48, degree: int = 32, n_data: int | None = Non
         for b0 in range(0, n, prune_block):
             b1 = min(b0 + prune_block, n)
             nbrs[b0:b1] = np.asarray(_rng_prune_block(
-                vecs, cand_i_j[b0:b1], cand_d_j[b0:b1], R=degree))
-    start = _medoid(vecs, seed=seed)
+                vecs, cand_i_j[b0:b1], cand_d_j[b0:b1], R=degree,
+                metric=metric))
+    start = _medoid(vecs, seed=seed, metric=metric)
     vecs_np = np.asarray(vecs)
     nbrs = _add_reverse_edges(nbrs)
-    nbrs = _repair_connectivity(vecs_np, nbrs, start, impl)
+    nbrs = _repair_connectivity(vecs_np, nbrs, start, impl,
+                                spare_full=metric == "ip")
     nbrs = _add_reverse_edges(nbrs)  # make repair spokes two-way as well
+    if metric == "ip":
+        nbrs = _fill_pruned(nbrs, cand_i)
     # OOD side table (paper §4.5): mean L2 (not squared) neighbor distance,
     # in row blocks — the gathered (rows, R, d) neighbor tensor of a whole
     # million-row table would not fit in device memory (the rowwise
@@ -573,7 +676,8 @@ def build_index(vecs, *, k: int = 48, degree: int = 32, n_data: int | None = Non
         for b0 in range(0, n, rows)])
     return GraphIndex(vecs=vecs, nbrs=nbrs_j, start=jnp.int32(start),
                       mean_nbr_dist=mnd,
-                      n_data=int(n if n_data is None else n_data))
+                      n_data=int(n if n_data is None else n_data),
+                      metric=metric)
 
 
 def build_merged_index(Y, X, **kw) -> GraphIndex:

@@ -14,19 +14,24 @@ layer (index caching, streaming batches, sharded execution) is
 compatibility wrapper: it spins up a transient engine per call, so the
 old build-per-invocation semantics are preserved exactly.
 
+Every entry point takes the engine's metric (``core.types.METRICS``): θ
+is a threshold on the space's dissimilarity, ‖x − y‖ or −⟨x, y⟩.
+
 The NLJ has exactly one entry point, ``cascade_join_pairs``, driven by a
 ``repro.quant.FilterCascade``: with no cascade it is the exact
 nested-loop ground truth; with tiers it filters every pair through the
 certified-bounds chain and re-ranks only the ambiguous band in f32, so
 the emitted set equals the exact one at every tier configuration.
-``exact_join_pairs`` survives as the no-cascade alias.
+``exact_join_pairs`` survives as the no-cascade alias. ``exact_ip_pairs``
+and ``theta_band`` are the plain float64 references the tests hold every
+path to.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.types import GraphIndex, JoinConfig, JoinResult
+from repro.core.types import GraphIndex, JoinConfig, JoinResult, theta_key
 from repro.kernels import ops
 
 
@@ -36,8 +41,8 @@ from repro.kernels import ops
 
 def cascade_join_pairs(X, Y, theta: float, cascade=None, *,
                        block: int = 512, pair_block: int = 1 << 15,
-                       impl: str | None = None, early_exit: bool = True
-                       ) -> tuple[np.ndarray, dict]:
+                       impl: str | None = None, early_exit: bool = True,
+                       metric: str = "l2") -> tuple[np.ndarray, dict]:
     """Exact NLJ through a ``FilterCascade``'s certified-bounds chain.
 
     Tier 0 streams its compressed codes pairwise against the whole of Y
@@ -71,11 +76,18 @@ def cascade_join_pairs(X, Y, theta: float, cascade=None, *,
     survivor counts: ``counts["escalated"]`` has one entry per tier
     beyond the first (pairs that tier had to evaluate) and
     ``counts["n_rerank"]`` the f32 band evaluations.
+
+    Under ``metric="ip"`` only the no-cascade path exists (the tiers'
+    bounds are L2 bounds): the exact pairs are those with −⟨x, y⟩ < θ
+    by ``ops.pairwise_neg_ip``.
     """
     X = jnp.asarray(X, jnp.float32)
     Y = jnp.asarray(Y, jnp.float32)
     tiers = tuple(cascade.tiers) if cascade is not None else ()
-    th2 = np.float32(theta) ** 2
+    if tiers and metric != "l2":
+        raise ValueError(f"the filter cascade certifies L2 bounds; metric "
+                         f"{metric!r} joins with quant 'off'")
+    th2 = np.float32(theta_key(theta, metric))
     counts = {"escalated": [0] * max(len(tiers) - 1, 0), "n_rerank": 0,
               "dims_scanned": 0, "dims_total": 0}
 
@@ -85,7 +97,7 @@ def cascade_join_pairs(X, Y, theta: float, cascade=None, *,
         for q0 in range(0, X.shape[0], block):
             q1 = min(q0 + block, X.shape[0])
             mask = np.asarray(ops.nlj_mask(X[q0:q1], Y, theta=float(theta),
-                                           impl=impl))
+                                           impl=impl, metric=metric))
             qi, yi = np.nonzero(mask)
             out.append(np.stack([qi + q0, yi], axis=1))
         pairs = (np.concatenate(out, axis=0) if out
@@ -170,24 +182,52 @@ def _rerank_pairs(xb, Y, qi, yi, q0: int, th2) -> np.ndarray:
 
 
 def exact_join_pairs(X, Y, theta: float, *, block: int = 1024,
-                     impl: str | None = None) -> np.ndarray:
-    """All (query, data) pairs with L2 distance < theta — the ground truth
-    (the no-cascade configuration of ``cascade_join_pairs``)."""
-    pairs, _ = cascade_join_pairs(X, Y, theta, None, block=block, impl=impl)
+                     impl: str | None = None, metric: str = "l2"
+                     ) -> np.ndarray:
+    """All (query, data) pairs whose dissimilarity is < theta — the ground
+    truth (the no-cascade configuration of ``cascade_join_pairs``)."""
+    pairs, _ = cascade_join_pairs(X, Y, theta, None, block=block, impl=impl,
+                                  metric=metric)
     return pairs
 
 
-def theta_band(X, Y, theta: float) -> set:
-    """Pairs whose float64 squared distance lies within the f32 matmul
-    rounding guard of θ² (host numpy, small inputs only).
+# Relative width of the inner product's rounding band at θ: 8 float32 ulps
+# (2⁻²³ each) of ‖x‖·‖y‖, which bounds Σ|xᵢyᵢ| (Cauchy–Schwarz), the
+# magnitude every rounding of a float32 dot is relative to. The program's
+# dots (the row-wise kernel's blocked product sums, the matmul kernels at
+# ``Precision.HIGHEST``) sum d ≤ 1024 terms in a tree of partial sums, a
+# few roundings deep, well inside it; bfloat16 inputs (2⁻⁸) are not.
+DOT_GUARD = 8 * 2.0 ** -23
+
+
+def exact_ip_pairs(X, Y, theta: float) -> np.ndarray:
+    """The plain inner-product reference: every (query, data) pair with
+    −⟨x, y⟩ < θ in float64 (host numpy; no kernel, cache or batching —
+    small inputs only)."""
+    x = np.asarray(X, np.float64)
+    y = np.asarray(Y, np.float64)
+    qi, yi = np.nonzero(-(x @ y.T) < theta)
+    return np.stack([qi, yi], axis=1).astype(np.int64)
+
+
+def theta_band(X, Y, theta: float, metric: str = "l2") -> set:
+    """Pairs whose float64 dissimilarity lies within its f32 rounding
+    band at θ (host numpy, small inputs only): for ``l2`` the squared
+    distance within the matmul rounding guard of θ²; for ``ip``, −⟨x, y⟩
+    within ``DOT_GUARD``·‖x‖·‖y‖ of θ.
 
     On these pairs two correct f32 evaluations may disagree — the
     matmul form of the exact NLJ against the difference form of the
     re-rank, or two partitionings of one reduction — so tests compare
     pair sets up to this band."""
-    from repro.quant.cascade import MATMUL_GUARD
     x = np.asarray(X, np.float64)
     y = np.asarray(Y, np.float64)
+    if metric == "ip":
+        band = DOT_GUARD * np.outer(np.linalg.norm(x, axis=1),
+                                    np.linalg.norm(y, axis=1))
+        qi, yi = np.nonzero(np.abs(-(x @ y.T) - theta) <= band)
+        return set(zip(qi.tolist(), yi.tolist()))
+    from repro.quant.cascade import MATMUL_GUARD
     d2 = np.sum((x[:, None, :] - y[None, :, :]) ** 2, axis=-1)
     norms = np.sum(x * x, axis=1)[:, None] + np.sum(y * y, axis=1)[None, :]
     qi, yi = np.nonzero(np.abs(d2 - theta * theta) <= MATMUL_GUARD * norms)

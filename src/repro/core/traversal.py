@@ -27,7 +27,9 @@ included), and `n_lane_iters`, the loop iterations in which each lane was
 still active. The loop bodies' phases carry `jax.named_scope`s (`select`,
 `gather`, `distance`, `visited`, `merge`) that name their device ops.
 
-All distances are squared L2 internally; thresholds are squared on entry.
+Distances are the index's metric (``GraphIndex.metric``): squared L2, or
+−⟨x, y⟩ under ``ip``; thresholds enter as ``core.types.theta_key`` (θ² or
+θ), and every test is ``dist < key``.
 """
 from __future__ import annotations
 
@@ -37,7 +39,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.types import NO_NODE, GraphIndex, TraversalConfig
+from repro.core.types import NO_NODE, GraphIndex, TraversalConfig, theta_key
 from repro.kernels import ops
 
 Array = jax.Array
@@ -109,7 +111,7 @@ def cascade_bounds(cascade, qc, cand: Array, valid: Array, esc_th2, *,
 
 def _probe(vecs: Array, x: Array, cand: Array, valid: Array, visited: Array,
            *, n_data: int, traverse_nondata: bool, dist_impl: str | None,
-           cascade=None, qc=None, esc_th2=None
+           cascade=None, qc=None, esc_th2=None, metric: str = "l2"
            ) -> tuple[Array, Array, Array, Array, Array, Array]:
     """Compute distances to candidate ids with dedup + visited masking.
 
@@ -123,6 +125,8 @@ def _probe(vecs: Array, x: Array, cand: Array, valid: Array, visited: Array,
         lower bounds* walked through the tier chain (``cascade_bounds``),
         so downstream `< θ²` tests accept a superset; the wave runner
         re-ranks pooled survivors exactly.
+      metric: the index's metric; the exact path's distances are
+        ``ops.rowwise_dists`` of it.
     Returns:
       (dist (B,K) f32 — +inf at invalid, ub (B,K) certified upper bounds
        (= dist on the exact f32 path), valid (B,K), new_visited,
@@ -163,7 +167,8 @@ def _probe(vecs: Array, x: Array, cand: Array, valid: Array, visited: Array,
         with jax.named_scope("gather"):
             cvec = vecs[cand_c]                             # (B, K, d)
         with jax.named_scope("distance"):
-            dist = ops.rowwise_sq_dists(x, cvec, impl=dist_impl)
+            dist = ops.rowwise_dists(x, cvec, metric=metric,
+                                     impl=dist_impl)
         ub = dist
     dist = jnp.where(valid, dist, _INF)
     ub = jnp.where(valid, ub, _INF)
@@ -180,7 +185,7 @@ def _probe(vecs: Array, x: Array, cand: Array, valid: Array, visited: Array,
 def _expand(index_vecs: Array, index_nbrs: Array, x: Array, sel_ids: Array,
             sel_valid: Array, visited: Array, *, n_data: int,
             traverse_nondata: bool, dist_impl: str | None,
-            cascade=None, qc=None, esc_th2=None):
+            cascade=None, qc=None, esc_th2=None, metric: str = "l2"):
     """Gather neighbor rows of selected nodes and probe them."""
     B, E = sel_ids.shape
     R = index_nbrs.shape[1]
@@ -191,7 +196,7 @@ def _expand(index_vecs: Array, index_nbrs: Array, x: Array, sel_ids: Array,
     dist, ub, valid, visited, n_new, n_esc = _probe(
         index_vecs, x, cand, valid, visited, n_data=n_data,
         traverse_nondata=traverse_nondata, dist_impl=dist_impl,
-        cascade=cascade, qc=qc, esc_th2=esc_th2)
+        cascade=cascade, qc=qc, esc_th2=esc_th2, metric=metric)
     return cand, dist, ub, valid, visited, n_new, n_esc
 
 
@@ -269,15 +274,15 @@ def greedy_search(index: GraphIndex, x: Array, seeds: Array,
 
     Args:
       x: (B, d) wave of queries; seeds: (B, S) start node ids.
-      theta: L2 threshold (scalar).
+      theta: threshold on the index metric's dissimilarity (scalar).
       cascade/qc: optional ``FilterCascade`` over the index vectors +
         queries encoded on its tiers' grids — traversal runs on certified
         lower bounds walked through the tier chain (see ``_probe``).
     """
-    vecs, nbrs = index.vecs, index.nbrs
+    vecs, nbrs, metric = index.vecs, index.nbrs, index.metric
     B = x.shape[0]
     L, E = cfg.beam_width, cfg.expand_per_iter
-    th2 = jnp.float32(theta) ** 2
+    th2 = theta_key(theta, metric)
     W = bitmap_words(vecs.shape[0])
     visited0 = jnp.zeros((B, W), jnp.uint32)
 
@@ -285,7 +290,7 @@ def greedy_search(index: GraphIndex, x: Array, seeds: Array,
     d0, _, v0, visited0, n0, e0 = _probe(
         vecs, x, seeds, seeds_valid, visited0, n_data=n_data,
         traverse_nondata=traverse_nondata, dist_impl=cfg.dist_impl,
-        cascade=cascade, qc=qc, esc_th2=th2)
+        cascade=cascade, qc=qc, esc_th2=th2, metric=metric)
     bd = jnp.full((B, L), _INF)
     bi = jnp.full((B, L), NO_NODE, jnp.int32)
     bexp = jnp.zeros((B, L), bool)
@@ -328,7 +333,7 @@ def greedy_search(index: GraphIndex, x: Array, seeds: Array,
         cand, cd, _, cv, visited, n_new, n_esc = _expand(
             vecs, nbrs, x, sel_ids, sel_valid, s.visited, n_data=n_data,
             traverse_nondata=traverse_nondata, dist_impl=cfg.dist_impl,
-            cascade=cascade, qc=qc, esc_th2=th2)
+            cascade=cascade, qc=qc, esc_th2=th2, metric=metric)
         visited = jnp.where(active[:, None], visited, s.visited)
         n_dist = s.n_dist + jnp.where(active, n_new, 0)
         n_esc2 = s.n_esc + jnp.where(active, n_esc, 0)
@@ -438,10 +443,10 @@ def range_expand(index: GraphIndex, x: Array, theta: float | Array, *,
     carry on the counts of the probes that produced the initial
     candidates.
     """
-    vecs, nbrs = index.vecs, index.nbrs
+    vecs, nbrs, metric = index.vecs, index.nbrs, index.metric
     B, K0 = init_idx.shape
     C, Lh, E = cfg.pool_cap, cfg.hybrid_beam, cfg.expand_per_iter
-    th2 = jnp.float32(theta) ** 2
+    th2 = theta_key(theta, metric)
     # eviction protection only matters when distances are bounds, and
     # only if the guard is enabled (cfg.hybrid_guard > 0)
     protect_th2 = (jnp.float32(cfg.hybrid_guard) * th2
@@ -534,7 +539,7 @@ def range_expand(index: GraphIndex, x: Array, theta: float | Array, *,
         cand, cd, cub, cv, visited, n_new, n_esc_new = _expand(
             vecs, nbrs, x, sel_ids, sel_valid, s.visited, n_data=n_data,
             traverse_nondata=traverse_nondata, dist_impl=cfg.dist_impl,
-            cascade=cascade, qc=qc, esc_th2=th2)
+            cascade=cascade, qc=qc, esc_th2=th2, metric=metric)
         visited = jnp.where(active[:, None], visited, s.visited)
         n_dist2 = s.n_dist + jnp.where(active, n_new, 0)
         n_esc2 = s.n_esc + jnp.where(active, n_esc_new, 0)

@@ -22,7 +22,10 @@ class GraphIndex:
 
     The adjacency is a padded neighbor table (the TPU analogue of NSG's
     adjacency lists). ``mean_nbr_dist`` is the paper's §4.5 side table (one
-    f32 per node, <1% overhead) used by the OOD predictor.
+    f32 per node, <1% overhead) used by the OOD predictor; it is an L2
+    table under either metric. ``metric`` (``METRICS``) is the space the
+    index was built in and is traversed in: its edges, its navigating
+    node and every distance a traversal computes on it.
     """
     vecs: Array                 # (N, d) node vectors
     nbrs: Array                 # (N, R) int32 neighbor ids, NO_NODE padded
@@ -32,6 +35,8 @@ class GraphIndex:
     # Nodes with id < n_data are data points (Y). For a merged index
     # G_{X∪Y}, ids in [n_data, N) are query nodes; for a plain data index,
     # n_data == N.
+    metric: str = dataclasses.field(default="l2",
+                                    metadata=dict(static=True))
 
     @property
     def n_nodes(self) -> int:
@@ -128,6 +133,28 @@ def early_exit_enabled(tcfg: TraversalConfig) -> bool:
     return env_flag("REPRO_EARLY_EXIT", tcfg.early_exit)
 
 
+# Distance spaces. θ is a threshold on the space's dissimilarity: ‖x − y‖
+# for "l2", −⟨x, y⟩ for "ip" (inner product), so a pair joins when its
+# dissimilarity is below θ — under "ip", when ⟨x, y⟩ > −θ. The program
+# compares its internal distances (squared L2, or −⟨x, y⟩ itself) with
+# ``theta_key(θ, metric)``.
+METRICS = ("l2", "ip")
+
+
+def check_metric(metric: str) -> str:
+    if metric not in METRICS:
+        raise ValueError(f"unknown metric {metric!r}; one of {METRICS}")
+    return metric
+
+
+def theta_key(theta, metric: str = "l2"):
+    """The f32 value the program's internal distances are compared with:
+    θ² for ``l2`` (distances are squared), θ for ``ip``."""
+    if metric == "ip":
+        return jnp.float32(theta)
+    return jnp.float32(theta) ** 2
+
+
 METHODS = ("nlj", "index", "es", "es_hws", "es_sws", "es_mi", "es_mi_adapt")
 
 # Compressed-storage modes: "off" streams f32 vectors through the distance
@@ -182,6 +209,9 @@ class JoinStats:
     n_lane_iters: int = 0          # loop iterations in which each valid
     #                                lane was still active, summed over
     #                                lanes (wave_size × n_iters bounds it)
+    n_hybrid_lane_iters: int = 0   # the part of n_lane_iters spent in
+    #                                range expansions of waves on the
+    #                                hybrid (BBFS) path
     n_overflow: int = 0            # in-range pool overflow (missed results)
     greedy_seconds: float = 0.0
     expand_seconds: float = 0.0    # BFS / BBFS phase

@@ -23,12 +23,20 @@ production north star):
     merged on the host. ``X ⋈_θ Y = ∪_s (X ⋈_θ Y_s)`` holds exactly, so
     recall composes additively across shards.
 
+  * **Metric** — the engine's ``metric`` (``core.types.METRICS``) is the
+    table's space, carried on every index artifact it builds
+    (``GraphIndex.metric``): squared L2, or the inner product (``ip``, θ a
+    threshold on −⟨x, y⟩). The certified bounds of the quant modes and
+    the mesh driver assume L2, so ``ip`` runs with quant ``off`` on one
+    device and raises a ``ValueError`` otherwise.
+
 ``vector_join()`` remains as a thin compatibility wrapper over a
 transient engine.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import time
 from collections import ChainMap, OrderedDict
@@ -39,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.types import (QUANT_FILTER_MODES, QUANT_MODES, GraphIndex,
-                              JoinConfig, JoinResult, JoinStats,
+                              JoinConfig, JoinResult, JoinStats, check_metric,
                               early_exit_enabled)
 from repro.engine import waves as W
 from repro.kernels import ops
@@ -137,18 +145,33 @@ class JoinEngine:
         for isolation). Every finished join publishes its ``JoinStats``
         here, artifact-cache hits/misses are counted per kind, and
         ``metrics_snapshot()`` / ``cumulative_stats()`` read it back.
+    metric : the table's space, ``"l2"`` or ``"ip"`` (see the module
+        header); every index the engine builds and every join it serves
+        uses it. ``"ip"`` raises for ``n_shards > 1`` and for a quant mode
+        other than ``"off"`` (in ``build_kw``, ``default`` or a call's
+        config).
     """
 
     def __init__(self, Y, *, build_kw: dict | None = None,
                  default: JoinConfig | None = None, n_shards: int = 1,
                  mesh=None, shard_axes=("data",), carry_window: int = 4096,
                  max_cached_indexes: int = 4,
-                 metrics: obs_metrics.Metrics | None = None):
+                 metrics: obs_metrics.Metrics | None = None,
+                 metric: str = "l2"):
         self.Y = jnp.asarray(Y)
         self.build_kw = dict(build_kw or {})
         self.default = default or JoinConfig()
         self.n_shards = (int(n_shards) if n_shards
                          else len(jax.devices()))   # 0 = one per device
+        self.metric = check_metric(metric)
+        if metric != "l2":
+            if self.n_shards > 1:
+                raise ValueError(
+                    f"metric {metric!r} runs on one device: the mesh "
+                    f"driver assumes L2 (n_shards={self.n_shards})")
+            self._check_quant(self.build_kw.get("quant", "off"),
+                              "the index build")
+            self._check_quant(self.default.quant, "the default config")
         self._mesh = mesh
         self._shard_axes = shard_axes
         self._plans: dict[bool, Any] = {}    # MeshPlan per traversal kind
@@ -203,6 +226,15 @@ class JoinEngine:
     def n_index_builds(self) -> int:
         return sum(self.build_counts.values())
 
+    def _check_quant(self, quant, where: str) -> None:
+        """The quant modes' certified bounds are L2 bounds: under another
+        metric only ``"off"`` runs."""
+        if self.metric != "l2" and quant not in (None, "off"):
+            raise ValueError(
+                f"metric {self.metric!r} supports quant 'off' only; "
+                f"{where} asks for quant {quant!r}, whose certified "
+                f"bounds assume L2")
+
     def _cache_event(self, kind: str, hit: bool) -> None:
         self.metrics.counter(
             f"engine.cache.{kind}.{'hit' if hit else 'miss'}").inc()
@@ -212,7 +244,7 @@ class JoinEngine:
         cascade from the engine's tier-store cache, so a cascade-driven
         index build and the joins served from that artifact share one
         int8 store instead of quantizing the same table twice."""
-        bk = dict(self.build_kw)
+        bk = dict(self.build_kw, metric=self.metric)
         mode = bk.pop("quant", None)
         if mode and mode != "off":
             from repro.quant.cascade import make_cascade
@@ -326,6 +358,7 @@ class JoinEngine:
         resident. Returns None for non-filtering modes."""
         from repro.quant.cascade import TIERS_BY_MODE, make_cascade
 
+        self._check_quant(cfg.quant, "the join")
         if cfg.quant not in QUANT_FILTER_MODES:
             return None
         names = TIERS_BY_MODE[cfg.quant]
@@ -375,7 +408,13 @@ class JoinEngine:
               index_x: GraphIndex | None = None,
               index_merged: GraphIndex | None = None) -> None:
         """Install prebuilt artifacts (no build counted) — the compat path
-        for callers that constructed indexes themselves."""
+        for callers that constructed indexes themselves. An index built in
+        another metric than the engine's is refused."""
+        for ix in (index_y, index_x, index_merged):
+            if ix is not None and ix.metric != self.metric:
+                raise ValueError(
+                    f"an index built under metric {ix.metric!r} cannot "
+                    f"serve an engine of metric {self.metric!r}")
         if index_y is not None:
             self._index_y = index_y
         if index_x is not None:
@@ -449,7 +488,8 @@ class JoinEngine:
             casc = self.cascade_for(("y",), self.Y, cfg, stats)
             pairs, counts = cascade_join_pairs(
                 X, self.Y, cfg.theta, casc, impl=cfg.traversal.dist_impl,
-                early_exit=early_exit_enabled(cfg.traversal))
+                early_exit=early_exit_enabled(cfg.traversal),
+                metric=self.metric)
             stats.n_rerank = counts["n_rerank"]
             if counts["escalated"]:
                 stats.n_esc8 = counts["escalated"][0]
@@ -633,7 +673,8 @@ class JoinEngine:
             pairs, counts = cascade_join_pairs(
                 X_batch, self.Y, cfg.theta, casc,
                 impl=cfg.traversal.dist_impl,
-                early_exit=early_exit_enabled(cfg.traversal))
+                early_exit=early_exit_enabled(cfg.traversal),
+                metric=self.metric)
             stats.n_rerank = counts["n_rerank"]
             if counts["escalated"]:
                 stats.n_esc8 = counts["escalated"][0]
@@ -667,8 +708,16 @@ class JoinEngine:
         self._batch_done(result, nb, cfg)
         return result
 
+    @staticmethod
+    def _annotate_call(stats: JoinStats) -> None:
+        """The call's lane-iteration counters on its ``join`` span."""
+        obs_trace.annotate_call(
+            lane_iters=stats.n_lane_iters,
+            hybrid_lane_iters=stats.n_hybrid_lane_iters)
+
     def _batch_done(self, result: JoinResult, nb: int,
                     cfg: JoinConfig | None = None) -> None:
+        self._annotate_call(result.stats)
         self.serve_stats["batches"] += 1
         self.serve_stats["queries"] += nb
         self.serve_stats["pairs"] += len(result.pairs)
@@ -725,6 +774,8 @@ class JoinEngine:
                 group.append((jnp.asarray(X2), c2, JoinStats(), offset))
             with obs_trace.call_span("join"):
                 outs = self._submit_search_group(group)
+                self._annotate_call(functools.reduce(
+                    JoinStats.merge, (r.stats for r in outs)))
             for (X2, c2, _, _), res in zip(group, outs):
                 self._batch_done(res, int(X2.shape[0]), c2)
             results.extend(outs)
@@ -999,7 +1050,8 @@ class JoinEngine:
             np.asarray(X_batch, np.float32), theta=float(base.theta),
             pool_cap=int(base.traversal.pool_cap),
             method=method, quant=quant, methods=methods,
-            quants=QUANT_MODES if quant is None else (quant,),
+            quants=((quant,) if quant is not None
+                    else QUANT_MODES if self.metric == "l2" else ("off",)),
             default_method=default_method, default_quant=base.quant,
             n_shards=self.n_shards, dim=int(self.Y.shape[1]))
         rep: dict[str, Any] = {"method": p.method, "quant": p.quant,
@@ -1138,6 +1190,7 @@ class JoinEngine:
 
     def _done(self, result: JoinResult, X,
               cfg: JoinConfig | None = None) -> JoinResult:
+        self._annotate_call(result.stats)
         self.serve_stats["joins"] += 1
         self.serve_stats["queries"] += int(X.shape[0])
         self.serve_stats["pairs"] += len(result.pairs)

@@ -58,7 +58,8 @@ import numpy as np
 from repro.core import ordering, traversal
 from repro.core.ood import predict_ood
 from repro.core.types import (NO_NODE, GraphIndex, JoinConfig, JoinStats,
-                              TraversalConfig, early_exit_enabled, env_flag)
+                              TraversalConfig, early_exit_enabled, env_flag,
+                              theta_key)
 from repro.kernels import ops
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -261,6 +262,7 @@ class WaveHandles:
     n_iters: tuple                 # device scalars, summed at assembly
     n_slots: Array                 # () candidate slots probed
     n_lane_iters: Array            # (B,) active iterations per lane
+    hybrid: bool                   # range expansion on the BBFS path
     # epilogue outputs (replaced wholesale on a capacity retry)
     keep: Array
     dist: Array
@@ -372,7 +374,10 @@ def assemble_wave(h: WaveHandles, stats: JoinStats, *,
         stats.n_dims_total += int(ndt)
         stats.n_iters += sum(int(i) for i in iters)
         stats.n_slots += int(n_slots)
-        stats.n_lane_iters += int(n_lane_iters[lv].sum())
+        lane_iters = int(n_lane_iters[lv].sum())
+        stats.n_lane_iters += lane_iters
+        if h.hybrid:
+            stats.n_hybrid_lane_iters += lane_iters
         stats.bytes_assembly += (
             pool_idx.nbytes + pool_dist.nbytes + keep.nbytes + n_pool.nbytes
             + best_idx.nbytes + n_dist.nbytes + n_esc.nbytes
@@ -412,7 +417,8 @@ def _mi_probe(merged: GraphIndex, x: Array, qids: Array, lane_valid: Array, *,
     dist, ub, valid, visited, n_new, n_esc = traversal._probe(
         merged.vecs, x, rows, valid, visited,
         n_data=merged.n_data, traverse_nondata=traverse_nondata,
-        dist_impl=dist_impl, cascade=cascade, qc=qc, esc_th2=esc_th2)
+        dist_impl=dist_impl, cascade=cascade, qc=qc, esc_th2=esc_th2,
+        metric=merged.metric)
     best = jnp.min(dist, axis=1)
     besti = jnp.take_along_axis(
         jnp.where(valid, rows, NO_NODE),
@@ -481,7 +487,7 @@ def launch_search_wave(index_y: GraphIndex, xw: Array, qids: np.ndarray,
         sv_j = jnp.asarray(seeds_valid) & jnp.asarray(lane_valid)[:, None]
         if cascade is not None and qc is None:
             qc = cascade.encode(xw)
-        th2 = jnp.float32(cfg.theta) ** 2
+        th2 = theta_key(cfg.theta, index_y.metric)
 
         t0 = time.perf_counter()
         g = traversal.greedy_search(
@@ -522,7 +528,7 @@ def launch_search_wave(index_y: GraphIndex, xw: Array, qids: np.ndarray,
             pool_idx=r.pool_idx, raw_pool_dist=r.pool_dist, n_pool=r.n_pool,
             best_idx=r.best_idx, n_dist=r.n_dist, n_esc=r.n_esc,
             overflow=r.overflow, n_iters=(g.n_iters, r.n_iters),
-            n_slots=r.n_slots, n_lane_iters=r.n_lane_iters,
+            n_slots=r.n_slots, n_lane_iters=r.n_lane_iters, hybrid=False,
             keep=keep, dist=dist, n_amb=n_amb, seed_ids=seed_ids,
             seed_valid=seed_valid2, n_dims_scanned=nds, n_dims_total=ndt,
             capctl=capctl, dist_impl=tcfg.dist_impl,
@@ -720,7 +726,7 @@ def launch_mi_wave(merged: GraphIndex, xw: Array, qids: np.ndarray,
         lv_j = jnp.asarray(lane_valid)
         if cascade is not None and qc is None:
             qc = cascade.encode(xw)
-        th2 = jnp.float32(cfg.theta) ** 2
+        th2 = theta_key(cfg.theta, merged.metric)
         if capctl is None:
             capctl = RerankCap(tcfg)
 
@@ -760,7 +766,7 @@ def launch_mi_wave(merged: GraphIndex, xw: Array, qids: np.ndarray,
             pool_idx=r.pool_idx, raw_pool_dist=r.pool_dist, n_pool=r.n_pool,
             best_idx=r.best_idx, n_dist=r.n_dist, n_esc=r.n_esc,
             overflow=r.overflow, n_iters=(r.n_iters,),
-            n_slots=r.n_slots, n_lane_iters=r.n_lane_iters,
+            n_slots=r.n_slots, n_lane_iters=r.n_lane_iters, hybrid=hybrid,
             keep=keep, dist=dist2, n_amb=n_amb, seed_ids=seed_ids,
             seed_valid=seed_valid, n_dims_scanned=nds, n_dims_total=ndt,
             capctl=capctl, dist_impl=tcfg.dist_impl,

@@ -1,6 +1,7 @@
 """Pallas TPU kernels for the join's distance-computation hot spot (paper C4).
 
-Two kernels:
+Two kernels per metric (squared L2, and the negated inner product −⟨x, y⟩
+of the ``ip`` metric, ``core.types.METRICS``):
 
   * ``pairwise``  — queries (B, d) vs a *shared* data tile (N, d) in the
     matmul form ``‖x‖² + ‖y‖² − 2·x·yᵀ``. This is MXU-shaped: arithmetic
@@ -12,6 +13,10 @@ Two kernels:
     (B, K, d) from the graph traversal. Each candidate row is used exactly
     once ⇒ memory-bound VPU work; the kernel tiles (B, K, d) so the working
     set sits in VMEM and the d-reduction accumulates in the f32 output block.
+
+The ``neg_ip`` kernels are the same two without the norms: the pairwise
+one is the matmul form's dot alone, the rowwise one a product sum; they
+share the L2 kernels' block shapes and the wrappers' padding.
 
 Block shapes default to MXU/VPU-aligned (multiples of 8×128 for f32);
 wrappers in ops.py pad and slice. Both kernels accumulate in f32 regardless
@@ -130,6 +135,82 @@ def rowwise_sq_dists_pallas(x: Array, cands: Array, *, bm: int = 8,
         functools.partial(_rowwise_kernel, nd=nd),
         name="rowwise_sq_dists",
         grid=grid,
+        in_specs=[
+            pl.BlockSpec((bm, dk), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bm, bkk, dk), lambda i, j, k: (i, j, k)),
+        ],
+        out_specs=pl.BlockSpec((bm, bkk), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((B, K), jnp.float32),
+        interpret=interpret,
+    )(x, cands)
+
+
+# ---------------------------------------------------------------------------
+# inner product: the same two kernels, −⟨x, y⟩ without the norms
+# ---------------------------------------------------------------------------
+
+def _pairwise_ip_kernel(x_ref, y_ref, o_ref):
+    k = pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    o_ref[...] -= jax.lax.dot_general(
+        x_ref[...], y_ref[...],
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=f32_precision(x_ref.dtype),
+        preferred_element_type=jnp.float32)
+
+
+def pairwise_neg_ip_pallas(x: Array, y: Array, *, bm: int = 256,
+                           bn: int = 512, bk: int = 512,
+                           interpret: bool = False) -> Array:
+    """Tiled pairwise −⟨x, y⟩, (B, d) × (N, d) → (B, N) f32. Shapes must
+    already be block-divisible, as for ``pairwise_sq_dists_pallas``."""
+    B, d = x.shape
+    N, _ = y.shape
+    bm, bn, bk = min(bm, B), min(bn, N), min(bk, d)
+    assert B % bm == 0 and N % bn == 0 and d % bk == 0, (x.shape, y.shape, (bm, bn, bk))
+    return pl.pallas_call(
+        _pairwise_ip_kernel,
+        name="pairwise_neg_ip",
+        grid=(B // bm, N // bn, d // bk),
+        in_specs=[
+            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
+        interpret=interpret,
+    )(x, y)
+
+
+def _rowwise_ip_kernel(x_ref, c_ref, o_ref):
+    kd = pl.program_id(2)
+
+    @pl.when(kd == 0)
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    xb = x_ref[...].astype(jnp.float32)          # (bm, dk)
+    cb = c_ref[...].astype(jnp.float32)          # (bm, bkk, dk)
+    o_ref[...] -= jnp.sum(cb * xb[:, None, :], axis=-1)
+
+
+def rowwise_neg_ip_pallas(x: Array, cands: Array, *, bm: int = 8,
+                          bkk: int = 128, dk: int = 512,
+                          interpret: bool = False) -> Array:
+    """Tiled per-query candidate −⟨x, c⟩, (B, d) × (B, K, d) → (B, K) f32.
+    Shapes must be block-divisible, as for ``rowwise_sq_dists_pallas``."""
+    B, d = x.shape
+    _, K, _ = cands.shape
+    bm, bkk, dk = min(bm, B), min(bkk, K), min(dk, d)
+    assert B % bm == 0 and K % bkk == 0 and d % dk == 0
+    return pl.pallas_call(
+        _rowwise_ip_kernel,
+        name="rowwise_neg_ip",
+        grid=(B // bm, K // bkk, d // dk),
         in_specs=[
             pl.BlockSpec((bm, dk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bm, bkk, dk), lambda i, j, k: (i, j, k)),

@@ -138,6 +138,68 @@ def rowwise_sq_dists(x: Array, cands: Array, *, impl: str | None = None) -> Arra
     return out[:B, :K]
 
 
+@functools.partial(jax.jit, static_argnames=("impl",))
+def pairwise_neg_ip(x: Array, y: Array, *, impl: str | None = None) -> Array:
+    """(B, d) × (N, d) → (B, N) f32 −⟨x, y⟩ (the ``ip`` metric's
+    dissimilarity). Zero padding adds nothing to a dot; padded rows and
+    columns are sliced off."""
+    impl = _resolve("pairwise_neg_ip", impl)
+    B, d = x.shape
+    N, _ = y.shape
+    if B == 0 or N == 0 or d == 0:
+        return jnp.zeros((B, N), jnp.float32)
+    if impl == "ref":
+        return _ref.pairwise_neg_ip(x, y)
+    Bp, bm = _grid_dim(B, 256, 8)
+    Np, bn = _grid_dim(N, 512, 128)
+    dp, bk = _grid_dim(d, 512, 128)
+    xp = _pad_axis(_pad_rows(x, Bp), dp, axis=1)
+    yp = _pad_axis(_pad_rows(y, Np), dp, axis=1)
+    out = _distance.pairwise_neg_ip_pallas(
+        xp, yp, bm=bm, bn=bn, bk=bk, interpret=(impl == "pallas_interpret"))
+    return out[:B, :N]
+
+
+@functools.partial(jax.jit, static_argnames=("impl",))
+def rowwise_neg_ip(x: Array, cands: Array, *, impl: str | None = None) -> Array:
+    """(B, d) × (B, K, d) → (B, K) f32 per-query candidate −⟨x, c⟩. At
+    d = 200 the candidates pad to 256 (the 128-lane block), as gist's 960
+    pads to 1024 for ``rowwise_sq_dists``."""
+    impl = _resolve("rowwise_neg_ip", impl)
+    B, d = x.shape
+    _, K, _ = cands.shape
+    if B == 0 or K == 0 or d == 0:
+        return jnp.zeros((B, K), jnp.float32)
+    if impl == "ref":
+        return _ref.rowwise_neg_ip(x, cands)
+    Bp, bm = _grid_dim(B, 8, 8)
+    Kp, bkk = _grid_dim(K, 128, 128)
+    dp, dk = _grid_dim(d, 512, 128)
+    xp = _pad_axis(_pad_rows(x, Bp), dp, axis=1)
+    cp = _pad_axis(_pad_axis(_pad_rows(cands, Bp), Kp, axis=1), dp, axis=2)
+    out = _distance.rowwise_neg_ip_pallas(
+        xp, cp, bm=bm, bkk=bkk, dk=dk, interpret=(impl == "pallas_interpret"))
+    return out[:B, :K]
+
+
+def pairwise_dists(x: Array, y: Array, *, metric: str = "l2",
+                   impl: str | None = None) -> Array:
+    """The metric's pairwise distance as the program compares it with
+    ``core.types.theta_key``: squared L2, or −⟨x, y⟩."""
+    if metric == "ip":
+        return pairwise_neg_ip(x, y, impl=impl)
+    return pairwise_sq_dists(x, y, impl=impl)
+
+
+def rowwise_dists(x: Array, cands: Array, *, metric: str = "l2",
+                  impl: str | None = None) -> Array:
+    """The metric's per-query candidate distance (``pairwise_dists``'s
+    rowwise form)."""
+    if metric == "ip":
+        return rowwise_neg_ip(x, cands, impl=impl)
+    return rowwise_sq_dists(x, cands, impl=impl)
+
+
 @functools.partial(jax.jit, static_argnames=("theta", "impl"))
 def nlj_count(x: Array, y: Array, *, theta: float,
               impl: str | None = None) -> Array:
@@ -166,10 +228,11 @@ def nlj_count(x: Array, y: Array, *, theta: float,
 
 
 def nlj_mask(x: Array, y: Array, *, theta: float,
-             impl: str | None = None) -> Array:
+             impl: str | None = None, metric: str = "l2") -> Array:
     """Exact boolean match matrix (B, N) — via pairwise kernel + compare."""
-    d = pairwise_sq_dists(x, y, impl=impl)
-    return d < jnp.float32(theta) ** 2
+    from repro.core.types import theta_key
+    d = pairwise_dists(x, y, metric=metric, impl=impl)
+    return d < theta_key(theta, metric)
 
 
 def topk_merge(beam_dist: Array, beam_idx: Array, cand_dist: Array,

@@ -47,6 +47,22 @@ def rowwise_sq_dists(x: Array, cands: Array) -> Array:
     return jnp.sum(diff * diff, axis=-1)
 
 
+def pairwise_neg_ip(x: Array, y: Array) -> Array:
+    """Negated inner products between all rows of x and y, (B, N) f32: the
+    dissimilarity of the ``ip`` metric."""
+    x = x.astype(jnp.float32)
+    y = y.astype(jnp.float32)
+    return -jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
+
+
+def rowwise_neg_ip(x: Array, cands: Array) -> Array:
+    """Negated inner product of each query with its own candidate rows,
+    (B, d) × (B, K, d) → (B, K) f32."""
+    x = x.astype(jnp.float32)
+    cands = cands.astype(jnp.float32)
+    return -jnp.sum(cands * x[:, None, :], axis=-1)
+
+
 def nlj_count(x: Array, y: Array, theta: float) -> Array:
     """Exact nested-loop-join matched-pair count per query.
 

@@ -24,7 +24,9 @@ Span names (the benchmark's readers match them exactly):
 Spans always open: with no profiler session a span costs one TraceMe
 check and records nothing. Attributes travel as event stats. Compute an
 expensive one only under ``recording()``. Spans opened inside one
-``call_span`` carry its ``call=<n>``, and wave spans carry ``wave=<k>``.
+``call_span`` carry its ``call=<n>``, and wave spans carry ``wave=<k>``;
+``annotate_call`` adds attributes to the open call's span once its
+numbers are known (the ``join`` span's lane-iteration counters).
 Tracing never touches the data path, so traced and untraced runs emit
 identical pair sets and counters (asserted in tests/test_obs.py).
 """
@@ -36,12 +38,14 @@ import itertools
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["span", "call_span", "recording", "profile"]
+__all__ = ["span", "call_span", "annotate_call", "recording", "profile"]
 
 _calls = itertools.count(1)
 # the call whose spans are open (0 outside any): context-local, so
 # threads and tasks each see their own
 _call = contextvars.ContextVar("call", default=0)
+# the open call's own span, for attributes known only at its end
+_call_span = contextvars.ContextVar("call_span", default=None)
 
 
 def recording() -> bool:
@@ -63,10 +67,22 @@ def call_span(name: str = "join", **attrs):
     A context manager, or a decorator of the call's function."""
     token = _call.set(next(_calls))
     try:
-        with span(name, **attrs):
-            yield _call.get()
+        with span(name, **attrs) as sp:
+            sp_token = _call_span.set(sp)
+            try:
+                yield _call.get()
+            finally:
+                _call_span.reset(sp_token)
     finally:
         _call.reset(token)
+
+
+def annotate_call(**attrs) -> None:
+    """Attach attributes to the open ``call_span``'s span, only while a
+    profiler session records (a no-op outside any call)."""
+    sp = _call_span.get()
+    if sp is not None and TraceAnnotation.is_enabled():
+        sp.set_metadata(**attrs)
 
 
 def profile(log_dir: str):

@@ -273,12 +273,15 @@ class JoinService:
             raise RequestRejected(
                 f"uid={req.uid}: X must be a non-empty (n, d) array, "
                 f"got shape {X.shape}")
-        d = int(self._tenants[req.tenant].Y.shape[1])
+        eng = self._tenants[req.tenant]
+        d = int(eng.Y.shape[1])
         if int(X.shape[1]) != d:
             raise RequestRejected(
                 f"uid={req.uid}: query dim {X.shape[1]} != tenant "
                 f"{req.tenant!r} dim {d}")
-        if not req.theta > 0:
+        # θ bounds the tenant metric's dissimilarity: a distance (> 0)
+        # under l2, −⟨x, y⟩ (any sign) under ip
+        if eng.metric == "l2" and not req.theta > 0:
             raise RequestRejected(f"uid={req.uid}: theta must be > 0")
         if req.method is not None:
             if req.method not in METHODS:
@@ -289,15 +292,19 @@ class JoinService:
                     f"uid={req.uid}: merged-index methods rebuild per "
                     "batch and are not servable through the streaming "
                     "front end")
-            if (req.method in _SINGLE_DEVICE
-                    and self._tenants[req.tenant].n_shards > 1):
+            if req.method in _SINGLE_DEVICE and eng.n_shards > 1:
                 raise RequestRejected(
                     f"uid={req.uid}: method {req.method!r} has no "
                     "sharded submit path and is not servable on a "
-                    f"{self._tenants[req.tenant].n_shards}-shard tenant")
+                    f"{eng.n_shards}-shard tenant")
         if req.quant is not None and req.quant not in QUANT_MODES:
             raise RequestRejected(
                 f"uid={req.uid}: unknown quant mode {req.quant!r}")
+        if eng.metric != "l2" and req.quant not in (None, "off"):
+            raise RequestRejected(
+                f"uid={req.uid}: tenant {req.tenant!r} has metric "
+                f"{eng.metric!r}, which supports quant 'off' only, not "
+                f"{req.quant!r}")
         if req.wave is not None and int(req.wave) not in self.cfg.buckets:
             raise RequestRejected(
                 f"uid={req.uid}: wave {req.wave} does not fit any "

@@ -262,6 +262,17 @@ def test_launch_join_trace_dir_writes_xplane(tmp_path):
 # -- the counters count the work done ----------------------------------------
 
 
+@pytest.mark.parametrize("path", ["es_mi_adapt", "es_sws"])
+def test_join_span_carries_the_lane_counters(traced_runs, path):
+    """The ``join`` span ends with the call's lane-iteration counters as
+    attributes (hybrid ones included), equal to its ``JoinStats``."""
+    res, spans = traced_runs[path]
+    (join,) = spans.named("join")
+    st = join[3]
+    assert int(st["lane_iters"]) == res.stats.n_lane_iters > 0
+    assert int(st["hybrid_lane_iters"]) == res.stats.n_hybrid_lane_iters
+
+
 @pytest.fixture(scope="module")
 def eng(ds):
     return JoinEngine(ds.Y, build_kw=BK, metrics=obs_metrics.Metrics())

@@ -4,8 +4,8 @@ Interpret mode (the rest of the suite) does not check what the TPU
 compiler checks: block shapes aligned to the (8, 128) tile, SMEM/VMEM
 placement, fast-memory limits. Each test here lowers one ``kernels.ops``
 entry with ``impl="pallas"`` for one chip of a described ``v5e:2x2``
-(nothing runs; no chip is needed) at the SIFT width d=128 and the GIST
-width d=960, and asserts the compiled program holds the kernel
+(nothing runs; no chip is needed) at the SIFT width d=128, the T2I width
+d=200 and the GIST width d=960, and asserts the compiled program holds the kernel
 (``tpu_custom_call``) under the stable name its ``pallas_call`` gives it,
 the name a profiler trace shows for its device op.
 
@@ -105,6 +105,10 @@ KERNELS = {
     "rowwise_sq_dists": (ops.rowwise_sq_dists,
                          lambda S, d: ((S((B, d), f32), S((B, K, d), f32)),
                                        {})),
+    "pairwise_neg_ip": (ops.pairwise_neg_ip, _f32_pair),
+    "rowwise_neg_ip": (ops.rowwise_neg_ip,
+                       lambda S, d: ((S((B, d), f32), S((B, K, d), f32)),
+                                     {})),
     "nlj_count": (ops.nlj_count,
                   lambda S, d: (_f32_pair(S, d)[0], dict(theta=1.0))),
     "gather_sq_dists": (ops.gather_sq_dists, _gather),
@@ -123,7 +127,7 @@ KERNELS = {
 }
 
 
-@pytest.mark.parametrize("d", [128, 960])
+@pytest.mark.parametrize("d", [128, 200, 960])
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(one_chip, name, d):
     fn, shapes = KERNELS[name]
